@@ -296,6 +296,12 @@ class EngineServer:
     async def health(self, request: web.Request) -> web.Response:
         if self.draining:
             return web.Response(status=503, text="draining")
+        fault = getattr(self.engine, "program_fault", None)
+        if fault:
+            # a step program failed to build (engine.py): the engine keeps
+            # answering so /stats and the flight recorder stay readable,
+            # but it must leave rotation rather than serve errors
+            return web.Response(status=503, text=f"program fault: {fault}")
         return web.Response(text="")
 
     async def abort(self, request: web.Request) -> web.Response:
@@ -867,6 +873,25 @@ class EngineServer:
         emit("tensor_parallel_degree", "gauge",
              s.get("tensor_parallel", 1),
              "tp mesh-axis size of the serving mesh (chips per replica)")
+        emit("device_count", "gauge", s.get("device_count", 0),
+             "devices JAX reports in this process (len(jax.devices()))")
+        emit("engine_step_errors_total", "counter",
+             s.get("engine_step_errors_total", 0),
+             "engine steps that raised (their batches finished with error)")
+        emit("engine_program_fault", "gauge",
+             int(bool(s.get("engine_program_fault"))),
+             "1 once a step program failed to build (/health answers 503)")
+        # the strings of the same report (platform, device kind, resolved
+        # attention implementations), info-style: constant 1, labels carry it
+        lines.append("# HELP vllm:device_info device and resolved attention paths")
+        lines.append("# TYPE vllm:device_info gauge")
+        lines.append(
+            f'vllm:device_info{{model_name="{m}",'
+            f'platform="{s.get("platform", "")}",'
+            f'device_kind="{s.get("device_kind", "")}",'
+            f'attn_impl_prefill="{s.get("attn_impl_prefill", "")}",'
+            f'attn_impl_decode="{s.get("attn_impl_decode", "")}"}} 1'
+        )
         emit("gpu_cache_usage_perc", "gauge", s["gpu_cache_usage_perc"])
         emit("gpu_prefix_cache_hit_rate", "gauge", s["gpu_prefix_cache_hit_rate"])
         emit("gpu_prefix_cache_hits_total", "counter", s["gpu_prefix_cache_hits_total"])

@@ -23,7 +23,11 @@ from production_stack_tpu import tracing
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.kv_manager import KVPageManager
 from production_stack_tpu.engine.model_loader import load_model
-from production_stack_tpu.engine.runner import ModelRunner, StepInput
+from production_stack_tpu.engine.runner import (
+    ModelRunner,
+    ProgramBuildError,
+    StepInput,
+)
 from production_stack_tpu.engine.lora import LoRAManager
 from production_stack_tpu.engine.scheduler import SamplingParams, ScheduledBatch, Scheduler, Sequence
 from production_stack_tpu.engine.tokenizer import load_tokenizer
@@ -63,7 +67,7 @@ class LLMEngine:
         enable_persistent_cache(cfg.compilation_cache_dir, scope=scope)
         self.cfg = cfg
         model_mod, model_cfg, params = load_model(
-            cfg.model, seed=cfg.seed, max_model_len=cfg.max_model_len
+            cfg.model, max_model_len=cfg.max_model_len
         )
         if cfg.attn_impl != "auto":
             model_cfg = dataclasses.replace(model_cfg, attn_impl=cfg.attn_impl)
@@ -215,6 +219,7 @@ class LLMEngine:
             num_pages=num_pages, page_size=cfg.page_size, seed=cfg.seed,
             enable_lora=cfg.enable_lora, max_loras=cfg.max_loras,
             max_lora_rank=cfg.max_lora_rank, lora_targets=lora_targets,
+            max_batch=cfg.max_num_seqs,
         )
         # KV quantization observability: bytes one token costs the pool
         # (the byte-wall number), and a startup quantize->dequantize
@@ -253,6 +258,14 @@ class LLMEngine:
         mesh_shape = dict(mesh.shape)
         self.tensor_parallel = mesh_shape.get("tp", 1)
         self.mesh_devices = int(mesh.devices.size)
+        import jax
+
+        dev0 = mesh.devices.flat[0]
+        self.device_info = {
+            "platform": dev0.platform,
+            "device_kind": dev0.device_kind,
+            "device_count": len(jax.devices()),
+        }
         self.lora: Optional[LoRAManager] = None
         if cfg.enable_lora:
             self.lora = LoRAManager(
@@ -538,14 +551,21 @@ class LLMEngine:
         self.total_prompt_tokens = 0
         self.total_generation_tokens = 0
         # dispatch-shape observability: chaining only engages on a quiescent
-        # batch, and whether it does dominates decode throughput on
-        # network-attached chips (each unchained dispatch pays a fetch RTT)
+        # batch (each unchained dispatch pays its own host fetch; what that
+        # costs on a directly attached chip is not measured)
         self.decode_dispatches_total = 0
         self.decode_chained_dispatches_total = 0
         # prefill dispatches issued while a decode chain was in flight
         # (run-ahead): the device queued them behind the chain instead of
         # idling through its fetch + scheduling turnaround
         self.runahead_prefill_dispatches_total = 0
+        # engine steps that raised (device thread is the only writer), and
+        # the first step program that failed to BUILD (runner.
+        # ProgramBuildError): every later batch of that shape fails the same
+        # way, so /health answers 503 from then on instead of staying green
+        # while requests finish with "error"
+        self.step_errors_total = 0
+        self.program_fault: Optional[str] = None
         self.spec_draft_tokens = 0     # drafts proposed (rounds * spec_k)
         self.spec_accepted_tokens = 0  # drafts the target accepted
         self.num_preemptions = 0
@@ -574,8 +594,8 @@ class LLMEngine:
         # (scheduler.arrival_rate): chaining pays off only on a quiescent
         # batch, so expected arrivals during a chain cap its depth
         self._arrival_times: collections.deque = collections.deque(maxlen=64)
-        # per-burst wall-time EMA feeding the same bound; seeded at a
-        # typical network-attached-chip burst cost until measured
+        # per-burst wall-time EMA feeding the same bound; the 50 ms seed
+        # only holds until the first measured burst replaces it
         self._burst_seconds = 0.05
         # engine-loop section time accounting (seconds, cumulative), scraped
         # via /metrics: attributes serving-loop overhead between the device
@@ -1319,10 +1339,10 @@ class LLMEngine:
                 ):
                     # every chunk in this step is intermediate — nobody's
                     # prompt completes, so the sampled tokens are discarded
-                    # anyway. Dispatch async and skip the host fetch: on
-                    # network-attached TPUs each fetch is a full host<->device
-                    # round trip, so an N-chunk prefill costs N*compute + 1 RTT
-                    # instead of N*(compute + RTT). A deferred device error
+                    # anyway. Dispatch async and skip the host fetch, so an
+                    # N-chunk prefill costs N*compute + 1 fetch instead of
+                    # N*(compute + fetch) (the fetch's cost on a directly
+                    # attached chip is not measured). A deferred device error
                     # surfaces at the next fetched step; _unfetched records
                     # whose KV state is then suspect so the handler can abort
                     # them too, not just the batch it surfaced on.
@@ -1339,6 +1359,16 @@ class LLMEngine:
                     tokens = np.asarray(ids)
             except Exception as step_err:
                 logger.exception("engine step failed; aborting batch")
+                self.step_errors_total += 1
+                if (
+                    isinstance(step_err, ProgramBuildError)
+                    and self.program_fault is None
+                ):
+                    self.program_fault = str(step_err)[:2000]
+                    logger.critical(
+                        "a step program failed to build; /health now "
+                        "answers 503: %s", self.program_fault,
+                    )
                 # postmortem: the window of scheduler/KV/compile events that
                 # led INTO this failure, while it is still in the ring
                 self._fr.record(
@@ -2305,6 +2335,19 @@ class LLMEngine:
             # tp=4 engine is one replica on 4 chips, not 4 replicas)
             "tensor_parallel": self.tensor_parallel,
             "mesh_devices": self.mesh_devices,
+            # the device the engine actually runs on, as JAX reports it, and
+            # what attn_impl resolved to (runner.resolve_attn_impl) with the
+            # reason whenever a kernel is not selected — strings, so the
+            # /metrics sweep skips them; device_count is the process-wide
+            # jax.devices() count (a tp=1 engine still claims every chip it
+            # can see)
+            **self.device_info,
+            "attn_impl_requested": self.runner.attn.requested,
+            "attn_impl_prefill": self.runner.attn.prefill,
+            "attn_impl_decode": self.runner.attn.decode,
+            "attn_impl_reason": self.runner.attn.reason,
+            "engine_step_errors_total": self.step_errors_total,
+            "engine_program_fault": self.program_fault or "",
             # KV quantization surface (docs/benchmarking.md byte-wall
             # model): pool bytes per token, quantized page count (= whole
             # pool when int8, 0 otherwise), and the startup dequant

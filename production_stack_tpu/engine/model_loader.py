@@ -3,7 +3,8 @@
 Two paths:
 - preset name (llama-debug / llama-3.2-1b / qwen2.5-7b / mixtral-8x7b /
   opt-125m ...): seeded random init — used by tests, benchmarks, and hermetic
-  environments.
+  environments. ``params`` comes back ``None``: the runner builds the tree
+  under jit straight into its shards (parallel/shardings.init_sharded).
 - local HuggingFace directory (config.json + *.safetensors): production path;
   weights live on a PVC exactly like the reference's HF_HOME cache
   (helm/templates/deployment-vllm-multi.yaml:191-196 in /root/reference).
@@ -14,7 +15,8 @@ HF per-layer tensors are mapped onto the layer-stacked trees the models use
 (every per-layer weight stacked on a leading [L] axis for the scan).
 
 Returns (module, config, params) — the module is the models/* family module
-whose `forward` the runner will jit.
+whose `forward` the runner will jit. HF params are HOST (numpy) leaves, so the
+runner places each leaf on its shards and no device ever holds a whole tree.
 """
 
 from __future__ import annotations
@@ -24,8 +26,6 @@ import json
 import os
 from typing import Any
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from production_stack_tpu import models
@@ -36,8 +36,8 @@ def is_hf_dir(path: str) -> bool:
     return os.path.isdir(path) and os.path.exists(os.path.join(path, "config.json"))
 
 
-def load_model(model: str, seed: int = 0, max_model_len: int | None = None):
-    """Returns (module, config, params)."""
+def load_model(model: str, max_model_len: int | None = None):
+    """Returns (module, config, params); params is None for a preset."""
     if is_hf_dir(model):
         mod, cfg, params = load_from_hf(model)
         if max_model_len:
@@ -60,7 +60,7 @@ def load_model(model: str, seed: int = 0, max_model_len: int | None = None):
         if max_model_len:
             # before init_params: OPT sizes its position table from this
             cfg = dataclasses.replace(cfg, max_model_len=max_model_len)
-        params = mod.init_params(cfg, jax.random.key(seed))
+        params = None
     return mod, cfg, params
 
 
@@ -97,10 +97,10 @@ def _weight_helpers(tensors: dict, num_layers: int, dtype):
     def get(name: str) -> np.ndarray:
         return np.asarray(tensors[name])
 
-    def stack(fmt: str, transpose: bool = True) -> jnp.ndarray:
+    def stack(fmt: str, transpose: bool = True) -> np.ndarray:
         ws = [get(fmt.format(i)) for i in range(num_layers)]
         arr = np.stack([w.T if transpose else w for w in ws])
-        return jnp.asarray(arr, dtype)
+        return arr.astype(dtype)
 
     return get, stack
 
@@ -127,7 +127,7 @@ def _load_llama_family(hf_cfg: dict, path: str) -> tuple[llama.LlamaConfig, dict
         # Mixtral: block_sparse_moe.gate + per-expert w1 (gate), w2 (down), w3 (up)
         L, E = cfg.num_layers, cfg.num_experts
 
-        def stack_experts(w: str) -> jnp.ndarray:
+        def stack_experts(w: str) -> np.ndarray:
             arr = np.stack([
                 np.stack([
                     get(f"model.layers.{i}.block_sparse_moe.experts.{e}.{w}.weight").T
@@ -135,7 +135,7 @@ def _load_llama_family(hf_cfg: dict, path: str) -> tuple[llama.LlamaConfig, dict
                 ])
                 for i in range(L)
             ])  # [L, E, in, out]
-            return jnp.asarray(arr, dt)
+            return arr.astype(dt)
 
         layers["moe_router"] = stack("model.layers.{}.block_sparse_moe.gate.weight")
         layers["moe_gate"] = stack_experts("w1")
@@ -147,12 +147,12 @@ def _load_llama_family(hf_cfg: dict, path: str) -> tuple[llama.LlamaConfig, dict
         layers["w_down"] = stack("model.layers.{}.mlp.down_proj.weight")
 
     params = {
-        "embed": jnp.asarray(get("model.embed_tokens.weight"), dt),
+        "embed": get("model.embed_tokens.weight").astype(dt),
         "layers": layers,
-        "final_norm": jnp.asarray(get("model.norm.weight"), dt),
+        "final_norm": get("model.norm.weight").astype(dt),
     }
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = jnp.asarray(get("lm_head.weight").T, dt)
+        params["lm_head"] = get("lm_head.weight").T.astype(dt)
     return cfg, params
 
 
@@ -168,8 +168,8 @@ def _load_opt(hf_cfg: dict, path: str) -> tuple[opt.OPTConfig, dict]:
     get, stack = _weight_helpers(t, cfg.num_layers, dt)
     lf = "decoder.layers.{}."
     params = {
-        "embed": jnp.asarray(get("decoder.embed_tokens.weight"), dt),
-        "pos_embed": jnp.asarray(get("decoder.embed_positions.weight"), dt),
+        "embed": get("decoder.embed_tokens.weight").astype(dt),
+        "pos_embed": get("decoder.embed_positions.weight").astype(dt),
         "layers": {
             "attn_norm_w": stack(lf + "self_attn_layer_norm.weight", transpose=False),
             "attn_norm_b": stack(lf + "self_attn_layer_norm.bias", transpose=False),
@@ -188,8 +188,8 @@ def _load_opt(hf_cfg: dict, path: str) -> tuple[opt.OPTConfig, dict]:
             "fc2": stack(lf + "fc2.weight"),
             "fc2_b": stack(lf + "fc2.bias", transpose=False),
         },
-        "final_norm_w": jnp.asarray(get("decoder.final_layer_norm.weight"), dt),
-        "final_norm_b": jnp.asarray(get("decoder.final_layer_norm.bias"), dt),
+        "final_norm_w": get("decoder.final_layer_norm.weight").astype(dt),
+        "final_norm_b": get("decoder.final_layer_norm.bias").astype(dt),
     }
     return cfg, params
 
@@ -201,7 +201,7 @@ def _load_gemma2(hf_cfg: dict, path: str) -> tuple["gemma2.Gemma2Config", dict]:
     get, stack = _weight_helpers(t, cfg.num_layers, dt)
     lf = "model.layers.{}."
     params = {
-        "embed": jnp.asarray(get("model.embed_tokens.weight"), dt),
+        "embed": get("model.embed_tokens.weight").astype(dt),
         "layers": {
             "attn_norm": stack(lf + "input_layernorm.weight", transpose=False),
             "post_attn_norm": stack(lf + "post_attention_layernorm.weight", transpose=False),
@@ -215,7 +215,7 @@ def _load_gemma2(hf_cfg: dict, path: str) -> tuple["gemma2.Gemma2Config", dict]:
             "w_up": stack(lf + "mlp.up_proj.weight"),
             "w_down": stack(lf + "mlp.down_proj.weight"),
         },
-        "final_norm": jnp.asarray(get("model.norm.weight"), dt),
+        "final_norm": get("model.norm.weight").astype(dt),
     }
     return cfg, params
 

@@ -31,6 +31,146 @@ from production_stack_tpu.ops.sampling import (
 )
 from production_stack_tpu.parallel import shardings
 from production_stack_tpu.parallel.mesh import make_mesh
+from production_stack_tpu.utils.logging import init_logger
+
+
+logger = init_logger(__name__)
+
+# TPU scalar memory, from the compiler's own refusal on v5e ("Ran out of
+# memory in memory space smem. Used 1.10M of 1.00M smem"). The decode
+# kernel's scalar-prefetch operands (page table + packed cell maps) all live
+# there for the largest (batch, pages) bucket; 64 KiB stays free for the
+# rest of the step program (the estimate tracks the compiler's count to ~1%).
+_SMEM_BYTES = (1 << 20) - (64 << 10)
+
+
+def _pow2_at_least(n: int) -> int:
+    """Scheduler batch/page buckets are powers of two (engine/scheduler.py)."""
+    return 1 << max(0, int(n) - 1).bit_length()
+
+
+class ProgramBuildError(RuntimeError):
+    """A step program failed the first time its shape was dispatched: trace,
+    lowering, compile or first launch. Unlike a fault in a program that has
+    run before, retrying cannot help — every later batch of that shape fails
+    the same way — so the engine stops reporting healthy (engine.py)."""
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnResolution:
+    """What ``attn_impl`` resolved to, and why — reported by GET /stats."""
+
+    requested: str
+    impl: str     # the value threaded into the model config
+    prefill: str  # "pallas" | "pallas_interpret" | "xla"
+    decode: str   # "pallas" | "pallas_shard_map" | "pallas_interpret" | "xla"
+    reason: str   # why a kernel is not selected ("" when both are)
+
+
+def kernel_refusal(
+    *, head_dim: int, kv_heads_per_shard: int, pool_itemsize: int,
+    page_size: int = 64, max_batch: Optional[int] = None,
+    max_pages: Optional[int] = None,
+) -> Optional[str]:
+    """Why Mosaic refuses the ragged kernels at this attention shape (None =
+    it compiles). Both kernels DMA whole ``[page, KH, D]`` pool pages, so
+    they share the constraint: the page's trailing (KH, D) dims must fill
+    whole (sublane-pack, 128-lane) tiles. The messages are the compiler's
+    (tests/test_kernels_compile_v5e.py compiles every preset against this
+    rule; PERF.md "Bring-up" lists the excluded shapes)."""
+    if head_dim % 128:
+        return (
+            f"head_dim {head_dim} is not a multiple of the 128-lane tile "
+            "(Mosaic: 'Slice shape along dimension 4 must be aligned to "
+            f"tiling (128), but is {head_dim}' at the page DMA)"
+        )
+    pack = max(1, 4 // pool_itemsize)  # rows packed per 32-bit sublane
+    if kv_heads_per_shard % pack:
+        return (
+            f"{kv_heads_per_shard} kv head(s) per shard with "
+            f"{pool_itemsize}-byte pool entries do not fill a sublane tile "
+            "(Mosaic: 'Slice shape along dimension 3 must be aligned to "
+            f"tiling ({pack}), but is {kv_heads_per_shard}' at the page DMA)"
+        )
+    if max_batch and max_pages:
+        from production_stack_tpu.ops.pallas.paged_attention import (
+            decode_smem_bytes,
+        )
+
+        rows, pages = _pow2_at_least(max_batch), _pow2_at_least(max_pages)
+        need = decode_smem_bytes(rows, pages, page_size, pool_itemsize)
+        if need > _SMEM_BYTES:
+            return (
+                f"scalar-prefetch operands of the largest decode bucket "
+                f"({rows} rows x {pages} pages) need {need} bytes of SMEM, "
+                f"{_SMEM_BYTES} are budgeted (XLA: 'Ran out of memory in "
+                "memory space smem')"
+            )
+    return None
+
+
+def resolve_attn_impl(
+    requested: str, *, platform: str, n_devices: int, fwd_takes_mesh: bool,
+    num_heads: int, num_kv_heads: int, head_dim: int, tp: int,
+    pool_itemsize: int, page_size: int = 64,
+    max_batch: Optional[int] = None, max_pages: Optional[int] = None,
+) -> AttnResolution:
+    """THE rule that picks the attention implementation, from platform and
+    shapes only. ``auto`` takes the kernels wherever they compile and says
+    why not otherwise; an explicit kernel request that cannot compile is a
+    ValueError here, at start-up, never a per-request failure."""
+    multi = n_devices > 1
+    if requested == "xla":
+        return AttnResolution(requested, "xla", "xla", "xla", "requested")
+    if requested == "pallas_interpret":
+        return AttnResolution(
+            requested, requested,
+            "xla" if multi else "pallas_interpret", "pallas_interpret",
+            "multi-device prefill stays on the XLA/ring path" if multi else "",
+        )
+    if requested not in ("auto", "pallas", "pallas_prefill"):
+        raise ValueError(
+            f"unknown attn_impl {requested!r}; options: auto, xla, pallas, "
+            "pallas_prefill, pallas_interpret"
+        )
+    refusal = None
+    if platform != "tpu":
+        refusal = f"no TPU backend (platform={platform})"
+    elif multi and not fwd_takes_mesh:
+        # GSPMD cannot partition a pallas_call: every multi-device case must
+        # reach the kernel through shard_map inside the model forward
+        refusal = "model family has no shard_map decode path for a mesh"
+    elif num_heads % tp or num_kv_heads % tp:
+        # the sharded kernel's specs split heads over tp; uneven head counts
+        # only work on the XLA/GSPMD gather path, which tolerates padding
+        refusal = (
+            f"heads ({num_heads} q / {num_kv_heads} kv) do not divide tp={tp}"
+        )
+    else:
+        refusal = kernel_refusal(
+            head_dim=head_dim, kv_heads_per_shard=num_kv_heads // tp,
+            pool_itemsize=pool_itemsize, page_size=page_size,
+            max_batch=max_batch, max_pages=max_pages,
+        )
+    if refusal is not None:
+        if requested != "auto":
+            raise ValueError(
+                f"attn_impl={requested!r} cannot compile here: {refusal}"
+            )
+        return AttnResolution(requested, "xla", "xla", "xla", refusal)
+    if multi:
+        # decode runs the kernel per shard; multi-device prefill keeps the
+        # XLA/ring path inside the model forward by design
+        return AttnResolution(
+            requested, "pallas", "xla", "pallas_shard_map",
+            "multi-device prefill stays on the XLA/ring path (GSPMD cannot "
+            "partition a pallas_call)",
+        )
+    if requested == "pallas":
+        return AttnResolution(
+            requested, "pallas", "xla", "pallas", "decode kernel requested"
+        )
+    return AttnResolution(requested, "pallas_prefill", "pallas", "pallas", "")
 
 
 @dataclasses.dataclass
@@ -74,7 +214,10 @@ class ModelRunner:
         max_loras: int = 4,
         max_lora_rank: int = 16,
         lora_targets: tuple[str, ...] = ("wq", "wk", "wv", "wo"),
+        max_batch: Optional[int] = None,
     ):
+        # ``max_batch``: the scheduler's largest decode batch, when the
+        # caller knows it — sizes the kernel's SMEM check (kernel_refusal)
         self.module = module if module is not None else models.module_for_config(cfg)
         self.cfg = cfg
         self.page_size = page_size
@@ -107,32 +250,43 @@ class ModelRunner:
                     f"pipeline_parallel_size={self._pp} must divide "
                     f"num_layers={cfg.num_layers}"
                 )
-        if cfg.attn_impl == "auto":
-            # pallas decode kernel on real TPU (the sharded path runs it per
-            # shard via shard_map — ops/pallas/paged_attention.py). sp/ep
-            # axes are mapped replicated (decode activations don't shard
-            # over them); pp calls the kernel inside the pipeline's manual
-            # region with stage-local layer pools. GSPMD alone cannot
-            # partition a pallas_call, which is why every multi-device case
-            # must reach the kernel through shard_map (fwd_takes_mesh).
-            mesh_ok = self.mesh.devices.size == 1 or fwd_takes_mesh
-            # the sharded kernel's shard_map specs split heads over tp
-            # (NH/KH) — uneven head counts (e.g. 2 KV heads at tp=4) only
-            # work on the XLA/GSPMD gather path, which tolerates padding
-            tp = mesh_shape.get("tp", 1)
-            heads_ok = (
-                getattr(cfg, "num_heads", 1) % tp == 0
-                and getattr(cfg, "num_kv_heads", 1) % tp == 0
+        # KV cache dtype (ops/quant.py): "auto" = model dtype; "bf16"/"fp16"
+        # pin an explicit fp pool dtype; "int8" stores quantized pages plus
+        # per-page per-kv-head scales pools — half the decode byte stream,
+        # double the effective pool capacity
+        kvdt = str(getattr(cfg, "kv_cache_dtype", "auto") or "auto")
+        known = {
+            "auto": None, "bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16,
+            "fp16": jnp.float16, "float16": jnp.float16, "int8": jnp.int8,
+        }
+        if kvdt not in known:
+            raise ValueError(
+                f"unknown kv_cache_dtype {kvdt!r}; options: {sorted(known)}"
             )
-            use_pallas = jax.default_backend() == "tpu" and mesh_ok and heads_ok
-            # "pallas_prefill": decode kernel everywhere it applies PLUS the
-            # v2 chunked-prefill kernel (ragged packed grid + contiguous-KV
-            # DMA ring + fused paged-KV write) on single-device prefill
-            # chunks; multi-device prefill keeps the XLA/ring path inside
-            # the model forward (GSPMD cannot partition a pallas_call)
-            cfg = dataclasses.replace(
-                cfg, attn_impl="pallas_prefill" if use_pallas else "xla"
-            )
+        self.kv_quant = kvdt == "int8"
+        self.kv_pool_dtype = known[kvdt] or getattr(cfg, "dtype", jnp.bfloat16)
+        tp = mesh_shape.get("tp", 1)
+        self.attn = resolve_attn_impl(
+            cfg.attn_impl,
+            platform=jax.default_backend(),
+            n_devices=self.mesh.devices.size,
+            fwd_takes_mesh=fwd_takes_mesh,
+            num_heads=getattr(cfg, "num_heads", 1),
+            num_kv_heads=getattr(cfg, "num_kv_heads", 1),
+            head_dim=getattr(cfg, "head_dim", 128),
+            tp=tp,
+            pool_itemsize=np.dtype(self.kv_pool_dtype).itemsize,
+            page_size=page_size,
+            max_batch=max_batch,
+            max_pages=-(-cfg.max_model_len // page_size),
+        )
+        logger.info(
+            "attention: requested=%s prefill=%s decode=%s%s",
+            self.attn.requested, self.attn.prefill, self.attn.decode,
+            f" ({self.attn.reason})" if self.attn.reason else "",
+        )
+        if self.attn.impl != cfg.attn_impl:
+            cfg = dataclasses.replace(cfg, attn_impl=self.attn.impl)
             self.cfg = cfg
         # the forward needs the mesh for sp/pp and for the sharded pallas
         # decode path on multi-device meshes
@@ -160,21 +314,6 @@ class ModelRunner:
             and self._pp == 1
         )
 
-        # KV cache dtype (ops/quant.py): "auto" = model dtype; "bf16"/"fp16"
-        # pin an explicit fp pool dtype; "int8" stores quantized pages plus
-        # per-page per-kv-head scales pools — half the decode byte stream,
-        # double the effective pool capacity
-        kvdt = str(getattr(cfg, "kv_cache_dtype", "auto") or "auto")
-        known = {
-            "auto": None, "bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16,
-            "fp16": jnp.float16, "float16": jnp.float16, "int8": jnp.int8,
-        }
-        if kvdt not in known:
-            raise ValueError(
-                f"unknown kv_cache_dtype {kvdt!r}; options: {sorted(known)}"
-            )
-        self.kv_quant = kvdt == "int8"
-        self.kv_pool_dtype = known[kvdt] or getattr(cfg, "dtype", jnp.bfloat16)
         if self.kv_quant:
             fwd_params = inspect.signature(self.module.forward).parameters
             if "kv_scales" not in fwd_params:
@@ -197,30 +336,18 @@ class ModelRunner:
                 )
 
         if params is None:
-            params = self.module.init_params(cfg, jax.random.key(seed))
-        pspecs = shardings.param_specs_for(params, pp=self._pp > 1)
-        self.params = shardings.shard_tree(params, pspecs, self.mesh)
+            # seeded random weights, built under jit straight into their
+            # shards (no device ever holds the whole tree)
+            self.params = shardings.init_sharded(
+                self.module.init_params, cfg, jax.random.key(seed),
+                self.mesh, pp=self._pp > 1,
+            )
+        else:
+            # host (numpy) leaves go leaf by leaf to their shards
+            pspecs = shardings.param_specs_for(params, pp=self._pp > 1)
+            self.params = shardings.shard_tree(params, pspecs, self.mesh)
         self._kv_init_kw = {} if kvdt == "auto" else {"dtype": known[kvdt]}
-        kp, vp = self.module.init_kv_pages(
-            cfg, num_pages, page_size, **self._kv_init_kw
-        )
-        kv_sh = self._kv_sharding()
-        self.k_pages = jax.device_put(kp, kv_sh)
-        self.v_pages = jax.device_put(vp, kv_sh)
-        self.k_scales = self.v_scales = None
-        if self.kv_quant:
-            from production_stack_tpu.ops.quant import init_kv_scales
-
-            sc_sh = self._kv_scales_sharding()
-            KH = getattr(cfg, "num_kv_heads", 1)
-            # two independent buffers: both are donated every step, and a
-            # shared device_put result would be one buffer donated twice
-            self.k_scales = jax.device_put(
-                init_kv_scales(cfg.num_layers, num_pages, KH), sc_sh
-            )
-            self.v_scales = jax.device_put(
-                init_kv_scales(cfg.num_layers, num_pages, KH), sc_sh
-            )
+        self.reset_kv()
         self._rng = jax.random.key(seed)
 
         self.enable_lora = enable_lora
@@ -252,6 +379,7 @@ class ModelRunner:
         # donated layouts.
         self._rep = NamedSharding(self.mesh, P())
         self._steps: dict[bool, Any] = {}  # want_logprobs -> jitted step
+        self._ran: set = set()  # (family, sig, shapes) that dispatched once
         self._set_page_fn = None  # built lazily in set_page
         self._get_page_fn = None  # built lazily in get_page (multi-host)
         self._get_pages_fns = {}  # batched offload spill, per id-count bucket
@@ -268,10 +396,9 @@ class ModelRunner:
         self._rng, key = jax.random.split(self._rng)
         if self.mesh.devices.size == 1:
             # single chip: hand numpy straight to the jitted call — one
-            # transfer batch instead of a device_put round trip per array
-            # (matters on network-attached chips). Device arrays (burst
-            # chaining feeds the previous burst's tokens back without a
-            # host fetch) pass through untouched.
+            # transfer batch instead of a device_put round trip per array.
+            # Device arrays (burst chaining feeds the previous burst's
+            # tokens back without a host fetch) pass through untouched.
             row = vec = lambda x, dt: (
                 x if isinstance(x, jax.Array) else np.asarray(x, np.dtype(dt))
             )
@@ -320,6 +447,22 @@ class ModelRunner:
             )
         return staged
 
+    def _dispatch(self, fn, family: str, sig, s: dict, args: tuple):
+        """Call a jitted step program; a failure on a shape that has never
+        dispatched is a ProgramBuildError, not a per-batch fault."""
+        key = (family, sig, s["input_ids"].shape, s["page_table"].shape)
+        if key in self._ran:
+            return fn(*args)
+        try:
+            out = fn(*args)
+        except Exception as e:
+            raise ProgramBuildError(
+                f"{family}{sig} ids{key[2]} pages{key[3]}: "
+                f"{type(e).__name__}: {str(e)[:2000]}"
+            ) from e
+        self._ran.add(key)
+        return out
+
     def _note_program_variant(self, family: str, sig) -> None:
         """Flight-recorder marker at a jit-cache miss: a NEW program variant
         is about to trace + compile (the actual XLA compile seconds land via
@@ -366,7 +509,10 @@ class ModelRunner:
         )
         if self.kv_quant:
             args = args + ((self.k_scales, self.v_scales),)
-        out = self._get_step(want_logprobs, want_pen)(*args)
+        out = self._dispatch(
+            self._get_step(want_logprobs, want_pen), "step",
+            (want_logprobs, want_pen), s, args,
+        )
         if self.kv_quant:
             *out, self.k_scales, self.v_scales = out
         if want_logprobs:
@@ -435,7 +581,7 @@ class ModelRunner:
         )
         if self.kv_quant:
             args = args + ((self.k_scales, self.v_scales),)
-        out = self._multi_steps[sig](*args)
+        out = self._dispatch(self._multi_steps[sig], "multi_step", sig, s, args)
         if self.kv_quant:
             *out, self.k_scales, self.v_scales = out
         if want_logprobs:
@@ -460,11 +606,11 @@ class ModelRunner:
         ([B, <=g*k] each) whose on-device concatenation is enqueued right at
         the group boundary and whose host copy starts immediately.
 
-        Why: on network-attached TPUs every host fetch costs a full round
-        trip (~100 ms), comparable to the burst's compute. Chaining feeds
-        burst j+1's input token straight from burst j's device-resident
-        output (toks[:, -1:]), so a chain of m bursts costs m*compute + 1 RTT
-        when the caller finally fetches, instead of m*(compute + RTT).
+        What it does: chaining feeds burst j+1's input token straight from
+        burst j's device-resident output (toks[:, -1:]), so a chain of m
+        bursts costs m*compute + 1 host fetch when the caller finally
+        fetches, instead of m*(compute + fetch). Its value on a directly
+        attached chip is not measured (ROADMAP D4 decides from the ledger).
         Grouped fetching goes further: because device programs execute in
         ENQUEUE order, a group's concat+copy enqueued at its boundary
         completes as soon as ITS bursts do — the transfer overlaps the later
@@ -572,22 +718,14 @@ class ModelRunner:
         s = self._stage(inp, with_limits=True)
         hist = jax.device_put(jnp.asarray(history, jnp.int32), self._row_sh) \
             if self.mesh.devices.size > 1 else np.asarray(history, np.int32)
-        toks, self.k_pages, self.v_pages = self._spec_fns[sig](
-            self.params,
-            self.k_pages,
-            self.v_pages,
-            hist,
-            s["input_ids"],
-            s["positions"],
-            s["page_table"],
-            s["kv_lens"],
-            s["kv_limits"],
-            s["temperature"],
-            s["top_k"],
-            s["top_p"],
-            s["key"],
-            self.lora,
-            s["lora_ids"],
+        toks, self.k_pages, self.v_pages = self._dispatch(
+            self._spec_fns[sig], "spec_step", sig, s,
+            (
+                self.params, self.k_pages, self.v_pages, hist,
+                s["input_ids"], s["positions"], s["page_table"],
+                s["kv_lens"], s["kv_limits"], s["temperature"], s["top_k"],
+                s["top_p"], s["key"], self.lora, s["lora_ids"],
+            ),
         )
         return toks
 
@@ -668,9 +806,9 @@ class ModelRunner:
     def get_pages(self, pids: "list[int]"):
         """Fetch N pages' K/V in ONE host round trip.
 
-        The per-page :meth:`get_page` costs a full host<->device round trip
-        (~100 ms on a network-attached chip); an eviction storm spilling a
-        long history page-by-page would stall the engine loop for seconds.
+        The per-page :meth:`get_page` costs a full host<->device round trip;
+        an eviction storm spilling a long history page-by-page stalls the
+        engine loop once per page.
         The page-id vector is bucketed to powers of two (padded by repeating
         the last id — an extra gather lane, harmless) so the program count
         stays bounded. Returns ``(ks, vs)``: per-page ``[L, page, KH, D]``
@@ -1113,24 +1251,32 @@ class ModelRunner:
         self._params_host = None
 
     def reset_kv(self) -> None:
-        """Zero the page pools (sleep/wake support frees and re-creates them)."""
-        kp, vp = self.module.init_kv_pages(
-            self.cfg, self.num_pages, self.page_size, **self._kv_init_kw
-        )
+        """(Re)create zeroed page pools, each device building only its own
+        shard (construction; sleep/wake frees and re-creates them)."""
         kv_sh = self._kv_sharding()
-        self.k_pages = jax.device_put(kp, kv_sh)
-        self.v_pages = jax.device_put(vp, kv_sh)
+        dt = self._kv_init_kw.get("dtype")
+        self.k_pages, self.v_pages = shardings.build_sharded(
+            self.module.init_kv_pages,
+            (self.cfg, self.num_pages, self.page_size, dt), (kv_sh, kv_sh),
+        )
+        self.k_scales = self.v_scales = None
         if self.kv_quant:
-            from production_stack_tpu.ops.quant import init_kv_scales
-
             KH = getattr(self.cfg, "num_kv_heads", 1)
             sc_sh = self._kv_scales_sharding()
-            self.k_scales = jax.device_put(
-                init_kv_scales(self.cfg.num_layers, self.num_pages, KH), sc_sh
+            self.k_scales, self.v_scales = shardings.build_sharded(
+                _scales_pools, (self.cfg.num_layers, self.num_pages, KH),
+                (sc_sh, sc_sh),
             )
-            self.v_scales = jax.device_put(
-                init_kv_scales(self.cfg.num_layers, self.num_pages, KH), sc_sh
-            )
+
+
+def _scales_pools(num_layers: int, num_pages: int, num_kv_heads: int):
+    """K and V scales pools: two buffers (both are donated every step)."""
+    from production_stack_tpu.ops.quant import init_kv_scales
+
+    return (
+        init_kv_scales(num_layers, num_pages, num_kv_heads),
+        init_kv_scales(num_layers, num_pages, num_kv_heads),
+    )
 
 
 def _multi_step_fn(forward, cfg, k, want_lp, want_pen, params, k_pages,

@@ -243,8 +243,9 @@ class Scheduler:
         # quiescence, not just a momentary gap in a sporadic stream
         self.last_arrival_age = float("inf")
         # streak-based chain growth: each chained dispatch pays exactly one
-        # fetch round trip, so depth sets the RTT share of decode time on
-        # network-attached chips. Sustained quiescence (consecutive chained
+        # host fetch, so depth sets the fetch share of decode time (not
+        # measured on a directly attached chip — ROADMAP D4). Sustained
+        # quiescence (consecutive chained
         # decode dispatches with nothing else runnable) doubles the depth up
         # to decode_pipeline_cap; any prefill, arrival, or idle pass resets.
         self._chain_streak = 0

@@ -197,9 +197,9 @@ class KVOffloadConnector:
     def save_pages(self, pairs: "list[tuple[int, bytes]]") -> "set[bytes]":
         """Offload a batch of HBM pages before their slots are reused —
         ONE device fetch per <=64 pages instead of one per page (each fetch
-        is a full host<->device round trip on network-attached chips; an
-        eviction storm spilling a long history page-by-page would stall the
-        engine loop for seconds). Never raises (same engine-loop safety as
+        is a full host<->device round trip; an eviction storm spilling a
+        long history page-by-page stalls the engine loop once per page).
+        Never raises (same engine-loop safety as
         save_page). Returns the hashes whose blobs are KNOWN to be in the
         store afterwards (already local + stored this call) — a caller that
         flips pages to the zero-I/O eviction path (``offloaded``) must only

@@ -42,7 +42,6 @@ from production_stack_tpu.ops.attention import (
 )
 from production_stack_tpu.ops.norms import rms_norm
 from production_stack_tpu.ops.rope import RopeScaling, apply_rope, rope_cos_sin
-from production_stack_tpu.parallel import compat
 
 
 @dataclass(frozen=True)
@@ -473,18 +472,11 @@ def forward(
     """
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
     pp = mesh.shape.get("pp", 1) if mesh is not None else 1
-    tp_mesh = mesh.shape.get("tp", 1) if mesh is not None else 1
-    ep_mesh = mesh.shape.get("ep", 1) if mesh is not None else 1
     B, T = input_ids.shape
     x = params["embed"][input_ids].astype(cfg.dtype)  # [B, T, H]
-    if sp > 1 and T > 1 and (compat.PARTIAL_MANUAL or tp_mesh == 1):
+    if sp > 1 and T > 1:
         # sequence parallelism: spread the chunk's token dim over sp so the
-        # norm/QKV/MLP FLOPs parallelize too, not just attention. On the 0.4
-        # toolchain this constraint makes the SPMD partitioner produce WRONG
-        # activations whenever tp-SHARDED params are also present (measured
-        # |dlogit| ~ |logit|max on an sp x tp mesh; exact without it, and
-        # exact on sp-only meshes) — there an sp x tp chunk computes
-        # sp-replicated and only attention itself parallelizes over sp.
+        # norm/QKV/MLP FLOPs parallelize too, not just attention
         from jax.sharding import NamedSharding, PartitionSpec
 
         x = jax.lax.with_sharding_constraint(
@@ -676,15 +668,9 @@ def forward(
                 if quant:
                     pallas_kw["k_scales"] = ksl
                     pallas_kw["v_scales"] = vsl
-            # under pp the kernel runs INSIDE the pipeline's manual region.
-            # With partial-manual shard_map that nests (the sharded call maps
-            # the remaining axes); without it (old jax) the pipeline region
-            # is already full-manual — every operand is a stage-local,
-            # tp-replicated shard — so the plain kernel on local data IS the
-            # correct per-shard program and nesting would be an error.
-            if mesh is not None and mesh.devices.size > 1 and (
-                pp == 1 or compat.PARTIAL_MANUAL
-            ):
+            # under pp the kernel runs INSIDE the pipeline's manual region;
+            # the sharded call nests there and maps the remaining axes
+            if mesh is not None and mesh.devices.size > 1:
                 attn = ragged_paged_attention_decode_sharded(
                     mesh, q[:, 0], *pool_args,
                     aux["page_table"], aux["kv_lens"],
@@ -759,25 +745,7 @@ def forward(
             elif post_write:
                 kc = jnp.concatenate([kc, k.astype(kc.dtype)], axis=1)
                 vc = jnp.concatenate([vc, v.astype(vc.dtype)], axis=1)
-            # On old jax (no partial-manual shard_map: compat.PARTIAL_MANUAL
-            # False) the ring's full-manual region nested inside this layer
-            # scan MISCOMPILES whenever the mesh also has a >1 axis that is
-            # mapped but unmentioned in the specs — measured |dlogit| ~
-            # |logit|max on sp x tp while the same ring is exact standalone,
-            # sp-only, and under every reduced repro. The widened ring maps
-            # dp/tp explicitly (ring_attention_serving), but ep has no
-            # natural attention axis to map, so an ep > 1 mesh carries the
-            # same hazard as unmapped tp did; the GSPMD flash path below is
-            # exact there, so sp x tp-or-ep prefill takes it (trading ring's
-            # sequence-axis sharding for correctness on that toolchain).
-            # Modern jax keeps the ring via partial manual.
-            # pp has no attention axis either, so the widened ring would
-            # refuse it (unmappable) — require pp == 1 so the fallback is
-            # the flash path, not a trace-time ValueError
-            ring_ok = compat.PARTIAL_MANUAL or (
-                tp_mesh == 1 and ep_mesh == 1 and pp == 1
-            )
-            if sp > 1 and Tm > 1 and cfg.sliding_window is None and ring_ok:
+            if sp > 1 and Tm > 1 and cfg.sliding_window is None:
                 # sequence-parallel prefill: ring attention over the sp axis
                 # (KV blocks rotate via ppermute while queries stay local)
                 from production_stack_tpu.parallel.ring_attention import (

@@ -6,9 +6,9 @@ contiguous [B, S, KH, D] tensor in HBM *before* attending — that copy is pure
 HBM-bandwidth waste in the bandwidth-bound decode regime. This kernel streams
 each page HBM->VMEM exactly once instead.
 
-v2 restructures the memory pipeline around two ideas (docs/benchmarking.md
-"Hardware ceilings": page-scattered reads measured 14-30 GB/s vs ~200 GB/s
-contiguous — the decode-step floor for long-context QA):
+v2 restructures the memory pipeline around two ideas (its achieved
+page-streaming rate on the current chip attachment is not measured —
+PERF.md):
 
 1. **Ragged packed grid.** v1 ran grid = (B, max_pages_bucket): a 50-page
    sequence in a 256-page bucket still executed ~200 dead grid cells whose
@@ -22,8 +22,8 @@ contiguous — the decode-step floor for long-context QA):
 
 2. **Deep page prefetch.** v1 fetched N pages per cell as N separate small
    BlockSpec inputs, so at most one cell's worth of page DMAs overlapped
-   compute and per-cell pipeline overhead dominated at small pages (876
-   tok/s at page 16 vs 1,501 at 128 on v5e). v2 leaves the pools in HBM
+   compute and per-cell pipeline overhead dominated at small pages. v2
+   leaves the pools in HBM
    (``memory_space=ANY``) and drives a manually multi-buffered VMEM ring of
    page copies with ``pltpu.make_async_copy``: R page DMAs stay in flight
    across cell boundaries (R = ``prefetch_pages``), so the HBM pipeline
@@ -62,6 +62,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def _scale_column(row):
+    """[1, KH] scale row (kv heads on lanes) -> [KH, 1] column (kv heads on
+    sublanes), so it broadcasts against a [page, KH, D] page. Mosaic has no
+    lane->sublane shape cast for a KH-wide vector; a masked lane reduction
+    over a [KH, KH] identity is the transpose it does support."""
+    KH = row.shape[1]
+    eye = (
+        lax.broadcasted_iota(jnp.int32, (KH, KH), 0)
+        == lax.broadcasted_iota(jnp.int32, (KH, KH), 1)
+    )
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
 
 def _decode_kernel(
@@ -186,13 +199,15 @@ def _decode_kernel(
                 vcp.wait()
                 s = g % R
                 q = (q_ref[0].astype(jnp.float32) * sm_scale).reshape(KH, G, D)
-                k = k_buf[s].astype(jnp.float32).transpose(1, 0, 2)  # [KH, page, D]
-                v = v_buf[s].astype(jnp.float32).transpose(1, 0, 2)
+                k = k_buf[s].astype(jnp.float32)  # [page, KH, D]
+                v = v_buf[s].astype(jnp.float32)
                 if quantized:
                     # dequant at the VMEM ring exit: per-page per-kv-head
                     # scale rows looked up from the resident slab
-                    k = k * ks_ref[0, pid][:, None, None]
-                    v = v * vs_ref[0, pid][:, None, None]
+                    k = k * _scale_column(ks_ref[0, pl.ds(pid, 1), :])[None]
+                    v = v * _scale_column(vs_ref[0, pl.ds(pid, 1), :])[None]
+                k = k.transpose(1, 0, 2)  # [KH, page, D]
+                v = v.transpose(1, 0, 2)
                 # batched over KH: [KH, G, D] x [KH, page, D] -> [KH, G, page]
                 scores = lax.dot_general(
                     q, k, (((2,), (2,)), ((0,), (0,))),
@@ -256,6 +271,32 @@ def _decode_kernel(
         o_ref[0] = out.reshape(NH, D).astype(o_ref.dtype)
 
 
+def _auto_pages_per_block(max_pages: int, page_size: int, itemsize: int) -> int:
+    """~128 KV slots of bookkeeping per cell for short-context buckets;
+    long-context buckets (>=128 pages) use ~512 — with the DMA ring the cell
+    size no longer bounds fetch depth, it only amortizes the per-cell
+    grid/index-map overhead. int8 pools double the slot target: each slot
+    costs half the bytes, so the same VMEM/DMA budget amortizes twice the
+    bookkeeping (re-sweep with scripts/profile_decode.py --impl pallas_int8
+    when retuning)."""
+    target = 512 if max_pages >= 128 else 128
+    if itemsize == 1:
+        target *= 2
+    return max(1, min(target // page_size, max_pages))
+
+
+def decode_smem_bytes(
+    batch: int, max_pages: int, page_size: int, itemsize: int
+) -> int:
+    """SMEM the decode kernel's scalar-prefetch operands take at one
+    (batch, pages) bucket with auto ``pages_per_block``: the [B, max_pages]
+    page table, the two packed cell maps of B * n_blocks entries, and five
+    [B] vectors — all int32. engine/runner.kernel_refusal holds the largest
+    bucket against the chip's SMEM."""
+    n_blocks = -(-max_pages // _auto_pages_per_block(max_pages, page_size, itemsize))
+    return 4 * batch * (max_pages + 2 * n_blocks + 5)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -316,8 +357,7 @@ def ragged_paged_attention_decode(
     ``prefetch_pages``: depth of the VMEM page-copy ring — how many page
     DMAs stay in flight ahead of compute (auto: up to 8, bounded by a ~2 MB
     per-array VMEM budget). This is what keeps the HBM pipeline full at
-    small pages; v1's per-cell BlockSpec fetches were the measured
-    876 -> 1,501 tok/s page-16-vs-128 cliff (engine/config.py).
+    small pages (v1's per-cell BlockSpec fetches could not).
 
     The grid itself is RAGGED: live (sequence, block) cells pack to the
     front of a 1D grid sized for the bucket's worst case, and trailing dead
@@ -343,17 +383,9 @@ def ragged_paged_attention_decode(
         k_cur = k_cur[:, None]  # [B, KH, D] -> C=1 window
         v_cur = v_cur[:, None]
     if pages_per_block is None:
-        # ~128 KV slots of bookkeeping per cell for short-context buckets;
-        # long-context buckets (>=128 pages) use ~512 — with the DMA ring
-        # the cell size no longer bounds fetch depth, it only amortizes the
-        # per-cell grid/index-map overhead. int8 pools double the slot
-        # target: each slot costs half the bytes, so the same VMEM/DMA
-        # budget amortizes twice the bookkeeping (re-sweep with
-        # scripts/profile_decode.py --impl pallas_int8 when retuning)
-        target = 512 if max_pages >= 128 else 128
-        if jnp.dtype(k_pages.dtype).itemsize == 1:
-            target *= 2
-        pages_per_block = max(1, min(target // page_size, max_pages))
+        pages_per_block = _auto_pages_per_block(
+            max_pages, page_size, jnp.dtype(k_pages.dtype).itemsize
+        )
     N = max(1, min(pages_per_block, max_pages))
     n_blocks = -(-max_pages // N)
     n_cells = B * n_blocks
@@ -410,8 +442,8 @@ def ragged_paged_attention_decode(
 
     in_specs = [
         pl.BlockSpec((1, NH, D), row3),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [q, k_pages, v_pages]
     if quantized:
@@ -559,21 +591,21 @@ def ragged_paged_attention_decode_sharded(
         # the window's KH axis shards over tp like the pool's
         in_specs += [P("dp", None, "tp", None), P("dp", None, "tp", None), P("dp")]
         operands += [k_cur, v_cur, cur_lens]
-    # only axes the mesh actually has, and never an axis some caller already
-    # made manual (the pp pipeline region). When called inside a manual
-    # region the context mesh (with those axes marked Manual) must be the
-    # one passed to the nested shard_map, not the concrete mesh.
-    from production_stack_tpu.parallel import compat
-
-    manual_already, ctx = compat.current_manual_axes()
-    sm_mesh = mesh if not manual_already else ctx
-    manual = ({"dp", "tp", "sp", "ep"} & set(mesh.axis_names)) - manual_already
-    out = compat.shard_map(
+    # EVERY mesh axis must be manual around a Mosaic call, size-1 ones too
+    # (XLA:TPU: "Mosaic kernels cannot be automatically partitioned"): map
+    # all of them, minus what an enclosing region (the pp pipeline) already
+    # holds. Inside such a region the context mesh (with those axes marked
+    # Manual) is the one the nested shard_map takes, not the concrete mesh.
+    ctx = jax.sharding.get_abstract_mesh()
+    manual_already = set() if ctx.empty else set(ctx.manual_axes)
+    sm_mesh = ctx if manual_already else mesh
+    manual = set(mesh.axis_names) - manual_already
+    out = jax.shard_map(
         body,
-        sm_mesh,
+        mesh=sm_mesh,
         axis_names=manual,
         in_specs=tuple(in_specs),
         out_specs=head,
-        check=False,
+        check_vma=False,
     )(*operands)
     return out

@@ -1,9 +1,10 @@
 """Pallas TPU kernel: ragged flash prefill over paged KV (v2) + fused
 paged-KV write.
 
-Why v2 (BENCH_r05): chunked prefill throughput went BACKWARDS with context
-— 9,788 tok/s at 16k fell to 7,158 at 32k — because the v1 kernel kept the
-decode-v1 memory structure the decode kernel already abandoned (PR 3):
+Why v2: chunked prefill throughput fell with context on the path v1 shared
+with XLA (the records that showed it predate the current chip attachment and
+are gone; the v2 kernel's own speed is not measured — PERF.md). The v1 kernel
+kept the decode-v1 memory structure the decode kernel already abandoned (PR 3):
 
 1. **Dense grid.** v1 ran grid = (B, n_qb, n_page_blocks) over the page
    BUCKET: a 1k-token history in a 32k bucket still executed ~500 dead
@@ -86,12 +87,13 @@ def _prefill_kernel(
     total_ref,   # [1] total live cells
     # inputs
     q_ref,       # [1, TQ, NH, D] (current (b, qb) block)
-    pos_ref,     # [1, TQ] int32 query positions (-1 pad)
+    pos_ref,     # [1, TQ, 1] int32 query positions (-1 pad)
     kp_hbm,      # [L, P, page, KH, D], memory_space=ANY (stays in HBM)
     vp_hbm,
     kc_ref,      # [1, Cw, KH, D] chunk K/V, front-padded by fp_pad slots
     vc_ref,
-    cpos_ref,    # [1, Cw] chunk entry positions (-1 pad)
+    cpos_ref,    # [1, n_cb, CB] chunk entry positions per fold sub-block
+                 # (-1 pad), NOT front-padded: row ci = entries ci*CB..
     *refs,       # [ks_ref, vs_ref (quantized: [1, P, KH] f32 scale slabs),]
                  # o_ref [, kp_out, vp_out [, o_ksc, o_vsc]], then scratch
                  # (see wrapper)
@@ -215,11 +217,13 @@ def _prefill_kernel(
             _start(jnp.int32(g))
 
     paged_end = lens_ref[b] - cl_ref[b]
-    pos_q = pos_ref[0]  # [TQ]
+    pos_q = pos_ref[0][None]  # [1, TQ, 1]: query positions ride sublanes
     win = win_ref[0]
 
     def fold(k, v, kv_pos, valid):
-        """One online-softmax update; k/v [KH, S, D], kv_pos/valid [S].
+        """One online-softmax update; k/v [KH, S, D], kv_pos/valid
+        [1, 1, S] (KV slots ride lanes — Mosaic has no rank-1 -> rank-3
+        shape cast, so every mask is built rank-3 from the start).
 
         Groups run under a fori_loop, NOT a Python loop: every unrolled fold
         would get its own scoped-vmem stack for the [KH, TQ, S] f32 score
@@ -227,10 +231,10 @@ def _prefill_kernel(
         (v1's measured 26 MB-vs-16 MB lesson). Inputs stay in their own
         dtype (bf16 in production: MXU-native)."""
         vis = (
-            valid[None, None, :]
-            & (kv_pos[None, None, :] <= pos_q[None, :, None])
-            & (pos_q[None, :, None] >= 0)
-            & (kv_pos[None, None, :] > pos_q[None, :, None] - win)
+            valid
+            & (kv_pos <= pos_q)
+            & (pos_q >= 0)
+            & (kv_pos > pos_q - win)
         )  # [1, TQ, S]
 
         def gbody(g, carry):
@@ -291,14 +295,17 @@ def _prefill_kernel(
             k = kb.transpose(1, 0, 2)  # [KH, KB, D]
             v = vb.transpose(1, 0, 2)
             start = (lopg_ref[r] + p * N) * page_size
-            idx = start + lax.iota(jnp.int32, KB)
+            idx = start + lax.broadcasted_iota(jnp.int32, (1, 1, KB), 2)
             # slots of pages beyond the live range hold stale ring bytes;
             # the validity bound (idx >= paged_end there) masks their
             # scores, but v must ALSO be sanitized: 0 * garbage in the
             # pij @ v matmul is NaN when the never-fetched slot is NaN
-            valid = idx < paged_end
-            v = jnp.where(valid[None, :, None], v, 0.0)
-            fold(k, v, idx, valid)
+            row_ok = (
+                start + lax.broadcasted_iota(jnp.int32, (1, KB, 1), 1)
+                < paged_end
+            )
+            v = jnp.where(row_ok, v, jnp.zeros_like(v))
+            fold(k, v, idx, idx < paged_end)
 
     # ---- fused paged-KV write: once per row, at its first cell ----------
     if fused_write and quantized:
@@ -497,8 +504,8 @@ def _prefill_kernel(
                 c0 = fp_pad + ci * CB
                 kc = kc_ref[0, pl.ds(c0, CB)].transpose(1, 0, 2)
                 vc = vc_ref[0, pl.ds(c0, CB)].transpose(1, 0, 2)
-                cpos = cpos_ref[0, pl.ds(c0, CB)]  # -1 pad = invisible
-                fold(kc, vc, cpos, cpos >= 0)
+                cpos = cpos_ref[0, pl.ds(ci, 1), :][None]  # [1, 1, CB]
+                fold(kc, vc, cpos, cpos >= 0)        # -1 pad = invisible
                 return carry
 
             lax.fori_loop(0, n_sub, cbody, 0)
@@ -639,18 +646,21 @@ def ragged_paged_attention_prefill(
         vc, v_cur.astype(chunk_dt), (0, FP, 0, 0)
     )
     cl = jnp.asarray(cur_lens, jnp.int32)
-    cpos = jnp.full((B, Cw), -1, jnp.int32)
+    # chunk entry positions, one [CB] row per fold sub-block. A (1, TQ) or
+    # (1, Cw) block of a [B, .] array breaks the TPU block rule (last two
+    # block dims divisible by (8, 128) or equal to the array's) once B > 1,
+    # so both position operands carry their block as whole trailing dims.
     Tc = k_cur.shape[1]
-    cpos = lax.dynamic_update_slice(
-        cpos,
-        jnp.where(
-            (lax.broadcasted_iota(jnp.int32, (B, Tc), 1) < cl[:, None])
-            & (positions[:, :Tc] >= 0),
-            positions[:, :Tc],
-            -1,
-        ),
-        (0, FP),
+    n_cb = -(-Tc // CB)
+    cpos = jnp.where(
+        (lax.broadcasted_iota(jnp.int32, (B, Tc), 1) < cl[:, None])
+        & (positions[:, :Tc] >= 0),
+        positions[:, :Tc],
+        -1,
     )
+    cpos = jnp.pad(
+        cpos, ((0, 0), (0, n_cb * CB - Tc)), constant_values=-1
+    ).reshape(B, n_cb, CB)
     win = (
         jnp.full((1,), 2**30, jnp.int32)
         if window is None
@@ -702,13 +712,13 @@ def ragged_paged_attention_prefill(
 
     def prow(c, *refs):
         so, qo = refs[5], refs[6]
-        return (so[c], qo[c])
+        return (so[c], qo[c], 0)
 
     def crow(c, *refs):
         return (refs[5][c], 0, 0, 0)
 
     def crow2(c, *refs):
-        return (refs[5][c], 0)
+        return (refs[5][c], 0, 0)
 
     def scrow(c, *refs):
         # scale slabs: the CURRENT layer's whole [P, KH] slice — constant
@@ -720,14 +730,16 @@ def ragged_paged_attention_prefill(
 
     in_specs = [
         pl.BlockSpec((1, TQ, NH, D), qrow),
-        pl.BlockSpec((1, TQ), prow),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec((1, Cw, KH, D), crow),
-        pl.BlockSpec((1, Cw, KH, D), crow),
-        pl.BlockSpec((1, Cw), crow2),
+        pl.BlockSpec((1, TQ, 1), prow),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+        # explicit VMEM: the fused write DMAs pages straight out of these
+        # blocks, and a DMA source needs a definite memory space
+        pl.BlockSpec((1, Cw, KH, D), crow, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, Cw, KH, D), crow, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, n_cb, CB), crow2),
     ]
-    operands = [q, positions, k_pages, v_pages, kc, vc, cpos]
+    operands = [q, positions[..., None], k_pages, v_pages, kc, vc, cpos]
     if quantized:
         in_specs += [
             pl.BlockSpec((1, P, KH), scrow),
@@ -749,8 +761,8 @@ def ragged_paged_attention_prefill(
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
         ]
         out_specs += [
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ]
         # operand index counts scalar prefetch: pools sit at NS+2 / NS+3
         io_aliases = {NS + 2: 1, NS + 3: 2}
@@ -827,9 +839,9 @@ def ragged_paged_attention_prefill(
         # the default 16 MB scoped-vmem budget is a fraction of v5e's
         # physical VMEM; the f32 score temporaries of a TQ x KB cell need
         # more headroom than decode-sized cells
-        compiler_params=getattr(
-            pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-        )(vmem_limit_bytes=100 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=100 * 1024 * 1024
+        ),
         cost_estimate=pl.CostEstimate(
             flops=4 * B * T * NH * D * (max_pages * page_size + T),
             bytes_accessed=(

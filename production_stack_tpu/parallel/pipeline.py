@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from production_stack_tpu.parallel import compat
 
 
 def pipeline_local(
@@ -42,7 +41,7 @@ def pipeline_local(
     split by shard_map). Returns the final-stage outputs, [M, ...] on every
     device (psum-broadcast at the end).
     """
-    S = compat.axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     s = lax.axis_index(axis_name)
     M = microbatches.shape[0]
     T = M + S - 1
@@ -90,12 +89,12 @@ def pipeline_forward(
     """
     fn = functools.partial(pipeline_local, stage_fn, axis_name=axis_name)
     pspec = jax.tree.map(lambda _: P(axis_name), params)
-    shard_fn = compat.shard_map(
+    shard_fn = jax.shard_map(
         fn,
-        mesh,
+        mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )
     return shard_fn(params, microbatches)
 
@@ -134,7 +133,7 @@ def serving_layer_pipeline(
     layers, k_pages, v_pages, ll = scan_xs
 
     def body(x, aux, layers, kp, vp, ll):
-        S = compat.axis_size(axis_name)
+        S = lax.axis_size(axis_name)
         s = lax.axis_index(axis_name)
         perm = [(i, i + 1) for i in range(S - 1)]
         KH, D = kp.shape[3], kp.shape[4]
@@ -203,12 +202,12 @@ def serving_layer_pipeline(
     layer_specs = jax.tree.map(lambda _: lead, layers)
     ll_specs = None if ll is None else jax.tree.map(lambda _: lead, ll)
     aux_specs = jax.tree.map(lambda _: P(), aux)
-    x_final, k_new, v_new = compat.shard_map(
+    x_final, k_new, v_new = jax.shard_map(
         body,
-        mesh,
+        mesh=mesh,
         axis_names={axis_name},
         in_specs=(P(), aux_specs, layer_specs, lead, lead, ll_specs),
         out_specs=(P(), lead, lead),
-        check=False,
+        check_vma=False,
     )(x, aux, layers, k_pages, v_pages, ll)
     return x_final, (k_new, v_new)

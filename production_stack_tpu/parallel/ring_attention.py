@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from production_stack_tpu.parallel import compat
 
 NEG_INF = -1e30
 
@@ -77,7 +76,7 @@ def ring_attention_local(
     Sl, KH = k.shape[1], k.shape[2]
     G = NH // KH
     scale = sm_scale if sm_scale is not None else D**-0.5
-    sp = compat.axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 
@@ -134,21 +133,11 @@ def ring_attention_serving(
     >= 0 and <= query position) — matching flash_attention's explicit-
     positions semantics, which ignore kv_lens.
 
-    Partial-manual shard_map (modern jax): only ``sp`` is mapped — dp/tp
-    shardings of the batch/head axes keep flowing through GSPMD
-    automatically, so this composes with tensor parallelism without explicit
-    specs. On old jax (no partial manual: compat.PARTIAL_MANUAL False) the
-    region widens to full-manual, and there an axis that is mapped but
-    UNMENTIONED in the specs miscompiles when the shard_map sits inside the
-    layer ``lax.scan`` (observed: tp-replicated specs inside the scan
-    returned garbage attention on an sp x tp mesh — the serving engine's
-    exact shape). So on old jax the data axes are mapped EXPLICITLY instead:
-    batch over ``dp`` and heads over ``tp`` (each shard ring-attends its own
-    head slice — also no redundant compute). Head-over-tp sharding needs
-    NH/KH divisible by tp; callers (models/llama.py) fall back to the GSPMD
-    flash path otherwise. T and S pad up to multiples of sp (padded KV slots
-    get position -1 => invisible; padded queries get position -1 =>
-    discarded rows).
+    Partial-manual shard_map: only ``sp`` is mapped — dp/tp shardings of
+    the batch/head axes keep flowing through GSPMD automatically, so this
+    composes with tensor parallelism without explicit specs. T and S pad up
+    to multiples of sp (padded KV slots get position -1 => invisible; padded
+    queries get position -1 => discarded rows).
     """
     sp = mesh.shape[axis_name]
     B, T = q.shape[:2]
@@ -173,53 +162,18 @@ def ring_attention_serving(
     # pipeline), the context mesh is an AbstractMesh with that axis already
     # Manual — shard_map requires the matching mesh object, not the concrete
     # one we were constructed with
-    _, ctx = compat.current_manual_axes()
-    if ctx is not None:
+    ctx = jax.sharding.get_abstract_mesh()
+    if not ctx.empty and ctx.manual_axes:
         mesh = ctx
-    manual = {axis_name}
-    batch_ax = head_ax = None
-    if not compat.PARTIAL_MANUAL:
-        # full-manual widening (old jax): map the batch/head data axes
-        # explicitly — see the docstring; a mapped-but-unmentioned axis
-        # inside the layer scan is exactly the miscompile this avoids
-        names = set(mesh.axis_names)
-        if "dp" in names:
-            manual.add("dp")
-            batch_ax = "dp"
-        if "tp" in names:
-            NH, KH = q.shape[2], k.shape[2]
-            tp = mesh.shape["tp"]
-            if NH % tp or KH % tp:
-                raise ValueError(
-                    f"ring attention with tp={tp} needs head counts "
-                    f"divisible by tp (NH={NH}, KH={KH}); use the GSPMD "
-                    "attention path instead"
-                )
-            manual.add("tp")
-            head_ax = "tp"
-        # any OTHER >1 axis (ep, pp) has no natural attention dim to map —
-        # it would be mapped-but-unmentioned, the documented miscompile.
-        # Refuse loudly; callers (models/llama.py ring gate) fall back to
-        # the GSPMD flash path on such meshes.
-        unmappable = [
-            a for a in names - {axis_name, "dp", "tp"}
-            if mesh.shape[a] > 1
-        ]
-        if unmappable:
-            raise ValueError(
-                f"ring attention cannot widen to full-manual over "
-                f"{sorted(unmappable)} on this jax version; use the GSPMD "
-                "attention path instead"
-            )
-    seq = P(batch_ax, axis_name, head_ax, None)
-    pos_spec = P(batch_ax, axis_name)
-    out = compat.shard_map(
+    seq = P(None, axis_name, None, None)
+    pos_spec = P(None, axis_name)
+    out = jax.shard_map(
         fn,
-        mesh,
-        axis_names=manual,
+        mesh=mesh,
+        axis_names={axis_name},
         in_specs=(seq, seq, seq, pos_spec, pos_spec),
         out_specs=seq,
-        check=False,
+        check_vma=False,
     )(q, k, v, q_positions, kv_positions)
     return out[:, :T]
 
@@ -243,11 +197,11 @@ def ring_attention(
     fn = functools.partial(
         ring_attention_local, axis_name=axis_name, sm_scale=sm_scale
     )
-    shard_fn = compat.shard_map(
+    shard_fn = jax.shard_map(
         fn,
-        mesh,
+        mesh=mesh,
         in_specs=(qspec, kvspec, kvspec, P(None, axis_name), P(None)),
         out_specs=qspec,
-        check=False,
+        check_vma=False,
     )
     return shard_fn(q, k, v, q_positions, kv_lens)

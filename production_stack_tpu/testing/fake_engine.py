@@ -965,6 +965,13 @@ def make_app(model: str, speed: float, ttft: float, model_label: str | None = No
             # and the fleet controller read capacity shape through this
             f'vllm:tensor_parallel_degree{{model_name="{model}"}} {tensor_parallel}\n'
             f'vllm:num_requests_shed_total{{model_name="{model}"}} {STATE["shed"]}\n'
+            # device report, same names as the real engine: the fake runs on
+            # no accelerator and says so (platform="fake"), so a scrape can
+            # never mistake it for a chip
+            f'vllm:device_count{{model_name="{model}"}} 0\n'
+            f'vllm:engine_step_errors_total{{model_name="{model}"}} 0\n'
+            f'vllm:engine_program_fault{{model_name="{model}"}} 0\n'
+            f'vllm:device_info{{model_name="{model}",platform="fake",device_kind="fake",attn_impl_prefill="none",attn_impl_decode="none"}} 1\n'
             # fake-only observability: bounded-queue proof for overload tests,
             # per-process served/completed/abort counters for restart + replay
             # chaos assertions (served resets with the process, so a reborn

@@ -1,0 +1,250 @@
+"""Compile for a TPU v5e with no chip attached.
+
+The installed libtpu compiles for a v5e topology it cannot see:
+``jax.experimental.topologies.get_topology_desc`` hands back four abstract
+``TPU v5 lite`` devices, and ``jax.jit(f).lower(<ShapeDtypeStructs placed on
+them>).compile()`` runs the real XLA:TPU + Mosaic pipeline — so a kernel edit
+Mosaic refuses, an SMEM/VMEM overflow or a sharding the partitioner rejects
+fails here, on the CPU box, in seconds. It is evidence, not a chip run: it
+says nothing about numerics or speed (``chip_smoke.py`` phase K checks the
+numerics on the chip).
+
+``python -m production_stack_tpu.testing.v5e_aot --out r.json [--slow]`` runs
+the matrix tests/test_kernels_compile_v5e.py asserts on, in a process of its
+own: describing the topology loads libtpu, which the suite's one long-lived
+pytest process has no other use for. The recipe for kernel work is in
+.claude/skills/verify/SKILL.md.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import functools
+import json
+import sys
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from production_stack_tpu.parallel import shardings
+from production_stack_tpu.parallel.mesh import make_mesh
+
+TOPOLOGY = "v5e:2x2"
+
+
+@functools.cache
+def v5e_devices() -> tuple:
+    """The four abstract devices of one v5e host (raises where the installed
+    jax/libtpu cannot describe the topology)."""
+    from jax.experimental import topologies
+
+    return tuple(
+        topologies.get_topology_desc(
+            platform="tpu", topology_name=TOPOLOGY
+        ).devices
+    )
+
+
+def _on_chip(shape, dtype):
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=SingleDeviceSharding(v5e_devices()[0])
+    )
+
+
+def compile_decode_kernel(
+    *, B=8, NH=32, KH=8, D=128, page=64, max_pages=32, pool_pages=256,
+    layers=2, dtype=jnp.bfloat16, quant=False, window=None, cur=8,
+):
+    """AOT-compile ragged_paged_attention_decode for one v5e chip at one
+    (per-shard) attention shape; ``cur`` = burst window entries (0: none)."""
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        ragged_paged_attention_decode,
+    )
+
+    def f(q, kp, vp, pt, lens, layer, kc, vc, cl, ks, vs):
+        return ragged_paged_attention_decode(
+            q, kp, vp, pt, lens, window, k_cur=kc, v_cur=vc, cur_lens=cl,
+            layer=layer[0], k_scales=ks, v_scales=vs,
+        )
+
+    pool = _on_chip((layers, pool_pages, page, KH, D), jnp.int8 if quant else dtype)
+    win = _on_chip((B, cur, KH, D), dtype) if cur else None
+    sc = _on_chip((layers, pool_pages, KH), jnp.float32) if quant else None
+    return jax.jit(f).lower(
+        _on_chip((B, NH, D), dtype), pool, pool,
+        _on_chip((B, max_pages), jnp.int32), _on_chip((B,), jnp.int32),
+        _on_chip((1,), jnp.int32), win, win,
+        _on_chip((B,), jnp.int32) if cur else None, sc, sc,
+    ).compile()
+
+
+def compile_prefill_kernel(
+    *, B=4, T=512, NH=32, KH=8, D=128, page=64, max_pages=32, pool_pages=256,
+    layers=2, dtype=jnp.bfloat16, quant=False, window=None, fused=True,
+):
+    """AOT-compile ragged_paged_attention_prefill for one v5e chip."""
+    from production_stack_tpu.ops.pallas.prefill_attention import (
+        ragged_paged_attention_prefill,
+    )
+
+    def f(q, kp, vp, pt, pos, lens, kc, vc, cl, layer, ks, vs):
+        return ragged_paged_attention_prefill(
+            q, kp, vp, pt, pos, lens, kc, vc, cl, window, layer=layer[0],
+            fused_write=fused, k_scales=ks, v_scales=vs,
+        )
+
+    pool = _on_chip((layers, pool_pages, page, KH, D), jnp.int8 if quant else dtype)
+    chunk = _on_chip((B, T, KH, D), dtype)
+    sc = _on_chip((layers, pool_pages, KH), jnp.float32) if quant else None
+    return jax.jit(f, donate_argnums=(1, 2) if fused else ()).lower(
+        _on_chip((B, T, NH, D), dtype), pool, pool,
+        _on_chip((B, max_pages), jnp.int32), _on_chip((B, T), jnp.int32),
+        _on_chip((B,), jnp.int32), chunk, chunk, _on_chip((B,), jnp.int32),
+        _on_chip((1,), jnp.int32), sc, sc,
+    ).compile()
+
+
+def compile_step_program(
+    cfg, *, tp=1, B=4, T=512, max_pages=64, num_pages=512, page_size=64,
+    decode_steps=0,
+):
+    """AOT-compile the serving step ModelRunner would jit for ``cfg`` on a
+    v5e mesh of ``tp`` chips: a prefill/decode ``step`` (``decode_steps=0``)
+    or the ``decode_steps``-token deferred burst. ``cfg.attn_impl`` must
+    already be resolved (engine/runner.resolve_attn_impl)."""
+    from production_stack_tpu import models
+    from production_stack_tpu.engine import runner
+
+    module = models.module_for_config(cfg)
+    mesh = make_mesh(tp=tp, devices=v5e_devices())
+
+    def on_mesh(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, spec)
+        )
+
+    shapes = jax.eval_shape(lambda: module.init_params(cfg, jax.random.key(0)))
+    params = jax.tree.map(
+        lambda s, spec: on_mesh(s.shape, s.dtype, spec),
+        shapes, shardings.param_specs_for(shapes),
+    )
+    pool = on_mesh(
+        (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim),
+        cfg.dtype, shardings.KV_PAGES_SPEC,
+    )
+    forward = (
+        functools.partial(module.forward, mesh=mesh) if tp > 1 else module.forward
+    )
+    row = lambda n: on_mesh((B, n), jnp.int32)  # noqa: E731
+    vec = lambda dt: on_mesh((B,), dt)  # noqa: E731
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    key = jax.ShapeDtypeStruct(
+        key.shape, key.dtype, sharding=NamedSharding(mesh, P())
+    )
+    sampling = (vec(jnp.float32), vec(jnp.int32), vec(jnp.float32), key)
+    if decode_steps:
+        fn = functools.partial(
+            runner._multi_step_deferred_fn, forward, cfg, decode_steps,
+            False, False,
+        )
+        args = (params, pool, pool, row(1), row(1), row(max_pages),
+                vec(jnp.int32), vec(jnp.int32), *sampling)
+    else:
+        fn = functools.partial(runner._step_fn, forward, cfg, False, False)
+        args = (params, pool, pool, row(T), row(T), row(max_pages),
+                vec(jnp.int32), *sampling)
+    return jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+
+
+# -- the matrix tests/test_kernels_compile_v5e.py asserts on -------------------
+
+def preset_shapes() -> dict:
+    """Unique per-shard attention shapes over every ``llama.PRESETS`` family
+    x {one chip, tp=4 shard} x {bf16, int8 pools}: id -> (NH, KH, D, int8).
+    (The sliding window is a scalar operand of both kernels, not a shape.)"""
+    from production_stack_tpu.models import llama
+
+    seen: dict = {}
+    for name, cfg in llama.PRESETS.items():
+        for tp in (1, 4):
+            if cfg.num_heads % tp or cfg.num_kv_heads % tp:
+                continue  # the rule's "heads do not divide tp" branch
+            for int8 in (False, True):
+                key = (cfg.num_heads // tp, cfg.num_kv_heads // tp,
+                       cfg.head_dim, int8)
+                seen.setdefault(key, f"{name}/tp{tp}/{'int8' if int8 else 'bf16'}")
+    return {v: k for k, v in seen.items()}
+
+
+# the prefill kernel takes 5-10 s per shape: the tier-1 run compiles the
+# widest bf16 one, ``--slow`` the rest (and the SMEM boundary)
+TIER1_PREFILL = (32, 8, 128, False)
+
+
+def _attempt(fn, **kw) -> dict:
+    try:
+        fn(**kw)
+        return {"compiled": True, "error": ""}
+    except Exception as e:  # noqa: BLE001 - the compiler's refusal IS the result
+        return {"compiled": False, "error": f"{type(e).__name__}: {e}"[:1500]}
+
+
+def run_matrix(slow: bool = False) -> dict:
+    from production_stack_tpu.engine import runner
+    from production_stack_tpu.models import llama
+
+    out: dict = {"decode": {}, "prefill": {}, "smem": {}, "tp4_step": {}}
+    for cid, (NH, KH, D, int8) in preset_shapes().items():
+        refusal = runner.kernel_refusal(
+            head_dim=D, kv_heads_per_shard=KH, pool_itemsize=1 if int8 else 2
+        )
+        kw = dict(NH=NH, KH=KH, D=D, quant=int8, window=4096)
+        if not slow:
+            out["decode"][cid] = dict(
+                _attempt(compile_decode_kernel, B=8, **kw), refusal=refusal
+            )
+        tier1_shape = (NH, KH, D, int8) == TIER1_PREFILL
+        if refusal is None and tier1_shape != slow:
+            # prefill_batch x prefill_chunk of the default config, fused write
+            out["prefill"][cid] = _attempt(
+                compile_prefill_kernel, B=4, T=512, **kw
+            )
+    if slow:
+        for rows in (64, 128):  # 64 x 2048 fits the rule's budget, 128 does not
+            out["smem"][str(rows)] = _attempt(
+                compile_decode_kernel, B=rows, max_pages=2048, pool_pages=4096
+            )
+    else:
+        # the whole deferred-burst decode program at mistral-7b widths on a
+        # four-chip mesh (depth 2: the layer scan compiles one layer)
+        cfg = dataclasses.replace(
+            llama.PRESETS["mistral-7b"], num_layers=2, attn_impl="pallas"
+        )
+        out["tp4_step"] = _attempt(
+            compile_step_program, cfg=cfg, tp=4, B=8, max_pages=64,
+            num_pages=128, decode_steps=8,
+        )
+    return out
+
+
+def main() -> int:
+    p = argparse.ArgumentParser("v5e-aot")
+    p.add_argument("--out", required=True)
+    p.add_argument("--slow", action="store_true",
+                   help="the costlier half of the matrix")
+    args = p.parse_args()
+    try:
+        v5e_devices()
+    except Exception as e:  # noqa: BLE001 - "cannot describe the topology"
+        result = {"unavailable": f"{type(e).__name__}: {e}"[:500]}
+    else:
+        result = run_matrix(slow=args.slow)
+    with open(args.out, "w") as f:
+        json.dump(result, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -26,8 +26,8 @@ from production_stack_tpu.utils.metrics import LATENCY_BUCKETS, Histogram
 TPOT_BUCKETS = (
     0.01, 0.025, 0.05, 0.075, 0.1, 0.15, 0.2, 0.3, 0.4, 0.5, 0.75, 1.0, 2.5,
 )
-# restore batches are bounded by kv_offload_max_io_pages; sub-second to a few
-# seconds on network-attached hosts
+# restore batches are bounded by kv_offload_max_io_pages; the range spans
+# sub-millisecond to tens of seconds (not measured on a directly attached chip)
 RESTORE_BUCKETS = (
     0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
 )

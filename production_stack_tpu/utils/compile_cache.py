@@ -1,8 +1,8 @@
 """Persistent XLA compilation cache wiring.
 
-Cold compiles on a network-attached TPU cost 20-40 s per program variant;
-a serving engine compiles dozens of (batch bucket, pages bucket) shapes at
-startup. The reference stack never pays this (vLLM ships precompiled CUDA
+A serving engine compiles dozens of (batch bucket, pages bucket) program
+variants, seconds each on a v5e host (PERF.md "Bring-up" has the count and
+the total as set-up time). The reference stack never pays this (vLLM ships precompiled CUDA
 kernels); the TPU-native equivalent is JAX's persistent compilation cache,
 which serves every repeat compile from disk — across engine restarts, test
 runs, and bench invocations.
@@ -47,10 +47,11 @@ def _cpu_feature_scope() -> str:
     written by an identically-configured process.
     """
     import jax
+    import jaxlib
 
     parts = [
         jax.__version__,
-        getattr(jax, "lib", None) and getattr(jax.lib, "__version__", "") or "",
+        jaxlib.__version__,
         os.environ.get("XLA_FLAGS", ""),
         ",".join(sorted(m for m in ("tensorflow", "torch") if m in sys.modules)),
     ]

@@ -111,6 +111,9 @@ DASHBOARD_ALLOWLIST = {
     "vllm:compile_cache_entries",
     "vllm:compile_cache_bytes",
     "vllm:engine_step_duty_cycle",
+    "vllm:device_count",                     # static device report: read by
+    "vllm:device_info",                      # chip_smoke.py and bench rows,
+    "vllm:engine_program_fault",             # mirrored by /health 503
     "vllm_router:slo_request_outcomes_total",  # dashboard charts attainment
     "vllm_router:slo_records_total",           # these are its diagnostics
     "vllm_router:cpu_usage_perc",            # charted via the memory panel

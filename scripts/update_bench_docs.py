@@ -139,7 +139,7 @@ def _decode_contrast(e: dict) -> str:
             "decode spans"
         )
     return f"engine-direct contrast {_fmt(direct)}" + (
-        " — unstable on this tunnel, see measurement notes"
+        " — unstable, see measurement notes"
         if (direct or 0) < (e.get("http_decode_tokens_per_sec") or 0) / 2
         else ""
     )
@@ -294,12 +294,25 @@ def update_file(path: str, block: str) -> bool:
 DOC_PATHS = ("docs/benchmarking.md", "README.md")
 
 
+def require_tpu_artifact(details: dict) -> None:
+    """A CPU run is never written under a device metric's name: only an
+    artifact that says it ran on a TPU may reach the docs."""
+    platform = (details.get("extras") or {}).get("platform")
+    if platform != "tpu":
+        raise SystemExit(
+            f"refusing BENCH_DETAILS.json with platform={platform!r}: the "
+            "docs' numbers stand under device metric names and come only "
+            "from a chip run"
+        )
+
+
 def main() -> None:
     details_path = os.path.join(ROOT, "BENCH_DETAILS.json")
     if not os.path.exists(details_path):
         raise SystemExit("BENCH_DETAILS.json not found; run python bench.py first")
     with open(details_path) as f:
         details = json.load(f)
+    require_tpu_artifact(details)
     block = render_block(details)
     for rel in DOC_PATHS:
         changed = update_file(os.path.join(ROOT, rel), block)

@@ -22,7 +22,7 @@ import update_bench_docs as ubd  # noqa: E402
 def test_docs_numbers_match_artifact():
     details_path = os.path.join(ROOT, "BENCH_DETAILS.json")
     if not os.path.exists(details_path):
-        pytest.skip("no BENCH_DETAILS.json checked in yet")
+        pytest.skip("no BENCH_DETAILS.json checked in: the docs say 'not measured'")
     with open(details_path) as f:
         block = ubd.render_block(json.load(f))
     for rel in ubd.DOC_PATHS:
@@ -37,6 +37,16 @@ def test_docs_numbers_match_artifact():
             "`python scripts/update_bench_docs.py` after bench.py and commit "
             "both the docs and BENCH_DETAILS.json:\n" + "\n".join(mismatches)
         )
+
+
+def test_update_refuses_an_artifact_that_did_not_run_on_a_tpu():
+    ubd.require_tpu_artifact(_details())  # platform "tpu": accepted
+    cpu = _details()
+    cpu["extras"]["platform"] = "cpu"
+    with pytest.raises(SystemExit, match="platform='cpu'"):
+        ubd.require_tpu_artifact(cpu)
+    with pytest.raises(SystemExit, match="platform=None"):
+        ubd.require_tpu_artifact({"value": 1.0})
 
 
 def _details(p50=123.4, tps=400.0):

@@ -193,6 +193,18 @@ class TestHTTPServer:
         assert 'vllm:num_requests_running{model_name="llama-debug"}' in text
         assert "vllm:generation_tokens_total" in text
 
+    def test_stats_and_metrics_name_the_device_and_attention_paths(self, server):
+        s = requests.get(f"{server}/stats").json()
+        assert s["platform"] == "cpu" and s["device_kind"] and s["device_count"] >= 1
+        assert s["attn_impl_requested"] == "auto"
+        assert s["attn_impl_prefill"] == s["attn_impl_decode"] == "xla"
+        assert s["attn_impl_reason"] == "no TPU backend (platform=cpu)"
+        assert s["engine_step_errors_total"] == 0 and s["engine_program_fault"] == ""
+        text = requests.get(f"{server}/metrics").text
+        assert 'vllm:device_info{model_name="llama-debug",platform="cpu"' in text
+        assert 'attn_impl_decode="xla"} 1' in text
+        assert 'vllm:engine_step_errors_total{model_name="llama-debug"} 0' in text
+
     def test_n_parallel_sampling_nonstream(self, server):
         r = requests.post(
             f"{server}/v1/completions",
@@ -400,3 +412,48 @@ def test_min_tokens_suppresses_eos(engine):
     assert 5 <= len(toks) <= 32
     assert toks[-1] == eos          # the forced EOS lands once allowed
     assert eos not in toks[:4]      # and NEVER below the floor
+
+
+def test_first_dispatch_failure_is_a_program_build_error():
+    """A shape that fails the first time it is dispatched (trace, lowering,
+    compile) is not a per-batch fault; one that has run before is."""
+    from production_stack_tpu.engine.runner import ModelRunner, ProgramBuildError
+    from production_stack_tpu.models import llama
+
+    r = ModelRunner(llama.PRESETS["llama-debug"], num_pages=8, page_size=8)
+    staged = {"input_ids": np.zeros((1, 4)), "page_table": np.zeros((1, 2))}
+
+    def refuse(*_a):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    with pytest.raises(ProgramBuildError, match="Mosaic failed to compile"):
+        r._dispatch(refuse, "step", (False, False), staged, ())
+    assert r._dispatch(lambda: 7, "step", (True, False), staged, ()) == 7
+    with pytest.raises(RuntimeError) as e:  # ran before: the original error
+        r._dispatch(refuse, "step", (True, False), staged, ())
+    assert not isinstance(e.value, ProgramBuildError)
+
+
+def test_program_build_failure_takes_the_engine_out_of_rotation():
+    """The engine must not stay green serving errors: the request finishes
+    with 'error', the step is counted, and /health answers 503 from then
+    on, naming the program."""
+    from production_stack_tpu.engine.api_server import EngineServer
+
+    eng = LLMEngine(_cfg())
+
+    def refuse(*_a, **_k):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    eng.runner._get_step = lambda *_a: refuse
+    eng.start()
+    try:
+        outs = _collect(eng, "hello there", max_tokens=4)
+        assert outs[-1].finished and outs[-1].finish_reason == "error"
+        s = eng.stats()
+        assert s["engine_step_errors_total"] >= 1
+        assert "Mosaic failed to compile" in s["engine_program_fault"]
+        resp = asyncio.run(EngineServer(eng.cfg, eng).health(None))
+        assert resp.status == 503 and "program fault" in resp.text
+    finally:
+        eng.stop()

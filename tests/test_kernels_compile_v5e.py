@@ -1,0 +1,93 @@
+"""Both Pallas kernels AOT-compiled for a TPU v5e, on the CPU box.
+
+For the attention shape of every ``llama.PRESETS`` family x {one chip, tp=4
+shard} x {bf16 pools, int8 pools}: the runner's rule
+(``engine/runner.kernel_refusal``) says whether ``auto`` may pick the kernels
+there. Where it may, the kernels must compile with the real XLA:TPU + Mosaic
+pipeline (``testing/v5e_aot.py`` — libtpu compiles for a topology it cannot
+see); where it may not, Mosaic must indeed refuse, so the rule never hides a
+shape that works. A kernel edit Mosaic rejects therefore fails here, not on
+the chip. Evidence of compilation only — numerics are ``chip_smoke.py``
+phase K's.
+
+The compiles run in a CHILD process (one per suite half), which keeps libtpu
+out of the suite's one long-lived pytest process.
+"""
+
+import json
+import subprocess
+import sys
+
+import pytest
+
+from production_stack_tpu.engine import runner
+from production_stack_tpu.testing.procs import REPO_ROOT, cpu_env
+
+
+def _matrix(tmp_path_factory, slow: bool) -> dict:
+    out = tmp_path_factory.mktemp("v5e_aot") / "result.json"
+    argv = [sys.executable, "-m", "production_stack_tpu.testing.v5e_aot",
+            "--out", str(out)] + (["--slow"] if slow else [])
+    r = subprocess.run(argv, cwd=REPO_ROOT, env=cpu_env(), timeout=900,
+                       capture_output=True, text=True)
+    assert r.returncode == 0, r.stderr[-3000:]
+    result = json.loads(out.read_text())
+    if "unavailable" in result:
+        pytest.skip(f"get_topology_desc(tpu, v5e:2x2): {result['unavailable']}")
+    return result
+
+
+@pytest.fixture(scope="module")
+def tier1(tmp_path_factory):
+    return _matrix(tmp_path_factory, slow=False)
+
+
+@pytest.fixture(scope="module")
+def slow_half(tmp_path_factory):
+    return _matrix(tmp_path_factory, slow=True)
+
+
+def test_decode_kernel_compiles_or_rule_excludes(tier1):
+    assert len(tier1["decode"]) >= 8
+    for cid, r in tier1["decode"].items():
+        if r["refusal"] is None:
+            assert r["compiled"], f"{cid}: {r['error']}"
+        else:
+            # the rule must not hide a shape that works: Mosaic does refuse
+            assert not r["compiled"], f"{cid} compiles but the rule excludes it"
+            assert "aligned to tiling" in r["error"], f"{cid}: {r['error']}"
+    compiled = {c for c, r in tier1["decode"].items() if r["compiled"]}
+    assert {"llama-3-8b/tp1/bf16", "llama-3-8b/tp1/int8",
+            "qwen2.5-7b/tp1/bf16", "llama-3-8b/tp4/bf16"} <= compiled
+
+
+def test_prefill_kernel_compiles_at_the_widest_shape(tier1):
+    assert list(tier1["prefill"]) == ["llama-3-8b/tp1/bf16"]
+    for cid, r in tier1["prefill"].items():
+        assert r["compiled"], f"{cid}: {r['error']}"
+
+
+def test_tp4_decode_step_compiles_through_shard_map(tier1):
+    """The kernel must sit in a region where EVERY mesh axis is manual, or
+    XLA:TPU refuses: 'Mosaic kernels cannot be automatically partitioned'."""
+    assert tier1["tp4_step"]["compiled"], tier1["tp4_step"]["error"]
+
+
+@pytest.mark.slow
+def test_prefill_kernel_compiles_at_every_other_shape(slow_half):
+    assert len(slow_half["prefill"]) >= 4
+    for cid, r in slow_half["prefill"].items():
+        assert r["compiled"], f"{cid}: {r['error']}"
+
+
+@pytest.mark.slow
+def test_largest_default_bucket_fits_smem_and_the_next_does_not(slow_half):
+    """64 rows x 2048 pages (max_num_seqs default x a 128k context) is inside
+    the rule's SMEM budget and compiles; 128 x 2048 is outside and XLA says
+    why."""
+    kw = dict(head_dim=128, kv_heads_per_shard=8, pool_itemsize=2)
+    assert runner.kernel_refusal(max_batch=64, max_pages=2048, **kw) is None
+    assert slow_half["smem"]["64"]["compiled"], slow_half["smem"]["64"]["error"]
+    assert "SMEM" in runner.kernel_refusal(max_batch=128, max_pages=2048, **kw)
+    assert not slow_half["smem"]["128"]["compiled"]
+    assert "smem" in slow_half["smem"]["128"]["error"]

@@ -248,11 +248,12 @@ class TestShardedKernel:
 
 
 class TestAutoImplResolution:
-    """attn_impl=auto must only pick pallas when the mesh can actually run it:
-    the sharded kernel's shard_map specs split heads over tp, so uneven head
-    counts (e.g. 2 KV heads at tp=4) must fall back to the XLA gather path."""
+    """attn_impl=auto must only pick the kernels where they can compile and
+    run (engine/runner.resolve_attn_impl — the one rule on platform and
+    shapes), and must say why when it does not."""
 
-    def _resolve(self, monkeypatch, tp, dp=1, num_heads=4, num_kv_heads=2):
+    def _resolve(self, monkeypatch, tp, dp=1, num_heads=8, num_kv_heads=4,
+                 head_dim=128, attn_impl="auto"):
         import jax
 
         from production_stack_tpu.engine.runner import ModelRunner
@@ -262,28 +263,78 @@ class TestAutoImplResolution:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         cfg = dataclasses.replace(
             llama.PRESETS["llama-debug"],
-            num_heads=num_heads, num_kv_heads=num_kv_heads, attn_impl="auto",
+            num_heads=num_heads, num_kv_heads=num_kv_heads,
+            head_dim=head_dim, attn_impl=attn_impl,
         )
         r = ModelRunner(
             cfg, mesh=make_mesh(tp=tp, dp=dp), num_pages=16, page_size=8, seed=0
         )
-        return r.cfg.attn_impl
+        assert r.cfg.attn_impl == r.attn.impl
+        return r.attn
 
-    def test_even_heads_pick_pallas(self, monkeypatch, eight_devices):
-        # "pallas_prefill" since kernel v2: decode kernel everywhere PLUS
-        # the chunked-prefill kernel on single-device prefill dispatches
-        assert self._resolve(monkeypatch, tp=2) == "pallas_prefill"
+    def test_one_device_picks_both_kernels(self, monkeypatch):
+        a = self._resolve(monkeypatch, tp=1)
+        assert (a.impl, a.prefill, a.decode, a.reason) == (
+            "pallas_prefill", "pallas", "pallas", ""
+        )
+
+    def test_mesh_runs_decode_kernel_per_shard_and_xla_prefill(
+        self, monkeypatch, eight_devices
+    ):
+        a = self._resolve(monkeypatch, tp=2)
+        assert (a.impl, a.prefill, a.decode) == (
+            "pallas", "xla", "pallas_shard_map"
+        )
+        assert "prefill" in a.reason
 
     def test_uneven_kv_heads_fall_back_to_xla(self, monkeypatch, eight_devices):
-        assert self._resolve(monkeypatch, tp=4) == "xla"
+        a = self._resolve(monkeypatch, tp=4, num_heads=4, num_kv_heads=2)
+        assert a.impl == "xla" and "do not divide tp=4" in a.reason
 
     def test_uneven_heads_fall_back_to_xla(self, monkeypatch, eight_devices):
         # 6 q / 2 kv heads at tp=4: neither divides (for valid GQA configs
         # tp | kv_heads already implies tp | num_heads, so the q check only
         # fires together with the kv one)
-        assert (
-            self._resolve(monkeypatch, tp=4, num_heads=6, num_kv_heads=2) == "xla"
+        a = self._resolve(monkeypatch, tp=4, num_heads=6, num_kv_heads=2)
+        assert a.impl == "xla" and "do not divide" in a.reason
+
+    def test_head_dim_64_is_excluded_with_the_compilers_message(
+        self, monkeypatch
+    ):
+        a = self._resolve(monkeypatch, tp=1, head_dim=64)
+        assert a.impl == a.prefill == a.decode == "xla"
+        assert "aligned to tiling (128), but is 64" in a.reason
+
+    def test_one_kv_head_per_shard_is_excluded(self, monkeypatch, eight_devices):
+        # qwen2.5-7b at tp=4: 4 kv heads / 4 = 1 bf16 row per shard
+        a = self._resolve(monkeypatch, tp=4, num_heads=8, num_kv_heads=4)
+        assert a.impl == "xla"
+        assert "aligned to tiling (2), but is 1" in a.reason
+
+    def test_explicit_kernel_that_cannot_compile_fails_at_startup(
+        self, monkeypatch
+    ):
+        with pytest.raises(ValueError, match="cannot compile here"):
+            self._resolve(
+                monkeypatch, tp=1, head_dim=64, attn_impl="pallas_prefill"
+            )
+
+    def test_no_tpu_means_xla_and_says_so(self):
+        from production_stack_tpu.engine.runner import resolve_attn_impl
+
+        a = resolve_attn_impl(
+            "auto", platform="cpu", n_devices=1, fwd_takes_mesh=True,
+            num_heads=32, num_kv_heads=8, head_dim=128, tp=1, pool_itemsize=2,
         )
+        assert a.impl == "xla" and a.reason == "no TPU backend (platform=cpu)"
+
+    def test_smem_budget_bounds_the_largest_decode_bucket(self):
+        from production_stack_tpu.engine.runner import kernel_refusal
+
+        kw = dict(head_dim=128, kv_heads_per_shard=8, pool_itemsize=2)
+        # 64 rows x 2048 pages compiled for v5e; 128 x 2048 ran out of SMEM
+        assert kernel_refusal(max_batch=64, max_pages=2048, **kw) is None
+        assert "SMEM" in kernel_refusal(max_batch=128, max_pages=2048, **kw)
 
 
 class TestShardedKernelOnParallelMeshes:
@@ -352,15 +403,16 @@ class TestShardedKernelOnParallelMeshes:
                         {"ep": 2, "tp": 2}, {"sp": 2, "ep": 2, "tp": 2}):
             cfg = dataclasses.replace(
                 llama.PRESETS["llama-debug"],
-                num_heads=8, num_kv_heads=4, attn_impl="auto",
+                num_heads=8, num_kv_heads=4, head_dim=128, attn_impl="auto",
             )
             r = ModelRunner(
                 cfg, mesh=make_mesh(**mesh_kw), num_pages=16, page_size=8,
                 seed=0,
             )
-            # auto resolves to the full kernel surface; the model forward
-            # gates the prefill kernel back to single-device dispatches
-            assert r.cfg.attn_impl == "pallas_prefill", mesh_kw
+            # decode runs the kernel per shard; multi-device prefill stays
+            # on the XLA/ring path and the resolution says so
+            assert r.cfg.attn_impl == "pallas", mesh_kw
+            assert r.attn.decode == "pallas_shard_map", mesh_kw
 
 
 class TestMultiPageBlocks:

@@ -494,6 +494,8 @@ def test_metric_label_cardinality_bounded(stack):
         "source", "device", "reason", "objective", "model", "outcome",
         # SLO class (docs/failure-handling.md): closed two-value set
         "priority",
+        # vllm:device_info: one static series per engine process
+        "platform", "device_kind", "attn_impl_prefill", "attn_impl_decode",
     }
     forbidden = {"request_id", "seq_id", "trace_id", "x_request_id"}
     for url in (router_url, engine_url):
