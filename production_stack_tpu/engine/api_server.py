@@ -903,8 +903,21 @@ class EngineServer:
              s["decode_chained_dispatches_total"])
         emit("runahead_prefill_dispatches_total", "counter",
              s.get("runahead_prefill_dispatches_total", 0))
+        emit("decode_kv_tokens_read_total", "counter",
+             s.get("decode_kv_tokens_read_total", 0),
+             "KV tokens the decoded tokens attended (min(context, window) each)")
+        emit("first_dispatches_total", "counter",
+             s.get("first_dispatches_total", 0),
+             "step-program shapes dispatched for the first time in this process")
+        emit("first_dispatch_seconds_total", "counter",
+             s.get("first_dispatch_seconds_total", 0.0),
+             "wall seconds the engine stood at first dispatches")
+        for phase in ("trace", "lower", "compile", "run"):
+            emit(f"first_dispatch_{phase}_seconds_total", "counter",
+                 s.get(f"first_dispatch_{phase}_seconds_total", 0.0))
         for k in sorted(s):  # kv offload / transfer / spec / warm-start / loop
-            if k.startswith(("kv_", "spec_decode_", "engine_loop_", "warm_start_")):
+            if k.startswith(("kv_", "spec_decode_", "engine_loop_", "engine_dispatch_",
+                             "warm_start_")):
                 kind = "counter" if k.endswith("_total") else "gauge"
                 emit(k, kind, s[k])
         # TTFT hop breakdown for streaming requests (accept->submit->first
@@ -1054,6 +1067,39 @@ class EngineServer:
 
         payload, status = flightrecorder.export_for_query(request.query)
         return web.json_response(payload, status=status)
+
+    async def profile_start(self, request: web.Request) -> web.Response:
+        """Start the device profiler in this process (debug surface;
+        docs/tracing.md). Body: {"dir": <directory the trace is written
+        under>}. The program's ``pstpu.*`` host spans switch on with it."""
+        from production_stack_tpu.tracing import profiler
+
+        try:
+            log_dir = str((await request.json())["dir"])
+        except (ValueError, KeyError, TypeError):
+            return web.json_response(
+                {"error": {"message": 'body must be {"dir": "<path>"}'}},
+                status=400,
+            )
+        try:
+            await asyncio.get_running_loop().run_in_executor(
+                None, profiler.start, log_dir
+            )
+        except RuntimeError as e:
+            return web.json_response({"error": {"message": str(e)}}, status=409)
+        return web.json_response({"ok": True, "dir": log_dir})
+
+    async def profile_stop(self, request: web.Request) -> web.Response:
+        """Stop the profiler and write the trace; answers {"stop_s", "path"}."""
+        from production_stack_tpu.tracing import profiler
+
+        try:
+            done = await asyncio.get_running_loop().run_in_executor(
+                None, profiler.stop
+            )
+        except RuntimeError as e:
+            return web.json_response({"error": {"message": str(e)}}, status=409)
+        return web.json_response({"ok": True, **done})
 
     async def stats(self, request: web.Request) -> web.Response:
         """JSON engine state snapshot (saturation, queue depths, KV pool,
@@ -1951,6 +1997,9 @@ class EngineServer:
             r.add_get("/v1/traces", self.traces)
             r.add_get("/v1/debug/flightrecorder", self.flightrecorder)
             r.add_post("/metrics/reset", self.metrics_reset)
+            # the device profiler writes wherever the caller says: debug only
+            r.add_post("/v1/debug/profile/start", self.profile_start)
+            r.add_post("/v1/debug/profile/stop", self.profile_stop)
         r.add_post("/abort", self.abort)
         # live sequence migration (docs/migration.md): registered even when
         # --no-migration (handlers answer 501) so the wire surface — and the

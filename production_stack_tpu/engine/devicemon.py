@@ -48,22 +48,64 @@ logger = init_logger(__name__)
 
 # -- JAX compile listener -----------------------------------------------------
 #
-# jax.monitoring fires '/jax/core/compile/backend_compile_duration' once per
-# XLA backend compile. One process-global listener accumulates the totals and
-# mirrors each event into the flight recorder, so a compile stall shows up in
-# an anomaly dump next to the scheduler events it starved.
+# jax.monitoring reports, on the thread that dispatches, how long a jitted
+# call spent tracing the Python function, lowering the jaxpr to an MLIR module,
+# and in the backend compile (a real compile or a load from the persistent
+# cache alike). One process-global listener accumulates the backend-compile
+# totals and mirrors each into the flight recorder, so a compile stall shows up
+# in an anomaly dump next to the scheduler events it starved; while a
+# ``capture_first_dispatch()`` is open on the calling thread it also splits
+# that call's wall by phase (engine/runner.py ``_dispatch``).
 
 _compile_lock = threading.Lock()
 _compile_seconds_total = 0.0
 _compile_events_total = 0
 _listener_installed = False
 
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_PHASE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_capture = threading.local()
+
+
+class capture_first_dispatch:
+    """``with capture_first_dispatch() as phases:`` around ONE jitted call:
+    ``phases`` ends up holding the seconds JAX reported on this thread for
+    ``trace`` / ``lower`` / ``compile`` and the persistent cache's
+    ``cache_hits`` / ``cache_misses`` (a miss is counted when the entry is
+    written). Zeros when the monitoring API is unavailable."""
+
+    def __enter__(self) -> dict:
+        _capture.phases = phases = {
+            "trace": 0.0, "lower": 0.0, "compile": 0.0,
+            "cache_hits": 0, "cache_misses": 0,
+        }
+        return phases
+
+    def __exit__(self, *exc) -> None:
+        _capture.phases = None
 
 
 def _on_event_duration(name: str, duration: float, **_kw) -> None:
     global _compile_seconds_total, _compile_events_total
-    if name != _COMPILE_EVENT:
+    phase = _PHASE_OF_EVENT.get(name)
+    if phase is None:
+        return
+    phases = getattr(_capture, "phases", None)
+    if phases is not None:
+        if phase == "trace":
+            # jitted helpers called by the step function trace INSIDE its
+            # trace and report first: the outermost duration holds them all
+            phases["trace"] = max(phases["trace"], duration)
+        else:
+            phases[phase] += duration
+    if phase != "compile":
         return
     with _compile_lock:
         _compile_seconds_total += duration
@@ -78,10 +120,17 @@ def _on_event_duration(name: str, duration: float, **_kw) -> None:
         pass
 
 
+def _on_event(name: str, **_kw) -> None:
+    key = _CACHE_EVENTS.get(name)
+    phases = getattr(_capture, "phases", None)
+    if key is not None and phases is not None:
+        phases[key] += 1
+
+
 def install_compile_listener() -> bool:
-    """Register the jax.monitoring duration listener once per process.
-    Idempotent; returns whether the listener is active (False when JAX's
-    monitoring API is unavailable — telemetry then reports zeros)."""
+    """Register the jax.monitoring listeners once per process. Idempotent;
+    returns whether they are active (False when JAX's monitoring API is
+    unavailable — telemetry then reports zeros)."""
     global _listener_installed
     if _listener_installed:
         return True
@@ -89,6 +138,7 @@ def install_compile_listener() -> bool:
         import jax.monitoring as monitoring
 
         monitoring.register_event_duration_secs_listener(_on_event_duration)
+        monitoring.register_event_listener(_on_event)
     except Exception as e:  # noqa: BLE001 - monitoring API may be absent
         logger.warning("jax compile telemetry unavailable (%s)", e)
         return False

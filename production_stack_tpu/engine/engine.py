@@ -31,9 +31,62 @@ from production_stack_tpu.engine.runner import (
 from production_stack_tpu.engine.lora import LoRAManager
 from production_stack_tpu.engine.scheduler import SamplingParams, ScheduledBatch, Scheduler, Sequence
 from production_stack_tpu.engine.tokenizer import load_tokenizer
+from production_stack_tpu.tracing import profiler
 from production_stack_tpu.utils.logging import init_logger
 
 logger = init_logger(__name__)
+
+
+class _LoopSection:
+    """``with engine._section(name, **attrs):`` — one section of the engine
+    loop. On exit its wall seconds go into ``loop_seconds[name]`` (and stay in
+    ``.seconds``); while a profile runs it is also the span
+    ``pstpu.loop.<name>`` in the profiler's trace (tracing/profiler.py).
+
+    ``apply`` and ``emit`` also run INSIDE a dispatch (a chained decode applies
+    each fetched group while later bursts still compute): what they book while
+    another section is open is taken off that section, so ``wait``,
+    ``schedule``, ``step``, ``apply`` and ``emit`` are disjoint and sum to the
+    loop's wall. ``stage``, ``runahead``, ``chain_dispatch`` and
+    ``chain_fetch`` are parts of ``step``: they nest inside it and it keeps
+    their seconds."""
+
+    __slots__ = ("_secs", "_name", "_span", "_t0", "_inner0", "seconds")
+
+    def __init__(self, secs: dict, name: str, attrs: dict):
+        self._secs, self._name = secs, name
+        self._span = profiler.span("pstpu.loop." + name, **attrs)
+        self.seconds = 0.0
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._inner0 = self._secs["apply"] + self._secs["emit"]
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        secs = self._secs
+        inner = secs["apply"] + secs["emit"] - self._inner0
+        self.seconds = time.perf_counter() - self._t0 - inner
+        secs[self._name] += self.seconds
+        self._span.__exit__(*exc)
+        return False
+
+
+def _kv_tokens_read(kv_len, steps, window) -> int:
+    """KV tokens that ``steps`` consecutive decode tokens attend, the first at
+    a context of ``kv_len`` tokens (itself included), each capped by the
+    sliding window: sum of min(c, window) for c in kv_len .. kv_len+steps-1.
+    Arrays in, one integer out."""
+    kv_len = np.asarray(kv_len, np.int64)
+    steps = np.maximum(np.asarray(steps, np.int64), 0)
+    last = kv_len + steps - 1
+    total = steps * (kv_len + last) // 2
+    if window:
+        over_first = np.maximum(kv_len, window + 1)
+        n_over = np.maximum(last - over_first + 1, 0)
+        total = total - n_over * ((over_first - window) + (last - window)) // 2
+    return int(total.sum())
 
 
 @dataclasses.dataclass
@@ -559,6 +612,11 @@ class LLMEngine:
         # (run-ahead): the device queued them behind the chain instead of
         # idling through its fetch + scheduling turnaround
         self.runahead_prefill_dispatches_total = 0
+        # work per decode dispatch, counted from the batch the scheduler
+        # built (host numpy, no device read): KV tokens the decoded tokens
+        # attend (min(context, sliding window) each — what a decode-attention
+        # roofline prices)
+        self.decode_kv_tokens_read_total = 0
         # engine steps that raised (device thread is the only writer), and
         # the first step program that failed to BUILD (runner.
         # ProgramBuildError): every later batch of that shape fails the same
@@ -604,7 +662,10 @@ class LLMEngine:
         self.loop_seconds = {
             "wait": 0.0, "schedule": 0.0, "step": 0.0, "apply": 0.0,
             "emit": 0.0, "chain_dispatch": 0.0, "chain_fetch": 0.0,
+            "stage": 0.0, "runahead": 0.0,
         }
+        # the runner books its host->device staging into the same accounting
+        self.runner.section = self._section
         # per-request SLO accounting (ISSUE 7 tentpole b): every finished
         # sequence appends a terminal record (queue wait, TTFT, tokens,
         # inter-token p99, KV pages peak, outcome) to this bounded log; the
@@ -1123,240 +1184,41 @@ class LLMEngine:
                 time.sleep(0.05)
                 self._drain_inbox(block=False)
                 continue
-            t_sec = time.perf_counter()
-            self._drain_inbox(block=not self.scheduler.has_work())
-            self._shed_expired()  # queue-deadline load shedding
-            if self.warm is not None:
-                # periodic warm-start manifest (crash protection): prefers
-                # idle loop iterations, forced past 2x the interval
-                self.warm.maybe_spill(busy=self.scheduler.has_work())
-            # adaptive chain depth inputs: the scheduler caps chained bursts
-            # so the expected number of arrivals stuck waiting behind a chain
-            # stays below ~half a request (scheduler.schedule)
-            self.scheduler.arrival_rate = self._recent_arrival_rate()
-            self.scheduler.burst_seconds = self._burst_seconds
-            self.scheduler.last_arrival_age = (
-                time.monotonic() - self._arrival_times[-1]
-                if self._arrival_times else float("inf")
-            )
-            t0 = time.perf_counter()
-            self.loop_seconds["wait"] += t0 - t_sec
-            batch = self.scheduler.schedule()
-            self.loop_seconds["schedule"] += time.perf_counter() - t0
+            with self._section("wait"):
+                self._drain_inbox(block=not self.scheduler.has_work())
+                self._shed_expired()  # queue-deadline load shedding
+                if self.warm is not None:
+                    # periodic warm-start manifest (crash protection): prefers
+                    # idle loop iterations, forced past 2x the interval
+                    self.warm.maybe_spill(busy=self.scheduler.has_work())
+                # adaptive chain depth inputs: the scheduler caps chained
+                # bursts so the expected number of arrivals stuck waiting
+                # behind a chain stays below ~half a request
+                # (scheduler.schedule)
+                self.scheduler.arrival_rate = self._recent_arrival_rate()
+                self.scheduler.burst_seconds = self._burst_seconds
+                self.scheduler.last_arrival_age = (
+                    time.monotonic() - self._arrival_times[-1]
+                    if self._arrival_times else float("inf")
+                )
+            with self._section("schedule"):  # the scheduler's decision alone
+                batch = self.scheduler.schedule()
             if batch is None:
                 continue
-            self._record_sched_event(batch)
-            if batch.kind == "prefill":
-                self._note_first_dispatch(batch)
-            fetched = True
-            lp_data = None  # (chosen [B, cols], top_ids, top_lp [B, cols, K])
-            t_step = time.perf_counter()
-            # apply/emit seconds booked inline (incremental chained fetch)
-            # this iteration — excluded from the step/chain_fetch sections
-            # so the loop_seconds breakdown stays disjoint and sums to wall
-            inline_ae = 0.0
+            # apply/emit seconds booked inside the dispatch (incremental
+            # chained fetch) are taken off the enclosing sections, so wait +
+            # schedule + step + apply + emit stay disjoint and sum to the
+            # loop's wall (_LoopSection)
+            step = self._section(
+                "step", **(self._dispatch_attrs(batch) if profiler.active() else {})
+            )
             try:
-                inp = StepInput(
-                    batch.input_ids, batch.positions, batch.page_table,
-                    batch.kv_lens, batch.temperature, batch.top_k, batch.top_p,
-                    lora_ids=batch.lora_ids, kv_limits=batch.kv_limits,
-                )
-                if batch.want_penalties:
-                    inp.history = batch.history
-                    inp.prompt_lens = batch.prompt_lens
-                    inp.presence = np.array(
-                        [s.params.presence_penalty for s in batch.seqs]
-                        + [0.0] * (len(batch.kv_lens) - len(batch.seqs)),
-                        np.float32,
-                    )
-                    inp.frequency = np.array(
-                        [s.params.frequency_penalty for s in batch.seqs]
-                        + [0.0] * (len(batch.kv_lens) - len(batch.seqs)),
-                        np.float32,
-                    )
-                    inp.repetition = np.array(
-                        [s.params.repetition_penalty for s in batch.seqs]
-                        + [1.0] * (len(batch.kv_lens) - len(batch.seqs)),
-                        np.float32,
-                    )
-                # rows still under their min_tokens floor get EOS masked out
-                # of the sampled distribution (vLLM semantics — suppressing
-                # only the FINISH would feed a sampled EOS back into the
-                # context and derail the continuation). Conservative within
-                # a dispatch: the ban holds for ALL the tokens one dispatch
-                # covers, and the scheduler caps chaining for rows near the
-                # floor (scheduler.schedule), so the overshoot stays
-                # < decode_steps regardless of pipeline depth; the
-                # scheduler's finish gate stays as the exact backstop.
-                eos = self.tokenizer.eos_token_id
-                def _eos_ban(s):
-                    return (
-                        not s.params.ignore_eos
-                        and len(s.output_ids) < s.params.min_tokens
-                    )
-                if any(s.params.logit_bias or _eos_ban(s) for s in batch.seqs):
-                    B = len(batch.kv_lens)
-                    # bucket the bias width so a batch's entry count doesn't
-                    # mint a fresh program variant per distinct size
-                    need = max(
-                        len(s.params.logit_bias or {}) + (1 if _eos_ban(s) else 0)
-                        for s in batch.seqs
-                    )
-                    K = 8
-                    while K < need:
-                        K *= 2
-                    V = self.model_cfg.vocab_size
-                    # out-of-range sentinel V drops unused slots on device
-                    bias_ids = np.full((B, K), V, np.int32)
-                    bias_vals = np.zeros((B, K), np.float32)
-                    for i, s in enumerate(batch.seqs):
-                        j = 0
-                        for tid, bv in (s.params.logit_bias or {}).items():
-                            bias_ids[i, j] = tid
-                            bias_vals[i, j] = bv
-                            j += 1
-                        if _eos_ban(s):
-                            bias_ids[i, j] = eos
-                            bias_vals[i, j] = -1e9
-                    inp.bias_ids, inp.bias_vals = bias_ids, bias_vals
-                if (
-                    batch.kind == "decode"
-                    and self.scheduler.spec_k
-                    and batch.history is not None
-                ):
-                    tokens = np.asarray(
-                        self.runner.step_spec(
-                            inp, batch.history, self.scheduler.decode_steps,
-                            self.scheduler.spec_k, self.scheduler.spec_ngram,
-                        )
-                    )  # [B, steps, 1+spec_k], -1 padded
-                    emitted = tokens >= 0
-                    rounds = int(emitted.any(axis=2).sum())
-                    self.spec_draft_tokens += rounds * self.scheduler.spec_k
-                    # each round emits its accepted drafts plus one bonus token
-                    self.spec_accepted_tokens += int(emitted.sum()) - rounds
-                elif batch.kind == "decode" and self.scheduler.decode_steps > 1:
-                    wlp = batch.want_logprobs
-                    self.decode_dispatches_total += 1
-                    if batch.bursts > 1:
-                        self.decode_chained_dispatches_total += 1
-                        t_chain = time.perf_counter()
-                        # chained bursts: all dispatches go out before any
-                        # fetch, so the chain costs bursts*compute + 1 round
-                        # trip for the LAST burst only.
-                        devs = self.runner.step_multi_pipelined(
-                            inp, self.scheduler.decode_steps, batch.bursts,
-                            wlp,
-                            # grouped on-device concat + eager host copy at
-                            # each 4-burst boundary (see runner docstring);
-                            # the logprobs path still fetches whole-chain
-                            fetch_group=0 if wlp else 4,
-                        )
-                        t_disp = time.perf_counter()
-                        self.loop_seconds["chain_dispatch"] += t_disp - t_chain
-                        import jax.numpy as jnp
-
-                        if wlp:
-                            import jax
-
-                            # one pytree fetch: device_get starts all four
-                            # copies together (~1 RTT), where sequential
-                            # np.asarray calls would pay one RTT each
-                            tokens, *lps = jax.device_get((
-                                jnp.concatenate([d[0] for d in devs], axis=1),
-                                *(jnp.concatenate([d[1][x] for d in devs], axis=1)
-                                  for x in range(3)),
-                            ))
-                            lp_data = tuple(lps)
-                        else:
-                            # incremental grouped fetch: the runner already
-                            # enqueued each group's on-device concat at its
-                            # burst boundary and started its host copy, so
-                            # group j's tokens stream back while groups
-                            # j+1.. still compute — the fetch RTT (and the
-                            # ~50 ms per-RPC floor, amortized 4x) hides
-                            # inside the chain's own compute, and clients
-                            # get a chunk per group instead of one
-                            # chain-sized batch. Applying group j before
-                            # j+1 lands is safe: a row that finishes
-                            # (EOS/stop) keeps computing masked/discarded
-                            # tokens, its freed pages cannot be reallocated
-                            # until the next schedule() (this thread), and
-                            # the garbage tokens write past the region the
-                            # prefix cache registered.
-                            gcats = devs
-                            # run-ahead: admit fresh arrivals and dispatch
-                            # their prefill chunks NOW — the device queues
-                            # them straight behind the chain's bursts
-                            # instead of idling through the chain's fetch +
-                            # scheduling turnaround. Aborts are deferred
-                            # (see _drain_inbox) so no page freed under the
-                            # in-flight chain can be re-allocated here.
-                            ra_done, ra_inter = self._runahead_prefills(batch)
-                            ae0 = (self.loop_seconds["apply"]
-                                   + self.loop_seconds["emit"])
-                            for c in gcats:
-                                self._apply_and_emit(batch, np.asarray(c))
-                            # the chain's fetches retire dispatches QUEUED
-                            # BEFORE the chain; run-ahead intermediates came
-                            # after, so they stay suspect until the next
-                            # fetch unless a run-ahead final fetch follows
-                            self._unfetched = ra_inter
-                            for ra, ids in ra_done:
-                                self._apply_and_emit(ra, np.asarray(ids))
-                            if ra_done:
-                                self._unfetched = []
-                            inline_ae = (
-                                self.loop_seconds["apply"]
-                                + self.loop_seconds["emit"] - ae0
-                            )
-                            fetched = False  # retirement handled above
-                            tokens = None  # processed inline
-                        self.loop_seconds["chain_fetch"] += (
-                            time.perf_counter() - t_disp - inline_ae
-                        )
-                        # per-burst wall time EMA (includes fetch + apply +
-                        # emit amortized over the chain — a mild
-                        # overestimate, erring toward shorter chains and so
-                        # better TTFT under arrivals)
-                        dt = (time.perf_counter() - t_chain) / batch.bursts
-                        self._burst_seconds = (
-                            0.7 * self._burst_seconds + 0.3 * dt
-                        )
-                    elif wlp:
-                        toks, lps = self.runner.step_multi(
-                            inp, self.scheduler.decode_steps, True
-                        )
-                        tokens = np.asarray(toks)
-                        lp_data = tuple(np.asarray(x) for x in lps)
-                    else:
-                        tokens = np.asarray(
-                            self.runner.step_multi(inp, self.scheduler.decode_steps)
-                        )  # [B, k]
-                elif batch.kind == "prefill" and not any(
-                    s.num_computed + c >= len(s.prompt_ids)
-                    for s, c in zip(batch.seqs, batch.chunk_sizes)
-                ):
-                    # every chunk in this step is intermediate — nobody's
-                    # prompt completes, so the sampled tokens are discarded
-                    # anyway. Dispatch async and skip the host fetch, so an
-                    # N-chunk prefill costs N*compute + 1 fetch instead of
-                    # N*(compute + fetch) (the fetch's cost on a directly
-                    # attached chip is not measured). A deferred device error
-                    # surfaces at the next fetched step; _unfetched records
-                    # whose KV state is then suspect so the handler can abort
-                    # them too, not just the batch it surfaced on.
-                    self.runner.step(inp)
-                    self._unfetched.append(batch)
-                    fetched = False
-                    tokens = np.full((len(batch.seqs),), -1, np.int32)
-                elif batch.want_logprobs:
-                    ids, _, lps = self.runner.step(inp, want_logprobs=True)
-                    tokens = np.asarray(ids)
-                    lp_data = tuple(np.asarray(x)[:, None] for x in lps)
-                else:
-                    ids, _ = self.runner.step(inp)
-                    tokens = np.asarray(ids)
+                with step:
+                    self._record_sched_event(batch)
+                    if batch.kind == "prefill":
+                        self._note_first_dispatch(batch)
+                    work = self._count_work(batch)
+                    tokens, lp_data, fetched = self._dispatch_batch(batch)
             except Exception as step_err:
                 logger.exception("engine step failed; aborting batch")
                 self.step_errors_total += 1
@@ -1398,8 +1260,7 @@ class LLMEngine:
                         self.scheduler._finish(s, "error")
                         self._emit(s, "", error=True)
                 continue
-            step_wall = time.perf_counter() - t_step - inline_ae
-            self.loop_seconds["step"] += step_wall
+            step_wall = step.seconds
             if self._fr.enabled:
                 # runner step timing, dispatch-granular: a fetched step's
                 # wall is real device time; a skip-fetch dispatch's wall is
@@ -1407,7 +1268,7 @@ class LLMEngine:
                 self._fr.record(
                     "step", step=self.step_idx, batch_kind=batch.kind,
                     wall_ms=round(step_wall * 1000, 3), bursts=batch.bursts,
-                    fetched=fetched,
+                    fetched=fetched, **work,
                 )
             if fetched:
                 self._unfetched.clear()  # a real fetch retires prior dispatches
@@ -1431,6 +1292,249 @@ class LLMEngine:
             if tokens is not None:
                 self._apply_and_emit(batch, tokens, lp_data)
         logger.info("engine loop exited")
+
+    def _section(self, name: str, **attrs) -> _LoopSection:
+        return _LoopSection(self.loop_seconds, name, attrs)
+
+    def _dispatch_attrs(self, batch) -> dict:
+        """What the scheduler knows of a dispatch, for its span in the
+        profiler's trace. A shape's FIRST dispatch shows as the span
+        ``pstpu.first_dispatch`` nested inside (runner._dispatch)."""
+        decode, sched = batch.kind == "decode", self.scheduler
+        return {
+            "kind": batch.kind,
+            # the branch _dispatch_batch takes
+            "family": (
+                "spec_step" if decode and sched.spec_k and batch.history is not None
+                else "multi_step" if decode and sched.decode_steps > 1
+                else "step"
+            ),
+            "rows": len(batch.seqs),
+            "chunk": int(batch.input_ids.shape[1]),
+            "pages": int(batch.page_table.shape[1]),
+            "bursts": batch.bursts,
+            "step": self.step_idx,
+        }
+
+    def _count_work(self, batch) -> dict:
+        """The dispatch's work, for the flight recorder's ``step`` event; a
+        decode's KV tokens read are also added to the total."""
+        n = len(batch.seqs)
+        if batch.kind == "prefill":
+            return {"prefill_tokens": int(sum(batch.chunk_sizes))}
+        steps = max(1, self.scheduler.decode_steps) * batch.bursts
+        kv_len = batch.kv_lens[:n]
+        if batch.kv_limits is not None:
+            # a row decodes while its KV length stays under its limit
+            steps = np.minimum(steps, batch.kv_limits[:n] - kv_len + 1)
+        read = _kv_tokens_read(
+            kv_len, steps, getattr(self.model_cfg, "sliding_window", None)
+        )
+        self.decode_kv_tokens_read_total += read
+        return {"kv_tokens_read": read}
+
+    def _dispatch_batch(self, batch):
+        """Stage and dispatch one scheduled batch and fetch what the host
+        needs of it. Returns (tokens, lp_data, fetched): ``tokens`` is None
+        when a chained decode was applied and emitted inline, ``lp_data`` is
+        (chosen [B, cols], top_ids, top_lp [B, cols, K]) or None, and
+        ``fetched`` says whether a host fetch retired the earlier dispatches."""
+        fetched = True
+        lp_data = None
+        inp = StepInput(
+            batch.input_ids, batch.positions, batch.page_table,
+            batch.kv_lens, batch.temperature, batch.top_k, batch.top_p,
+            lora_ids=batch.lora_ids, kv_limits=batch.kv_limits,
+        )
+        if batch.want_penalties:
+            inp.history = batch.history
+            inp.prompt_lens = batch.prompt_lens
+            inp.presence = np.array(
+                [s.params.presence_penalty for s in batch.seqs]
+                + [0.0] * (len(batch.kv_lens) - len(batch.seqs)),
+                np.float32,
+            )
+            inp.frequency = np.array(
+                [s.params.frequency_penalty for s in batch.seqs]
+                + [0.0] * (len(batch.kv_lens) - len(batch.seqs)),
+                np.float32,
+            )
+            inp.repetition = np.array(
+                [s.params.repetition_penalty for s in batch.seqs]
+                + [1.0] * (len(batch.kv_lens) - len(batch.seqs)),
+                np.float32,
+            )
+        # rows still under their min_tokens floor get EOS masked out
+        # of the sampled distribution (vLLM semantics — suppressing
+        # only the FINISH would feed a sampled EOS back into the
+        # context and derail the continuation). Conservative within
+        # a dispatch: the ban holds for ALL the tokens one dispatch
+        # covers, and the scheduler caps chaining for rows near the
+        # floor (scheduler.schedule), so the overshoot stays
+        # < decode_steps regardless of pipeline depth; the
+        # scheduler's finish gate stays as the exact backstop.
+        eos = self.tokenizer.eos_token_id
+        def _eos_ban(s):
+            return (
+                not s.params.ignore_eos
+                and len(s.output_ids) < s.params.min_tokens
+            )
+        if any(s.params.logit_bias or _eos_ban(s) for s in batch.seqs):
+            B = len(batch.kv_lens)
+            # bucket the bias width so a batch's entry count doesn't
+            # mint a fresh program variant per distinct size
+            need = max(
+                len(s.params.logit_bias or {}) + (1 if _eos_ban(s) else 0)
+                for s in batch.seqs
+            )
+            K = 8
+            while K < need:
+                K *= 2
+            V = self.model_cfg.vocab_size
+            # out-of-range sentinel V drops unused slots on device
+            bias_ids = np.full((B, K), V, np.int32)
+            bias_vals = np.zeros((B, K), np.float32)
+            for i, s in enumerate(batch.seqs):
+                j = 0
+                for tid, bv in (s.params.logit_bias or {}).items():
+                    bias_ids[i, j] = tid
+                    bias_vals[i, j] = bv
+                    j += 1
+                if _eos_ban(s):
+                    bias_ids[i, j] = eos
+                    bias_vals[i, j] = -1e9
+            inp.bias_ids, inp.bias_vals = bias_ids, bias_vals
+        if (
+            batch.kind == "decode"
+            and self.scheduler.spec_k
+            and batch.history is not None
+        ):
+            tokens = np.asarray(
+                self.runner.step_spec(
+                    inp, batch.history, self.scheduler.decode_steps,
+                    self.scheduler.spec_k, self.scheduler.spec_ngram,
+                )
+            )  # [B, steps, 1+spec_k], -1 padded
+            emitted = tokens >= 0
+            rounds = int(emitted.any(axis=2).sum())
+            self.spec_draft_tokens += rounds * self.scheduler.spec_k
+            # each round emits its accepted drafts plus one bonus token
+            self.spec_accepted_tokens += int(emitted.sum()) - rounds
+        elif batch.kind == "decode" and self.scheduler.decode_steps > 1:
+            wlp = batch.want_logprobs
+            self.decode_dispatches_total += 1
+            if batch.bursts > 1:
+                self.decode_chained_dispatches_total += 1
+                t_chain = time.perf_counter()
+                # chained bursts: all dispatches go out before any
+                # fetch, so the chain costs bursts*compute + 1 round
+                # trip for the LAST burst only.
+                with self._section("chain_dispatch"):
+                    devs = self.runner.step_multi_pipelined(
+                        inp, self.scheduler.decode_steps, batch.bursts,
+                        wlp,
+                        # grouped on-device concat + eager host copy at
+                        # each 4-burst boundary (see runner docstring);
+                        # the logprobs path still fetches whole-chain
+                        fetch_group=0 if wlp else 4,
+                    )
+                with self._section("chain_fetch"):
+                    import jax.numpy as jnp
+
+                    if wlp:
+                        import jax
+
+                        # one pytree fetch: device_get starts all four
+                        # copies together (~1 RTT), where sequential
+                        # np.asarray calls would pay one RTT each
+                        tokens, *lps = jax.device_get((
+                            jnp.concatenate([d[0] for d in devs], axis=1),
+                            *(jnp.concatenate([d[1][x] for d in devs], axis=1)
+                              for x in range(3)),
+                        ))
+                        lp_data = tuple(lps)
+                    else:
+                        # incremental grouped fetch: the runner already
+                        # enqueued each group's on-device concat at its
+                        # burst boundary and started its host copy, so
+                        # group j's tokens stream back while groups
+                        # j+1.. still compute — the fetch RTT (and the
+                        # ~50 ms per-RPC floor, amortized 4x) hides
+                        # inside the chain's own compute, and clients
+                        # get a chunk per group instead of one
+                        # chain-sized batch. Applying group j before
+                        # j+1 lands is safe: a row that finishes
+                        # (EOS/stop) keeps computing masked/discarded
+                        # tokens, its freed pages cannot be reallocated
+                        # until the next schedule() (this thread), and
+                        # the garbage tokens write past the region the
+                        # prefix cache registered.
+                        gcats = devs
+                        # run-ahead: admit fresh arrivals and dispatch
+                        # their prefill chunks NOW — the device queues
+                        # them straight behind the chain's bursts
+                        # instead of idling through the chain's fetch +
+                        # scheduling turnaround. Aborts are deferred
+                        # (see _drain_inbox) so no page freed under the
+                        # in-flight chain can be re-allocated here.
+                        with self._section("runahead"):
+                            ra_done, ra_inter = self._runahead_prefills(batch)
+                        for c in gcats:
+                            self._apply_and_emit(batch, np.asarray(c))
+                        # the chain's fetches retire dispatches QUEUED
+                        # BEFORE the chain; run-ahead intermediates came
+                        # after, so they stay suspect until the next
+                        # fetch unless a run-ahead final fetch follows
+                        self._unfetched = ra_inter
+                        for ra, ids in ra_done:
+                            self._apply_and_emit(ra, np.asarray(ids))
+                        if ra_done:
+                            self._unfetched = []
+                        fetched = False  # retirement handled above
+                        tokens = None  # processed inline
+                # per-burst wall time EMA (includes fetch + apply +
+                # emit amortized over the chain — a mild
+                # overestimate, erring toward shorter chains and so
+                # better TTFT under arrivals)
+                dt = (time.perf_counter() - t_chain) / batch.bursts
+                self._burst_seconds = (
+                    0.7 * self._burst_seconds + 0.3 * dt
+                )
+            elif wlp:
+                toks, lps = self.runner.step_multi(
+                    inp, self.scheduler.decode_steps, True
+                )
+                tokens = np.asarray(toks)
+                lp_data = tuple(np.asarray(x) for x in lps)
+            else:
+                tokens = np.asarray(
+                    self.runner.step_multi(inp, self.scheduler.decode_steps)
+                )  # [B, k]
+        elif batch.kind == "prefill" and not any(
+            s.num_computed + c >= len(s.prompt_ids)
+            for s, c in zip(batch.seqs, batch.chunk_sizes)
+        ):
+            # every chunk in this step is intermediate — nobody's
+            # prompt completes, so the sampled tokens are discarded
+            # anyway. Dispatch async and skip the host fetch, so an
+            # N-chunk prefill costs N*compute + 1 fetch instead of
+            # N*(compute + fetch) (the fetch's cost on a directly
+            # attached chip is not measured). A deferred device error
+            # surfaces at the next fetched step; _unfetched records
+            # whose KV state is then suspect so the handler can abort
+            # them too, not just the batch it surfaced on.
+            self.runner.step(inp)
+            self._unfetched.append(batch)
+            fetched = False
+            tokens = np.full((len(batch.seqs),), -1, np.int32)
+        elif batch.want_logprobs:
+            ids, _, lps = self.runner.step(inp, want_logprobs=True)
+            tokens = np.asarray(ids)
+            lp_data = tuple(np.asarray(x)[:, None] for x in lps)
+        else:
+            ids, _ = self.runner.step(inp)
+            tokens = np.asarray(ids)
+        return tokens, lp_data, fetched
 
     def _record_sched_event(self, batch) -> None:
         """Flight-recorder "sched" event: the batch composition and the
@@ -1535,23 +1639,26 @@ class LLMEngine:
         resulting deltas — called once per dispatch, or once per BURST for
         incrementally-fetched chains (the per-column apply is identical
         either way; scheduler.apply_step skips finished rows)."""
-        t_apply = time.perf_counter()
-        events = self.scheduler.apply_step(
-            batch, tokens, self.tokenizer.eos_token_id
-        )
-        if batch.kind == "prefill":
-            for s, c in zip(batch.seqs, batch.chunk_sizes):
-                self.total_prompt_tokens += c
-        if self._kv_sender is not None:
-            # ship KV before emitting the finish event: the prefill HTTP
-            # response must not return until the decode peer holds the KV
-            pushed = set()
-            for s, _, _, _ in events:
-                if s.finished and s.seq_id not in pushed:
-                    pushed.add(s.seq_id)
-                    self._push_finished_kv(s)
-        t_emit = time.perf_counter()
-        self.loop_seconds["apply"] += t_emit - t_apply
+        with self._section("apply"):
+            events = self.scheduler.apply_step(
+                batch, tokens, self.tokenizer.eos_token_id
+            )
+            if batch.kind == "prefill":
+                for s, c in zip(batch.seqs, batch.chunk_sizes):
+                    self.total_prompt_tokens += c
+            if self._kv_sender is not None:
+                # ship KV before emitting the finish event: the prefill HTTP
+                # response must not return until the decode peer holds the KV
+                pushed = set()
+                for s, _, _, _ in events:
+                    if s.finished and s.seq_id not in pushed:
+                        pushed.add(s.seq_id)
+                        self._push_finished_kv(s)
+        with self._section("emit"):
+            self._emit_events(events, lp_data)
+
+    def _emit_events(self, events, lp_data) -> None:
+        """Stream the applied tokens to their requests."""
         # group burst events per sequence: one RequestOutput per seq per
         # device step, carrying every new token (finished only on the
         # last, so consumers never drop trailing burst tokens)
@@ -1569,7 +1676,6 @@ class LLMEngine:
         for s, toks, lps in grouped.values():
             self.total_generation_tokens += len(toks)
             self._process_token(s, toks, lps or None)
-        self.loop_seconds["emit"] += time.perf_counter() - t_emit
 
     def _push_finished_kv(self, seq: Sequence) -> None:
         """Producer role: push every hashed page of a finished sequence to the
@@ -2368,9 +2474,22 @@ class LLMEngine:
             "runahead_prefill_dispatches_total": (
                 self.runahead_prefill_dispatches_total
             ),
+            "decode_kv_tokens_read_total": self.decode_kv_tokens_read_total,
         }
+        # first dispatches of step-program shapes (runner._dispatch): how
+        # many, their wall seconds, and the split trace / lower / compile
+        # (or cache load) / run (first execution and the rest)
+        fd = self.runner.first_dispatch
+        out["first_dispatches_total"] = fd["count"]
+        out["first_dispatch_seconds_total"] = round(fd["seconds"], 4)
+        for phase in ("trace", "lower", "compile", "run"):
+            out[f"first_dispatch_{phase}_seconds_total"] = round(fd[phase], 4)
         for section, secs in self.loop_seconds.items():
-            out[f"engine_loop_{section}_seconds_total"] = round(secs, 3)
+            # stage and runahead are parts of step that the loop did not
+            # separate before: under a prefix of their own, so that a reader
+            # summing engine_loop_* reads what it always did
+            prefix = "engine_dispatch" if section in ("stage", "runahead") else "engine_loop"
+            out[f"{prefix}_{section}_seconds_total"] = round(secs, 3)
         # interactive-SLO degradation signal for the fleet controller's
         # latency-protection policy (migration/controller.py): p99 over the
         # recent interactive ok-request window, 0.0 while idle

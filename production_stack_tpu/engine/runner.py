@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Any, Optional
 
 import jax
@@ -22,6 +23,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from production_stack_tpu import models
+from production_stack_tpu.engine import devicemon
 from production_stack_tpu.ops.attention import write_kv_pages_all_layers
 from production_stack_tpu.ops.sampling import (
     apply_logit_bias,
@@ -31,6 +33,7 @@ from production_stack_tpu.ops.sampling import (
 )
 from production_stack_tpu.parallel import shardings
 from production_stack_tpu.parallel.mesh import make_mesh
+from production_stack_tpu.tracing import get_flightrecorder, profiler
 from production_stack_tpu.utils.logging import init_logger
 
 
@@ -380,6 +383,15 @@ class ModelRunner:
         self._rep = NamedSharding(self.mesh, P())
         self._steps: dict[bool, Any] = {}  # want_logprobs -> jitted step
         self._ran: set = set()  # (family, sig, shapes) that dispatched once
+        # what first dispatches cost, by phase (engine stats() exports it)
+        self.first_dispatch = {
+            "count": 0, "seconds": 0.0,
+            "trace": 0.0, "lower": 0.0, "compile": 0.0, "run": 0.0,
+        }
+        # `with self.section("stage"):` books host->device staging into the
+        # engine loop's section accounting; the engine sets it, a runner on
+        # its own gets the profiler's span alone
+        self.section = lambda name: profiler.span("pstpu.loop." + name)
         self._set_page_fn = None  # built lazily in set_page
         self._get_page_fn = None  # built lazily in get_page (multi-host)
         self._get_pages_fns = {}  # batched offload spill, per id-count bucket
@@ -393,6 +405,10 @@ class ModelRunner:
     def _stage(self, inp: StepInput, with_limits: bool = False) -> dict:
         """Host→device staging shared by step/step_multi: split the RNG and
         device_put every input with the runner's shardings."""
+        with self.section("stage"):
+            return self._stage_inputs(inp, with_limits)
+
+    def _stage_inputs(self, inp: StepInput, with_limits: bool) -> dict:
         self._rng, key = jax.random.split(self._rng)
         if self.mesh.devices.size == 1:
             # single chip: hand numpy straight to the jitted call — one
@@ -448,38 +464,57 @@ class ModelRunner:
         return staged
 
     def _dispatch(self, fn, family: str, sig, s: dict, args: tuple):
-        """Call a jitted step program; a failure on a shape that has never
-        dispatched is a ProgramBuildError, not a per-batch fault."""
+        """Call a jitted step program. The first call of each (family, sig,
+        ids shape, pages shape) is timed to its result and split by phase; a
+        failure there is a ProgramBuildError, not a per-batch fault."""
         key = (family, sig, s["input_ids"].shape, s["page_table"].shape)
         if key in self._ran:
             return fn(*args)
+        devicemon.install_compile_listener()
+        ids_shape, pages_shape = list(key[2]), list(key[3])
+        t0 = time.perf_counter()
         try:
-            out = fn(*args)
+            with profiler.span(
+                "pstpu.first_dispatch", family=family, sig=repr(sig),
+                ids=str(ids_shape), pages=str(pages_shape),
+            ), devicemon.capture_first_dispatch() as phases:
+                out = jax.block_until_ready(fn(*args))
         except Exception as e:
             raise ProgramBuildError(
                 f"{family}{sig} ids{key[2]} pages{key[3]}: "
                 f"{type(e).__name__}: {str(e)[:2000]}"
             ) from e
+        wall = time.perf_counter() - t0
         self._ran.add(key)
-        return out
-
-    def _note_program_variant(self, family: str, sig) -> None:
-        """Flight-recorder marker at a jit-cache miss: a NEW program variant
-        is about to trace + compile (the actual XLA compile seconds land via
-        the jax.monitoring listener in engine/devicemon.py — this event ties
-        them to WHICH serving shape caused the compile). Steady-state serving
-        should record none of these; a stream of them mid-traffic means the
-        shape bucketing regressed and the engine is retracing."""
-        from production_stack_tpu.tracing import get_flightrecorder
-
+        # what JAX reported on this thread for the call; the rest is the
+        # first execution, the transfers and the executable's load
+        split = {p: phases[p] for p in ("trace", "lower", "compile")}
+        split["run"] = max(0.0, wall - sum(split.values()))
+        fd = self.first_dispatch
+        fd["count"] += 1
+        fd["seconds"] += wall
+        for p, secs in split.items():
+            fd[p] += secs
+        # ONE event per first dispatch ties the compile seconds (which the
+        # jax.monitoring listener records without a shape) to the serving
+        # shape that caused them. Steady-state serving records none: a stream
+        # of them mid-traffic means the set-up or the bucketing missed shapes.
         get_flightrecorder().record(
-            "compile", event="program_variant", family=family, sig=repr(sig)
+            "compile", event="first_dispatch", family=family, sig=repr(sig),
+            ids_shape=ids_shape, pages_shape=pages_shape,
+            seconds=round(wall, 4),
+            **{f"{p}_s": round(secs, 4) for p, secs in split.items()},
+            cache=(
+                "hit" if phases["cache_hits"]
+                else "miss" if phases["cache_misses"]
+                else "uncached" if phases["compile"] else "none"
+            ),
         )
+        return out
 
     def _get_step(self, want_lp: bool, want_pen: bool):
         sig = (want_lp, want_pen)
         if sig not in self._steps:
-            self._note_program_variant("step", sig)
             rep, n = self._rep, None
             outs = (rep, n, rep, rep, rep, n, n) if want_lp else (rep, n, n, n)
             donate = (1, 2)
@@ -487,8 +522,9 @@ class ModelRunner:
                 outs = outs + (n, n)  # updated scales pools
                 donate = (1, 2, 15)   # kv_scales tuple rides at arg 15
             self._steps[sig] = jax.jit(
-                functools.partial(
-                    _step_fn, self._forward, self.cfg, want_lp, want_pen
+                _named_program(
+                    "pstpu_step" + _flags(want_lp, want_pen),
+                    _step_fn, self._forward, self.cfg, want_lp, want_pen,
                 ),
                 donate_argnums=donate,
                 out_shardings=outs,
@@ -550,7 +586,6 @@ class ModelRunner:
         want_pen = "pen" in s
         sig = (k, want_logprobs, want_pen)
         if sig not in self._multi_steps:
-            self._note_program_variant("multi_step", sig)
             rep, n = self._rep, None
             outs = (
                 (rep, rep, rep, rep, rep, n, n)
@@ -566,9 +601,9 @@ class ModelRunner:
                 outs = outs + (n, n)
                 donate = (1, 2, 16)
             self._multi_steps[sig] = jax.jit(
-                functools.partial(
-                    fn, self._forward, self.cfg, k,
-                    want_logprobs, want_pen,
+                _named_program(
+                    f"pstpu_multi_step_k{k}" + _flags(want_logprobs, want_pen),
+                    fn, self._forward, self.cfg, k, want_logprobs, want_pen,
                 ),
                 donate_argnums=donate,
                 out_shardings=outs,
@@ -707,10 +742,10 @@ class ModelRunner:
             )
         sig = (steps, spec_k, ngram)
         if sig not in self._spec_fns:
-            self._note_program_variant("spec_step", sig)
             self._spec_fns[sig] = jax.jit(
-                functools.partial(
-                    _spec_fn, self._forward, self.cfg, steps, spec_k, ngram
+                _named_program(
+                    f"pstpu_spec_s{steps}_k{spec_k}_n{ngram}",
+                    _spec_fn, self._forward, self.cfg, steps, spec_k, ngram,
                 ),
                 donate_argnums=(1, 2),
                 out_shardings=(self._rep, None, None),
@@ -1269,6 +1304,21 @@ class ModelRunner:
             )
 
 
+def _named_program(name: str, fn, *static):
+    """``functools.partial(fn, *static)`` under a name: ``jax.jit`` calls the
+    program ``jit_<name>`` in the profiler's trace and in compile logs (a bare
+    partial is ``jit__unknown``). The name is part of the persistent compile
+    cache's key, so it holds the family and its static signature and nothing
+    that differs between processes (no id, no fingerprint)."""
+    program = functools.partial(fn, *static)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
+def _flags(want_lp: bool, want_pen: bool) -> str:
+    return ("_lp" if want_lp else "") + ("_pen" if want_pen else "")
+
+
 def _scales_pools(num_layers: int, num_pages: int, num_kv_heads: int):
     """K and V scales pools: two buffers (both are donated every step)."""
     from production_stack_tpu.ops.quant import init_kv_scales
@@ -1295,8 +1345,9 @@ def _multi_step_fn(forward, cfg, k, want_lp, want_pen, params, k_pages,
     pool_pages = k_pages.shape[1]
     page_size = k_pages.shape[2]
     flat = page_table.reshape(-1)
-    k_blk = jnp.take(k_pages, flat, axis=1)  # [L, B*P, page, KH, D]
-    v_blk = jnp.take(v_pages, flat, axis=1)
+    with jax.named_scope("kv_gather"):
+        k_blk = jnp.take(k_pages, flat, axis=1)  # [L, B*P, page, KH, D]
+        v_blk = jnp.take(v_pages, flat, axis=1)
     local_pt = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
     kw = {} if lora is None else {"lora": lora, "lora_ids": lora_ids}
     keys = jax.random.split(key, k)
@@ -1312,24 +1363,25 @@ def _multi_step_fn(forward, cfg, k, want_lp, want_pen, params, k_pages,
         logits, kp, vp = forward(
             params, cfg, ids, pos, kp, vp, local_pt, lens, **kw
         )
-        sample_from = logits
-        if want_pen:
-            sample_from = apply_penalties(
-                logits.astype(jnp.float32), hist, lens, plens, pres, freq, rep
-            )
-        if bias is not None:
-            sample_from = apply_logit_bias(
-                sample_from.astype(jnp.float32), *bias
-            )
-        if want_lp:
-            nxt, lp, tids, tlp = sample_with_logprobs(
-                logits, key_i, temperature, top_k, top_p,
-                sample_from=sample_from,
-            )
-            emit = (nxt, lp, tids, tlp)
-        else:
-            nxt = sample(sample_from, key_i, temperature, top_k, top_p)  # [B]
-            emit = nxt
+        with jax.named_scope("sample"):
+            sample_from = logits
+            if want_pen:
+                sample_from = apply_penalties(
+                    logits.astype(jnp.float32), hist, lens, plens, pres, freq, rep
+                )
+            if bias is not None:
+                sample_from = apply_logit_bias(
+                    sample_from.astype(jnp.float32), *bias
+                )
+            if want_lp:
+                nxt, lp, tids, tlp = sample_with_logprobs(
+                    logits, key_i, temperature, top_k, top_p,
+                    sample_from=sample_from,
+                )
+                emit = (nxt, lp, tids, tlp)
+            else:
+                nxt = sample(sample_from, key_i, temperature, top_k, top_p)  # [B]
+                emit = nxt
         if want_pen:
             # record this step's token at its absolute position so later
             # steps in the burst count it
@@ -1351,13 +1403,14 @@ def _multi_step_fn(forward, cfg, k, want_lp, want_pen, params, k_pages,
     # by each row, so no duplicate indices; everything else in the block is an
     # unmodified copy (incl. shared prefix pages and padding), dropped via an
     # out-of-range index.
-    p_idx = jnp.arange(P, dtype=jnp.int32)[None, :]
-    first = (kv_lens - 1) // page_size
-    last = (lens_f - 1) // page_size
-    written = (p_idx >= first[:, None]) & (p_idx <= last[:, None])
-    safe = jnp.where(written, page_table, pool_pages).reshape(-1)
-    k_pages = k_pages.at[:, safe].set(k_blk, mode="drop")
-    v_pages = v_pages.at[:, safe].set(v_blk, mode="drop")
+    with jax.named_scope("kv_commit"):
+        p_idx = jnp.arange(P, dtype=jnp.int32)[None, :]
+        first = (kv_lens - 1) // page_size
+        last = (lens_f - 1) // page_size
+        written = (p_idx >= first[:, None]) & (p_idx <= last[:, None])
+        safe = jnp.where(written, page_table, pool_pages).reshape(-1)
+        k_pages = k_pages.at[:, safe].set(k_blk, mode="drop")
+        v_pages = v_pages.at[:, safe].set(v_blk, mode="drop")
     # hist_f returns so chained bursts can feed it forward device-side
     # (penalty counts must include THIS burst's tokens at the next seam)
     if want_lp:
@@ -1410,24 +1463,25 @@ def _multi_step_deferred_fn(forward, cfg, k, want_lp, want_pen, params,
             params, cfg, ids, pos, k_pages, v_pages, page_table, lens,
             kv_burst=(ka, va, counts), **kw
         )
-        sample_from = logits
-        if want_pen:
-            sample_from = apply_penalties(
-                logits.astype(jnp.float32), hist, lens, plens, pres, freq, rep
-            )
-        if bias is not None:
-            sample_from = apply_logit_bias(
-                sample_from.astype(jnp.float32), *bias
-            )
-        if want_lp:
-            nxt, lp, tids, tlp = sample_with_logprobs(
-                logits, key_i, temperature, top_k, top_p,
-                sample_from=sample_from,
-            )
-            emit = (nxt, lp, tids, tlp)
-        else:
-            nxt = sample(sample_from, key_i, temperature, top_k, top_p)  # [B]
-            emit = nxt
+        with jax.named_scope("sample"):
+            sample_from = logits
+            if want_pen:
+                sample_from = apply_penalties(
+                    logits.astype(jnp.float32), hist, lens, plens, pres, freq, rep
+                )
+            if bias is not None:
+                sample_from = apply_logit_bias(
+                    sample_from.astype(jnp.float32), *bias
+                )
+            if want_lp:
+                nxt, lp, tids, tlp = sample_with_logprobs(
+                    logits, key_i, temperature, top_k, top_p,
+                    sample_from=sample_from,
+                )
+                emit = (nxt, lp, tids, tlp)
+            else:
+                nxt = sample(sample_from, key_i, temperature, top_k, top_p)  # [B]
+                emit = nxt
         if want_pen:
             slot = jnp.where(pos[:, 0] >= 0, lens, H)
             hist = hist.at[rows, slot].set(nxt, mode="drop")
@@ -1465,19 +1519,21 @@ def _multi_step_deferred_fn(forward, cfg, k, want_lp, want_pen, params,
         )
 
         k_scales, v_scales = kv_scales
-        k_pages, v_pages, k_scales, v_scales = write_kv_pages_all_layers_quant(
-            k_pages, v_pages, k_scales, v_scales, k_acc, v_acc,
-            page_table, commit_pos,
-        )
+        with jax.named_scope("kv_commit"):
+            k_pages, v_pages, k_scales, v_scales = write_kv_pages_all_layers_quant(
+                k_pages, v_pages, k_scales, v_scales, k_acc, v_acc,
+                page_table, commit_pos,
+            )
         if want_lp:
             _, lp, tids, tlp = emitted
             return (toks.T, lp.T, jnp.swapaxes(tids, 0, 1),
                     jnp.swapaxes(tlp, 0, 1), hist_f, k_pages, v_pages,
                     k_scales, v_scales)
         return toks.T, hist_f, k_pages, v_pages, k_scales, v_scales
-    k_pages, v_pages = write_kv_pages_all_layers(
-        k_pages, v_pages, k_acc, v_acc, page_table, commit_pos
-    )
+    with jax.named_scope("kv_commit"):
+        k_pages, v_pages = write_kv_pages_all_layers(
+            k_pages, v_pages, k_acc, v_acc, page_table, commit_pos
+        )
     if want_lp:
         _, lp, tids, tlp = emitted
         return (toks.T, lp.T, jnp.swapaxes(tids, 0, 1),
@@ -1529,8 +1585,9 @@ def _spec_fn(forward, cfg, steps, k, n, params, k_pages, v_pages, history,
     H = history.shape[1]
     T = 1 + k
     flat = page_table.reshape(-1)
-    k_blk = jnp.take(k_pages, flat, axis=1)
-    v_blk = jnp.take(v_pages, flat, axis=1)
+    with jax.named_scope("kv_gather"):
+        k_blk = jnp.take(k_pages, flat, axis=1)
+        v_blk = jnp.take(v_pages, flat, axis=1)
     local_pt = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
     kw = {} if lora is None else {"lora": lora, "lora_ids": lora_ids}
     keys = jax.random.split(key, steps)
@@ -1551,10 +1608,11 @@ def _spec_fn(forward, cfg, steps, k, n, params, k_pages, v_pages, history,
             params, cfg, seq_in, pos_in, kp, vp, local_pt, lens_in,
             all_logits=True, **kw
         )                                                                # [B, T, V]
-        t = sample(
-            logits.reshape(B * T, -1), key_i,
-            rep(temperature), rep(top_k), rep(top_p),
-        ).reshape(B, T)
+        with jax.named_scope("sample"):
+            t = sample(
+                logits.reshape(B * T, -1), key_i,
+                rep(temperature), rep(top_k), rep(top_p),
+            ).reshape(B, T)
         # exact rejection sampling for a deterministic draft: accept the
         # leading run of draft tokens the target also sampled; the first
         # mismatch IS the corrected token (and position k's sample is the
@@ -1579,13 +1637,14 @@ def _spec_fn(forward, cfg, steps, k, n, params, k_pages, v_pages, history,
     # scatter back the pages holding accepted tokens (stale tail beyond the
     # accepted length never needs to persist); same uniqueness argument as
     # _multi_step_fn: the written logical range covers only freshly-owned pages
-    p_idx = jnp.arange(P, dtype=jnp.int32)[None, :]
-    first = (kv_lens - 1) // page_size
-    last = (lens_f - 1) // page_size  # padded rows: lens_f=0 -> last=-1 -> no write
-    written = (p_idx >= first[:, None]) & (p_idx <= last[:, None])
-    safe = jnp.where(written, page_table, pool_pages).reshape(-1)
-    k_pages = k_pages.at[:, safe].set(k_blk, mode="drop")
-    v_pages = v_pages.at[:, safe].set(v_blk, mode="drop")
+    with jax.named_scope("kv_commit"):
+        p_idx = jnp.arange(P, dtype=jnp.int32)[None, :]
+        first = (kv_lens - 1) // page_size
+        last = (lens_f - 1) // page_size  # padded rows: lens_f=0 -> last=-1 -> no write
+        written = (p_idx >= first[:, None]) & (p_idx <= last[:, None])
+        safe = jnp.where(written, page_table, pool_pages).reshape(-1)
+        k_pages = k_pages.at[:, safe].set(k_blk, mode="drop")
+        v_pages = v_pages.at[:, safe].set(v_blk, mode="drop")
     return jnp.transpose(toks, (1, 0, 2)), k_pages, v_pages  # [B, steps, T]
 
 
@@ -1606,25 +1665,26 @@ def _step_fn(forward, cfg, want_lp, want_pen, params, k_pages, v_pages,
             params, cfg, input_ids, positions, k_pages, v_pages, page_table,
             kv_lens, **kw,
         )
-    sample_from = logits
-    if want_pen:
-        hist, plens, pres, freq, rep = pen
-        sample_from = apply_penalties(
-            logits.astype(jnp.float32), hist, kv_lens, plens, pres, freq, rep
-        )
-    if bias is not None:
-        sample_from = apply_logit_bias(
-            sample_from.astype(jnp.float32), *bias
-        )
-    if want_lp:
-        # logprobs report the RAW distribution; penalties shape the draw only
-        ids, lp, tids, tlp = sample_with_logprobs(
-            logits, key, temperature, top_k, top_p, sample_from=sample_from
-        )
+    with jax.named_scope("sample"):
+        sample_from = logits
+        if want_pen:
+            hist, plens, pres, freq, rep = pen
+            sample_from = apply_penalties(
+                logits.astype(jnp.float32), hist, kv_lens, plens, pres, freq, rep
+            )
+        if bias is not None:
+            sample_from = apply_logit_bias(
+                sample_from.astype(jnp.float32), *bias
+            )
+        if want_lp:
+            # logprobs report the RAW distribution; penalties shape the draw only
+            ids, lp, tids, tlp = sample_with_logprobs(
+                logits, key, temperature, top_k, top_p, sample_from=sample_from
+            )
+            if quant:
+                return ids, logits, lp, tids, tlp, k_pages, v_pages, k_sc, v_sc
+            return ids, logits, lp, tids, tlp, k_pages, v_pages
+        ids = sample(sample_from, key, temperature, top_k, top_p)
         if quant:
-            return ids, logits, lp, tids, tlp, k_pages, v_pages, k_sc, v_sc
-        return ids, logits, lp, tids, tlp, k_pages, v_pages
-    ids = sample(sample_from, key, temperature, top_k, top_p)
-    if quant:
-        return ids, logits, k_pages, v_pages, k_sc, v_sc
-    return ids, logits, k_pages, v_pages
+            return ids, logits, k_pages, v_pages, k_sc, v_sc
+        return ids, logits, k_pages, v_pages

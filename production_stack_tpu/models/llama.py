@@ -473,7 +473,8 @@ def forward(
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
     pp = mesh.shape.get("pp", 1) if mesh is not None else 1
     B, T = input_ids.shape
-    x = params["embed"][input_ids].astype(cfg.dtype)  # [B, T, H]
+    with jax.named_scope("embed"):
+        x = params["embed"][input_ids].astype(cfg.dtype)  # [B, T, H]
     if sp > 1 and T > 1:
         # sequence parallelism: spread the chunk's token dim over sp so the
         # norm/QKV/MLP FLOPs parallelize too, not just attention
@@ -610,168 +611,170 @@ def forward(
                 )
             return y
 
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv(h, lp, cfg, Bm, Tm, aux["cos"], aux["sin"], proj)
-        if burst:
-            # append the current token into the burst window at slot
-            # ``counts`` (entries 0..counts-1 hold earlier burst tokens);
-            # the window, not the pool, carries this burst's K/V
-            rows = jnp.arange(Bm, dtype=jnp.int32)
-            cnt = aux["burst_counts"]
-            kwin = ka.at[rows, cnt].set(k[:, 0].astype(ka.dtype))
-            vwin = va.at[rows, cnt].set(v[:, 0].astype(va.dtype))
-        if not post_write:
-            kp, vp = write_kv_pages(
-                kp, vp, k.astype(kp.dtype), v.astype(vp.dtype),
-                aux["page_table"], aux["positions"],
-            )
-        if Tm == 1 and cfg.attn_impl.startswith("pallas"):
-            # decode: stream pages HBM->VMEM, no gather materialization; in
-            # post mode the current token's K/V fold in from registers. On a
-            # multi-device dp x tp mesh the kernel runs per shard via
-            # shard_map (GSPMD cannot partition a pallas_call).
-            from production_stack_tpu.ops.pallas.paged_attention import (
-                ragged_paged_attention_decode,
-                ragged_paged_attention_decode_sharded,
-            )
-
-            # the in-register window stays fp under int8 pools — it is the
-            # quantizer's INPUT, committed by the post-scan quant scatter
-            cur_dt = cfg.dtype if quant else k_pages.dtype
+        with jax.named_scope("attention"):
+            h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            q, k, v = _qkv(h, lp, cfg, Bm, Tm, aux["cos"], aux["sin"], proj)
             if burst:
-                cur_kw = dict(
-                    k_cur=kwin, v_cur=vwin,
-                    cur_lens=aux["burst_counts"] + 1,
+                # append the current token into the burst window at slot
+                # ``counts`` (entries 0..counts-1 hold earlier burst tokens);
+                # the window, not the pool, carries this burst's K/V
+                rows = jnp.arange(Bm, dtype=jnp.int32)
+                cnt = aux["burst_counts"]
+                kwin = ka.at[rows, cnt].set(k[:, 0].astype(ka.dtype))
+                vwin = va.at[rows, cnt].set(v[:, 0].astype(va.dtype))
+            if not post_write:
+                kp, vp = write_kv_pages(
+                    kp, vp, k.astype(kp.dtype), v.astype(vp.dtype),
+                    aux["page_table"], aux["positions"],
                 )
-            elif post_write:
-                cur_kw = dict(
-                    k_cur=k[:, 0].astype(cur_dt),
-                    v_cur=v[:, 0].astype(cur_dt),
-                )
-            else:
-                cur_kw = dict(k_cur=None, v_cur=None)
-            pallas_kw = dict(
-                window=cfg.sliding_window,
-                interpret=cfg.attn_impl == "pallas_interpret",
-                pages_per_block=cfg.decode_pages_per_block or None,
-                prefetch_pages=cfg.decode_prefetch_pages or None,
-                **cur_kw,
-            )
-            if stream_pools:
-                pool_args = (k_pages, v_pages)
-                pallas_kw["layer"] = li
-                if quant:
-                    pallas_kw["k_scales"] = k_scales
-                    pallas_kw["v_scales"] = v_scales
-            else:
-                pool_args = (kp, vp)
-                if quant:
-                    pallas_kw["k_scales"] = ksl
-                    pallas_kw["v_scales"] = vsl
-            # under pp the kernel runs INSIDE the pipeline's manual region;
-            # the sharded call nests there and maps the remaining axes
-            if mesh is not None and mesh.devices.size > 1:
-                attn = ragged_paged_attention_decode_sharded(
-                    mesh, q[:, 0], *pool_args,
-                    aux["page_table"], aux["kv_lens"],
-                    **pallas_kw,
-                )[:, None]
-            else:
-                attn = ragged_paged_attention_decode(
-                    q[:, 0], *pool_args, aux["page_table"], aux["kv_lens"],
-                    **pallas_kw,
-                )[:, None]
-        elif (
-            Tm > 1
-            and cfg.attn_impl.startswith("pallas")
-            and stream_pools
-            and not burst
-        ):
-            # chunked prefill: pallas flash kernel streams pages HBM->VMEM
-            # (no [B, S, KH, D] pool gather) and folds the chunk's own K/V
-            # in-register — the XLA scan ran at <20% MFU at 16k context
-            # (ops/pallas/prefill_attention.py)
-            from production_stack_tpu.ops.pallas.prefill_attention import (
-                ragged_paged_attention_prefill,
-            )
-
-            chunk_dt = cfg.dtype if quant else k_pages.dtype
-            kernel_kw = dict(
-                window=cfg.sliding_window,
-                interpret=cfg.attn_impl == "pallas_interpret",
-                pages_per_block=getattr(cfg, "prefill_pages_per_block", 0)
-                or None,
-                prefetch_pages=getattr(cfg, "prefill_prefetch_pages", 0)
-                or None,
-                layer=li,
-            )
-            if quant:
-                kernel_kw["k_scales"] = ksc_c if fused_prefill else k_scales
-                kernel_kw["v_scales"] = vsc_c if fused_prefill else v_scales
-            kernel_args = (
-                q,
-                kp_c if fused_prefill else k_pages,
-                vp_c if fused_prefill else v_pages,
-                aux["page_table"], aux["positions"], aux["kv_lens"],
-                k.astype(chunk_dt), v.astype(chunk_dt),
-                jnp.sum(aux["positions"] >= 0, axis=1).astype(jnp.int32),
-            )
-            if fused_prefill and quant:
-                attn, kp_c, vp_c, ksc_c, vsc_c = ragged_paged_attention_prefill(
-                    *kernel_args, fused_write=True, **kernel_kw
-                )
-            elif fused_prefill:
-                attn, kp_c, vp_c = ragged_paged_attention_prefill(
-                    *kernel_args, fused_write=True, **kernel_kw
-                )
-            else:
-                attn = ragged_paged_attention_prefill(
-                    *kernel_args, **kernel_kw
-                )
-        else:
-            if quant:
-                from production_stack_tpu.ops.quant import (
-                    gather_kv_pages_quant,
+            if Tm == 1 and cfg.attn_impl.startswith("pallas"):
+                # decode: stream pages HBM->VMEM, no gather materialization; in
+                # post mode the current token's K/V fold in from registers. On a
+                # multi-device dp x tp mesh the kernel runs per shard via
+                # shard_map (GSPMD cannot partition a pallas_call).
+                from production_stack_tpu.ops.pallas.paged_attention import (
+                    ragged_paged_attention_decode,
+                    ragged_paged_attention_decode_sharded,
                 )
 
-                kc, vc = gather_kv_pages_quant(
-                    kp, vp, ksl, vsl, aux["page_table"], dtype=cfg.dtype
-                )
-            else:
-                kc, vc = gather_kv_pages(kp, vp, aux["page_table"])
-            if burst:
-                kc = jnp.concatenate([kc, kwin.astype(kc.dtype)], axis=1)
-                vc = jnp.concatenate([vc, vwin.astype(vc.dtype)], axis=1)
-            elif post_write:
-                kc = jnp.concatenate([kc, k.astype(kc.dtype)], axis=1)
-                vc = jnp.concatenate([vc, v.astype(vc.dtype)], axis=1)
-            if sp > 1 and Tm > 1 and cfg.sliding_window is None:
-                # sequence-parallel prefill: ring attention over the sp axis
-                # (KV blocks rotate via ppermute while queries stay local)
-                from production_stack_tpu.parallel.ring_attention import (
-                    ring_attention_serving,
-                )
-
-                if post_write:
-                    # stale_kv_positions already covers pool slots + chunk
-                    kvp = aux["kv_pos"]
-                else:
-                    S = kc.shape[1]
-                    kvp = jnp.broadcast_to(
-                        jnp.arange(S, dtype=jnp.int32), (Bm, S)
+                # the in-register window stays fp under int8 pools — it is the
+                # quantizer's INPUT, committed by the post-scan quant scatter
+                cur_dt = cfg.dtype if quant else k_pages.dtype
+                if burst:
+                    cur_kw = dict(
+                        k_cur=kwin, v_cur=vwin,
+                        cur_lens=aux["burst_counts"] + 1,
                     )
-                attn = ring_attention_serving(
-                    mesh, q, kc, vc, aux["positions"], kvp
-                )
-            else:
-                attn = flash_attention(
-                    q, kc, vc, q_positions=aux["positions"],
-                    kv_lens=aux["kv_lens"],
+                elif post_write:
+                    cur_kw = dict(
+                        k_cur=k[:, 0].astype(cur_dt),
+                        v_cur=v[:, 0].astype(cur_dt),
+                    )
+                else:
+                    cur_kw = dict(k_cur=None, v_cur=None)
+                pallas_kw = dict(
                     window=cfg.sliding_window,
-                    kv_positions=aux["kv_pos"] if post_write else None,
+                    interpret=cfg.attn_impl == "pallas_interpret",
+                    pages_per_block=cfg.decode_pages_per_block or None,
+                    prefetch_pages=cfg.decode_prefetch_pages or None,
+                    **cur_kw,
                 )
-        x = x + proj(attn.reshape(Bm, Tm, -1), "wo")
-        x = _mlp_residual(x, lp, cfg, proj)
+                if stream_pools:
+                    pool_args = (k_pages, v_pages)
+                    pallas_kw["layer"] = li
+                    if quant:
+                        pallas_kw["k_scales"] = k_scales
+                        pallas_kw["v_scales"] = v_scales
+                else:
+                    pool_args = (kp, vp)
+                    if quant:
+                        pallas_kw["k_scales"] = ksl
+                        pallas_kw["v_scales"] = vsl
+                # under pp the kernel runs INSIDE the pipeline's manual region;
+                # the sharded call nests there and maps the remaining axes
+                if mesh is not None and mesh.devices.size > 1:
+                    attn = ragged_paged_attention_decode_sharded(
+                        mesh, q[:, 0], *pool_args,
+                        aux["page_table"], aux["kv_lens"],
+                        **pallas_kw,
+                    )[:, None]
+                else:
+                    attn = ragged_paged_attention_decode(
+                        q[:, 0], *pool_args, aux["page_table"], aux["kv_lens"],
+                        **pallas_kw,
+                    )[:, None]
+            elif (
+                Tm > 1
+                and cfg.attn_impl.startswith("pallas")
+                and stream_pools
+                and not burst
+            ):
+                # chunked prefill: pallas flash kernel streams pages HBM->VMEM
+                # (no [B, S, KH, D] pool gather) and folds the chunk's own K/V
+                # in-register — the XLA scan ran at <20% MFU at 16k context
+                # (ops/pallas/prefill_attention.py)
+                from production_stack_tpu.ops.pallas.prefill_attention import (
+                    ragged_paged_attention_prefill,
+                )
+
+                chunk_dt = cfg.dtype if quant else k_pages.dtype
+                kernel_kw = dict(
+                    window=cfg.sliding_window,
+                    interpret=cfg.attn_impl == "pallas_interpret",
+                    pages_per_block=getattr(cfg, "prefill_pages_per_block", 0)
+                    or None,
+                    prefetch_pages=getattr(cfg, "prefill_prefetch_pages", 0)
+                    or None,
+                    layer=li,
+                )
+                if quant:
+                    kernel_kw["k_scales"] = ksc_c if fused_prefill else k_scales
+                    kernel_kw["v_scales"] = vsc_c if fused_prefill else v_scales
+                kernel_args = (
+                    q,
+                    kp_c if fused_prefill else k_pages,
+                    vp_c if fused_prefill else v_pages,
+                    aux["page_table"], aux["positions"], aux["kv_lens"],
+                    k.astype(chunk_dt), v.astype(chunk_dt),
+                    jnp.sum(aux["positions"] >= 0, axis=1).astype(jnp.int32),
+                )
+                if fused_prefill and quant:
+                    attn, kp_c, vp_c, ksc_c, vsc_c = ragged_paged_attention_prefill(
+                        *kernel_args, fused_write=True, **kernel_kw
+                    )
+                elif fused_prefill:
+                    attn, kp_c, vp_c = ragged_paged_attention_prefill(
+                        *kernel_args, fused_write=True, **kernel_kw
+                    )
+                else:
+                    attn = ragged_paged_attention_prefill(
+                        *kernel_args, **kernel_kw
+                    )
+            else:
+                if quant:
+                    from production_stack_tpu.ops.quant import (
+                        gather_kv_pages_quant,
+                    )
+
+                    kc, vc = gather_kv_pages_quant(
+                        kp, vp, ksl, vsl, aux["page_table"], dtype=cfg.dtype
+                    )
+                else:
+                    kc, vc = gather_kv_pages(kp, vp, aux["page_table"])
+                if burst:
+                    kc = jnp.concatenate([kc, kwin.astype(kc.dtype)], axis=1)
+                    vc = jnp.concatenate([vc, vwin.astype(vc.dtype)], axis=1)
+                elif post_write:
+                    kc = jnp.concatenate([kc, k.astype(kc.dtype)], axis=1)
+                    vc = jnp.concatenate([vc, v.astype(vc.dtype)], axis=1)
+                if sp > 1 and Tm > 1 and cfg.sliding_window is None:
+                    # sequence-parallel prefill: ring attention over the sp axis
+                    # (KV blocks rotate via ppermute while queries stay local)
+                    from production_stack_tpu.parallel.ring_attention import (
+                        ring_attention_serving,
+                    )
+
+                    if post_write:
+                        # stale_kv_positions already covers pool slots + chunk
+                        kvp = aux["kv_pos"]
+                    else:
+                        S = kc.shape[1]
+                        kvp = jnp.broadcast_to(
+                            jnp.arange(S, dtype=jnp.int32), (Bm, S)
+                        )
+                    attn = ring_attention_serving(
+                        mesh, q, kc, vc, aux["positions"], kvp
+                    )
+                else:
+                    attn = flash_attention(
+                        q, kc, vc, q_positions=aux["positions"],
+                        kv_lens=aux["kv_lens"],
+                        window=cfg.sliding_window,
+                        kv_positions=aux["kv_pos"] if post_write else None,
+                    )
+            x = x + proj(attn.reshape(Bm, Tm, -1), "wo")
+        with jax.named_scope("mlp"):
+            x = _mlp_residual(x, lp, cfg, proj)
         if fused_prefill:
             # the kernel already committed this layer's K/V to the pool
             if quant:
@@ -836,33 +839,38 @@ def forward(
             write_kv_pages_all_layers_quant,
         )
 
-        k_pages, v_pages, k_scales, v_scales = write_kv_pages_all_layers_quant(
-            k_pages, v_pages, k_scales, v_scales, k_new, v_new,
-            page_table, positions,
-        )
+        with jax.named_scope("kv_commit"):
+            k_pages, v_pages, k_scales, v_scales = (
+                write_kv_pages_all_layers_quant(
+                    k_pages, v_pages, k_scales, v_scales, k_new, v_new,
+                    page_table, positions,
+                )
+            )
     elif post_write:
         (x, _), (k_new, v_new) = lax.scan(layer, (x, aux), scan_xs)
-        k_pages, v_pages = write_kv_pages_all_layers(
-            k_pages, v_pages, k_new, v_new, page_table, positions
-        )
+        with jax.named_scope("kv_commit"):
+            k_pages, v_pages = write_kv_pages_all_layers(
+                k_pages, v_pages, k_new, v_new, page_table, positions
+            )
     else:
         (x, _), (k_pages, v_pages) = lax.scan(layer, (x, aux), scan_xs)
 
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
-    if all_logits:
-        # speculative verify: T is small (1 + draft length), so [B, T, V] fits
-        if quant:
-            return (
-                (x @ head).astype(jnp.float32),
-                k_pages, v_pages, k_scales, v_scales,
-            )
-        return (x @ head).astype(jnp.float32), k_pages, v_pages
-    # Select each sequence's last valid token before the vocab projection so the
-    # logits tensor is [B, V], not [B, T, V] (a 2 GB save at V=128k, T=1k).
-    last_idx = jnp.maximum(jnp.sum(positions >= 0, axis=1) - 1, 0)  # [B]
-    x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]  # [B, H]
-    logits = (x_last @ head).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
+        if all_logits:
+            # speculative verify: T is small (1 + draft length), so [B, T, V] fits
+            if quant:
+                return (
+                    (x @ head).astype(jnp.float32),
+                    k_pages, v_pages, k_scales, v_scales,
+                )
+            return (x @ head).astype(jnp.float32), k_pages, v_pages
+        # Select each sequence's last valid token before the vocab projection so the
+        # logits tensor is [B, V], not [B, T, V] (a 2 GB save at V=128k, T=1k).
+        last_idx = jnp.maximum(jnp.sum(positions >= 0, axis=1) - 1, 0)  # [B]
+        x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]  # [B, H]
+        logits = (x_last @ head).astype(jnp.float32)
     if burst:
         return logits, k_acc, v_acc
     if quant:

@@ -59,6 +59,12 @@ GENERATED = [
         "wait", "schedule", "step", "apply", "emit", "chain_dispatch",
         "chain_fetch",
     )),
+    # ... and the two parts of step under a prefix of their own
+    *(f"vllm:engine_dispatch_{sec}_seconds_total" for sec in ("stage", "runahead")),
+    # engine/api_server.py: first-dispatch wall by phase (runner._dispatch)
+    *(f"vllm:first_dispatch_{phase}_seconds_total" for phase in (
+        "trace", "lower", "compile", "run",
+    )),
 ]
 
 # intentionally NOT on a dashboard (documentation in docs/ is still
@@ -77,6 +83,15 @@ DASHBOARD_ALLOWLIST = {
     "vllm:engine_loop_emit_seconds_total",
     "vllm:engine_loop_chain_dispatch_seconds_total",
     "vllm:engine_loop_chain_fetch_seconds_total",
+    "vllm:engine_dispatch_stage_seconds_total",
+    "vllm:engine_dispatch_runahead_seconds_total",
+    "vllm:decode_kv_tokens_read_total",      # the benchmark's counted roofline reads it
+    "vllm:first_dispatches_total",           # first-dispatch stalls: a start-up
+    "vllm:first_dispatch_seconds_total",     # and bench surface; the dashboard
+    "vllm:first_dispatch_trace_seconds_total",   # charts compile seconds
+    "vllm:first_dispatch_lower_seconds_total",
+    "vllm:first_dispatch_compile_seconds_total",
+    "vllm:first_dispatch_run_seconds_total",
     "vllm:decode_dispatches_total",          # dispatch-shape bench telemetry
     "vllm:decode_chained_dispatches_total",
     "vllm:runahead_prefill_dispatches_total",

@@ -27,6 +27,7 @@ def _known_flags() -> set:
                 ("benchmarks", "multi_round_qa.py"),
                 ("scripts", "chaos_check.py"),
                 ("scripts", "trace_report.py"),
+                ("scripts", "hostspans.py"),
                 ("scripts", "kv_directory_report.py"),
                 ("scripts", "fleet_controller.py"),
                 ("scripts", "graftcheck", "__main__.py")):

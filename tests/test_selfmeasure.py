@@ -1,0 +1,272 @@
+"""The engine measures itself (ISSUE 25): first dispatches by shape and phase,
+named step programs, the engine loop's sections as one helper and as spans in
+the profiler's trace, and work per dispatch counted from the scheduler's batch."""
+
+import asyncio
+import glob
+import json
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+from production_stack_tpu import tracing
+from production_stack_tpu.engine import devicemon
+from production_stack_tpu.engine.config import EngineConfig
+from production_stack_tpu.engine.engine import LLMEngine, _kv_tokens_read
+from production_stack_tpu.engine.runner import ModelRunner, StepInput
+from production_stack_tpu.engine.scheduler import SamplingParams
+from production_stack_tpu.models import llama
+from production_stack_tpu.tracing import profiler
+
+CFG = llama.PRESETS["llama-debug"]
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+WINDOW = llama.PRESETS["mistral-debug"].sliding_window  # 8
+
+
+def _decode_input(B, ctx, ctx_pages, k=None):
+    return StepInput(
+        input_ids=np.ones((B, 1), np.int32),
+        positions=np.full((B, 1), ctx, np.int32),
+        page_table=np.arange(B * ctx_pages, dtype=np.int32).reshape(B, ctx_pages),
+        kv_lens=np.full((B,), ctx + 1, np.int32),
+        temperature=np.zeros(B, np.float32), top_k=np.zeros(B, np.int32),
+        top_p=np.ones(B, np.float32),
+        kv_limits=None if k is None else np.full((B,), ctx + k, np.int32),
+    )
+
+
+def _first_dispatch_events():
+    return [e["data"] for e in tracing.get_flightrecorder().events(kind="compile")
+            if e["data"].get("event") == "first_dispatch"]
+
+
+def test_first_dispatch_is_counted_once_a_shape_and_split_by_phase():
+    tracing.get_flightrecorder().reset()
+    r = ModelRunner(CFG, num_pages=16, page_size=8, seed=0)
+    fd = r.first_dispatch
+    assert fd["count"] == 0
+    r.step(_decode_input(2, 8, 2))
+    assert fd["count"] == 1
+    r.step(_decode_input(2, 9, 2))  # the same shape again: nothing
+    assert fd["count"] == 1
+    r.step(_decode_input(4, 8, 2))  # another batch bucket
+    r.step_multi(_decode_input(2, 8, 2, k=4), 4)  # another family
+    assert fd["count"] == 3
+    phases = sum(fd[p] for p in ("trace", "lower", "compile", "run"))
+    assert phases == pytest.approx(fd["seconds"], rel=1e-6) and fd["seconds"] > 0
+    # JAX reported the phases on this thread (a warm persistent cache still
+    # traces and lowers; the backend-compile event covers a cache load)
+    assert fd["trace"] > 0 and fd["lower"] > 0 and fd["compile"] > 0
+    events = _first_dispatch_events()
+    assert [(e["family"], e["ids_shape"], e["pages_shape"]) for e in events] == [
+        ("step", [2, 1], [2, 2]), ("step", [4, 1], [4, 2]), ("multi_step", [2, 1], [2, 2])]
+    for e in events:
+        assert e["seconds"] == pytest.approx(
+            e["trace_s"] + e["lower_s"] + e["compile_s"] + e["run_s"], abs=1e-3)
+        assert e["cache"] in ("hit", "miss", "uncached")
+    assert events[2]["sig"] == "(4, False, False)"
+    # the marker this event replaces is gone
+    assert not hasattr(r, "_note_program_variant")
+    assert not [e for e in tracing.get_flightrecorder().events(kind="compile")
+                if e["data"].get("event") == "program_variant"]
+
+
+def test_a_trace_event_of_an_inner_jit_is_not_counted_twice():
+    devicemon.install_compile_listener()
+    with devicemon.capture_first_dispatch() as phases:
+        for name, secs in (("jaxpr_trace_duration", 0.2), ("jaxpr_trace_duration", 0.3),
+                           ("jaxpr_trace_duration", 1.0), ("jaxpr_to_mlir_module_duration", 0.5),
+                           ("backend_compile_duration", 2.0)):
+            devicemon._on_event_duration("/jax/core/compile/" + name, secs)
+        devicemon._on_event("/jax/compilation_cache/cache_hits")
+    assert phases == {"trace": 1.0, "lower": 0.5, "compile": 2.0, "cache_hits": 1, "cache_misses": 0}
+    before = dict(phases)
+    devicemon._on_event_duration("/jax/core/compile/jaxpr_trace_duration", 9.0)  # capture closed
+    assert phases == before
+
+
+PROGRAM_NAMES = """
+import json, numpy as np
+from production_stack_tpu.engine.runner import ModelRunner, StepInput
+from production_stack_tpu.models import llama
+r = ModelRunner(llama.PRESETS["llama-debug"], num_pages=8, page_size=8, seed=0)
+r._get_step(False, False); r._get_step(True, True)
+print(json.dumps(sorted(f.__name__ for f in r._steps.values())))
+"""
+
+
+def test_step_programs_carry_names_that_are_equal_in_two_processes():
+    r = ModelRunner(CFG, num_pages=8, page_size=8, seed=0)
+    r._get_step(False, False)
+    r._get_step(True, True)
+    r.step_multi(_decode_input(2, 8, 2, k=4), 4)
+    here = sorted(f.__name__ for f in r._steps.values())
+    assert here == ["pstpu_step", "pstpu_step_lp_pen"]
+    assert [f.__name__ for f in r._multi_steps.values()] == ["pstpu_multi_step_k4"]
+    outs = []
+    for hashseed in ("1", "2"):  # no id, no hash, no fingerprint in a name
+        env = dict(os.environ, PYTHONHASHSEED=hashseed, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=ROOT + os.pathsep + os.environ.get("PYTHONPATH", ""))
+        proc = subprocess.run([sys.executable, "-c", PROGRAM_NAMES], env=env, cwd=ROOT,
+                              capture_output=True, text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        outs.append(json.loads(proc.stdout.strip().splitlines()[-1]))
+    assert outs[0] == outs[1] == here
+
+
+def test_kv_tokens_read_is_the_sum_of_windowed_contexts():
+    def by_hand(kv_len, steps, window):
+        return sum(min(c, window) if window else c
+                   for L, n in zip(kv_len, steps) for c in range(L, L + max(n, 0)))
+    kv_len, steps = np.array([1, 5, 8, 9, 40, 7]), np.array([8, 8, 3, 1, 16, -2])
+    for window in (None, 8, 4096, 1):
+        assert _kv_tokens_read(kv_len, steps, window) == by_hand(kv_len, steps, window)
+    assert _kv_tokens_read(np.array([10]), 4, 12) == 10 + 11 + 12 + 12
+
+
+@pytest.fixture(scope="module")
+def engine():
+    eng = LLMEngine(EngineConfig(
+        model="mistral-debug", max_model_len=256, max_num_seqs=8, num_pages=64,
+        page_size=8, prefill_chunk=32, kv_cache_memory_gb=0.01))
+    eng.start()
+    yield eng
+    eng.stop()
+
+
+def _generate(engine, prompt, n):
+    async def run():
+        last = None
+        async for out in engine.generate(
+            f"t-{np.random.randint(1 << 30)}", prompt=prompt,
+            params=SamplingParams(max_tokens=n, temperature=0.0, ignore_eos=True),
+        ):
+            last = out
+        return last
+    return asyncio.run(run())
+
+
+def test_work_per_dispatch_equals_a_hand_count_with_a_window_and_a_prefix_hit(engine):
+    s0 = engine.stats()
+    prompt = "the engine counts what it dispatches, from the batch itself"
+    first = _generate(engine, prompt, 20)
+    again = _generate(engine, prompt, 20)
+    assert again.cached_tokens > 0 and first.cached_tokens == 0
+    s1 = engine.stats()
+    # prefill: every prompt token but the cached ones ran through prefill
+    computed = 2 * first.prompt_tokens - again.cached_tokens
+    assert s1["prompt_tokens_total"] - s0["prompt_tokens_total"] == computed
+    # decode: the first output token comes from the prefill; each of the 19
+    # others attended min(context, window) KV tokens, and every context here
+    # (prompt + outputs so far) is past the window of 8
+    assert first.prompt_tokens > WINDOW
+    by_hand = 2 * sum(min(first.prompt_tokens + i, WINDOW) for i in range(1, 20))
+    assert s1["decode_kv_tokens_read_total"] - s0["decode_kv_tokens_read_total"] == by_hand
+    steps = [e["data"] for e in tracing.get_flightrecorder().events(kind="step")]
+    assert sum(e.get("kv_tokens_read", 0) for e in steps) >= by_hand
+    assert sum(e.get("prefill_tokens", 0) for e in steps) >= computed
+
+
+def test_loop_sections_are_disjoint_sum_to_the_wall_and_keep_their_keys(engine):
+    def snap():
+        return time.perf_counter(), dict(engine.loop_seconds)
+    (t0, a) = snap()
+    _generate(engine, "sections of the loop, summed", 24)
+    time.sleep(0.3)  # an idle stretch: the loop waits on its inbox
+    (t1, b) = snap()
+    delta = {k: b[k] - a[k] for k in b}
+    top = sum(delta[k] for k in ("wait", "schedule", "step", "apply", "emit"))
+    # the loop blocks on its inbox at either edge of the interval, so up to
+    # one wait (0.5 s timeout) straddles it; nothing else is unaccounted
+    assert top == pytest.approx(t1 - t0, abs=0.6)
+    assert delta["step"] > 0 and delta["apply"] > 0 and delta["emit"] > 0
+    # the parts of a dispatch lie inside `step`
+    for part in ("stage", "chain_dispatch", "chain_fetch", "runahead"):
+        assert 0 <= delta[part] <= delta["step"] + 1e-9
+    assert delta["stage"] > 0
+    stats = engine.stats()
+    # /stats keeps every engine_loop_* key it had, and no more: the two parts
+    # of step that are new stand under a prefix of their own
+    assert {k for k in stats if k.startswith("engine_loop_")} == {
+        f"engine_loop_{k}_seconds_total" for k in (
+            "wait", "schedule", "step", "apply", "emit", "chain_dispatch", "chain_fetch")}
+    assert {k for k in stats if k.startswith("engine_dispatch_")} == {
+        "engine_dispatch_stage_seconds_total", "engine_dispatch_runahead_seconds_total"}
+
+
+def test_apply_and_emit_inside_a_dispatch_are_taken_off_it(engine):
+    secs = {k: 0.0 for k in engine.loop_seconds}
+    from production_stack_tpu.engine.engine import _LoopSection
+
+    with _LoopSection(secs, "step", {}) as step:
+        time.sleep(0.02)
+        with _LoopSection(secs, "chain_fetch", {}):
+            with _LoopSection(secs, "apply", {}):
+                time.sleep(0.03)
+            with _LoopSection(secs, "emit", {}):
+                time.sleep(0.01)
+    assert secs["apply"] == pytest.approx(0.03, abs=0.01)
+    assert secs["step"] == pytest.approx(0.02, abs=0.01) and step.seconds == secs["step"]
+    assert secs["chain_fetch"] < 0.01
+    assert secs["step"] + secs["apply"] + secs["emit"] == pytest.approx(0.06, abs=0.015)
+
+
+def test_under_a_profile_the_trace_holds_the_loop_spans_and_the_program_names(engine, tmp_path):
+    from jax.profiler import ProfileData
+
+    assert not profiler.active()
+    profiler.start(str(tmp_path))
+    with pytest.raises(RuntimeError, match="already running"):
+        profiler.start(str(tmp_path))
+    try:
+        assert profiler.active()
+        _generate(engine, "a new prompt whose length meets a new shape " * 3, 12)
+    finally:
+        done = profiler.stop()
+    assert not profiler.active() and done["stop_s"] >= 0 and done["path"] == str(tmp_path)
+    with pytest.raises(RuntimeError, match="no profile"):
+        profiler.stop()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names, attrs = set(), {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                names.add(ev.name)
+                if ev.name == "pstpu.loop.step":
+                    attrs = dict(ev.stats)
+    for section in ("wait", "schedule", "step", "stage", "apply", "emit"):
+        assert "pstpu.loop." + section in names
+    assert {"kind", "family", "rows", "chunk", "pages", "bursts"} <= set(attrs)
+    # the program's name, as JAX's own dispatch span shows it on the host plane
+    # (on the TPU the device plane's module line reads jit_pstpu_step(...))
+    assert any("pstpu_step" in n or "pstpu_multi_step" in n for n in names)
+    with open(path, "rb") as f:
+        assert b"jit_pstpu_" in f.read()
+
+
+def test_profile_endpoints_ride_the_debug_gate(engine, tmp_path):
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from production_stack_tpu.engine.api_server import EngineServer
+
+    async def drive(debug):
+        cfg = EngineConfig(model="mistral-debug", enable_debug_endpoints=debug)
+        async with TestClient(TestServer(EngineServer(cfg, engine).build_app())) as client:
+            r = await client.post("/v1/debug/profile/start", json={"dir": str(tmp_path)})
+            if not debug:
+                return r.status, None
+            assert r.status == 200 and profiler.active()
+            assert (await client.post("/v1/debug/profile/start", json={"dir": "x"})).status == 409
+            r = await client.post("/v1/debug/profile/stop")
+            assert (await client.post("/v1/debug/profile/stop")).status == 409
+            assert (await client.post("/v1/debug/profile/start", json={})).status == 400
+            return r.status, await r.json()
+
+    assert asyncio.run(drive(False))[0] in (404, 405)  # not registered without the flag
+    status, done = asyncio.run(drive(True))
+    assert status == 200 and done["stop_s"] >= 0 and done["path"] == str(tmp_path)
+    assert not profiler.active() and glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)

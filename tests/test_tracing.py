@@ -463,6 +463,26 @@ def test_flightrecorder_hot_path_overhead_micro():
     )
 
 
+def test_profiler_span_builds_nothing_while_no_profile_runs():
+    """The engine loop opens a span for every section of every iteration
+    (tracing/profiler.py): with no profile running that is one flag test and
+    the ONE shared no-op object, never a TraceAnnotation."""
+    from production_stack_tpu.tracing import profiler
+
+    assert not profiler.active()
+    first = profiler.span("pstpu.loop.step", kind="decode", rows=8)
+    assert first is profiler.span("pstpu.loop.apply") and first is profiler._NO_SPAN
+    with first as inside:
+        assert inside is first
+    n = 200000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with profiler.span("pstpu.loop.step"):
+            pass
+    per_span = (time.perf_counter() - t0) / n
+    assert per_span < 5e-6, f"an inactive span cost {per_span * 1e6:.2f}us"
+
+
 def _parse_label_sets(metrics_text):
     import re
 
