@@ -102,14 +102,12 @@ class EngineConfig:
     # stale pool + in-register chunk K/V and commits all layers with one
     # batched scatter after the layer scan (avoids per-layer pool copies)
     kv_write_mode: str = "post"
-    # decode-kernel memory pipeline tuning (threaded into the model config;
-    # ops/pallas/paged_attention.py). decode_pages_per_block: KV pages per
-    # packed grid cell (0 = auto: ~128 slots, ~512 for >=128-page buckets).
-    # decode_prefetch_pages: depth of the kernel's VMEM page-copy ring — how
-    # many page DMAs stay in flight ahead of compute (0 = auto: up to 8
-    # within a ~2 MB VMEM budget per pool array). Retune with
-    # scripts/profile_decode.py, which reports achieved HBM GB/s per
-    # (batch, context, page_size) bucket.
+    # decode-kernel overrides (threaded into the model config;
+    # ops/pallas/paged_attention.py derives both from the shapes when 0, and
+    # GET /stats decode_kernel_blocks says what it chose per bucket).
+    # decode_pages_per_block: KV pages a grid cell consumes as one tile.
+    # decode_prefetch_pages: pages the kernel's VMEM ring holds ahead of
+    # compute, rounded up to whole blocks (at least two blocks).
     decode_pages_per_block: int = 0
     decode_prefetch_pages: int = 0
     # prefill-kernel memory pipeline tuning (threaded into the model config;

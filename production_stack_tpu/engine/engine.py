@@ -2484,6 +2484,10 @@ class LLMEngine:
         out["first_dispatch_seconds_total"] = round(fd["seconds"], 4)
         for phase in ("trace", "lower", "compile", "run"):
             out[f"first_dispatch_{phase}_seconds_total"] = round(fd[phase], 4)
+        # per decode (batch x pages) bucket dispatched: the block of pages and
+        # the ring depth the kernel's derivation chose (a dict, so /metrics,
+        # which names its keys, leaves it to GET /stats)
+        out["decode_kernel_blocks"] = dict(self.runner.decode_blocks)
         for section, secs in self.loop_seconds.items():
             # stage and runahead are parts of step that the loop did not
             # separate before: under a prefix of their own, so that a reader

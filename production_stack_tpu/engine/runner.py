@@ -75,12 +75,15 @@ def kernel_refusal(
     page_size: int = 64, max_batch: Optional[int] = None,
     max_pages: Optional[int] = None,
 ) -> Optional[str]:
-    """Why Mosaic refuses the ragged kernels at this attention shape (None =
-    it compiles). Both kernels DMA whole ``[page, KH, D]`` pool pages, so
-    they share the constraint: the page's trailing (KH, D) dims must fill
-    whole (sublane-pack, 128-lane) tiles. The messages are the compiler's
+    """Why the ragged kernels are not taken at this attention shape (None =
+    they are). Both DMA whole pool pages, so head_dim must fill 128-lane
+    tiles. The prefill kernel copies ``[page, KH, D]``, so KH must fill a
+    sublane tile too; the decode kernel reads a page as ``[page * KH, D]``
+    rows and compiles without that, but the two are chosen
+    together and no chip run has checked it there, so the rule stands for
+    both. The messages are the compiler's
     (tests/test_kernels_compile_v5e.py compiles every preset against this
-    rule; PERF.md "Bring-up" lists the excluded shapes)."""
+    rule; PERF.md section 6 lists the excluded shapes)."""
     if head_dim % 128:
         return (
             f"head_dim {head_dim} is not a multiple of the 128-lane tile "
@@ -93,7 +96,8 @@ def kernel_refusal(
             f"{kv_heads_per_shard} kv head(s) per shard with "
             f"{pool_itemsize}-byte pool entries do not fill a sublane tile "
             "(Mosaic: 'Slice shape along dimension 3 must be aligned to "
-            f"tiling ({pack}), but is {kv_heads_per_shard}' at the page DMA)"
+            f"tiling ({pack}), but is {kv_heads_per_shard}' at the prefill "
+            "kernel's page DMA)"
         )
     if max_batch and max_pages:
         from production_stack_tpu.ops.pallas.paged_attention import (
@@ -101,7 +105,9 @@ def kernel_refusal(
         )
 
         rows, pages = _pow2_at_least(max_batch), _pow2_at_least(max_pages)
-        need = decode_smem_bytes(rows, pages, page_size, pool_itemsize)
+        need = decode_smem_bytes(
+            rows, pages, page_size, kv_heads_per_shard, head_dim, pool_itemsize
+        )
         if need > _SMEM_BYTES:
             return (
                 f"scalar-prefetch operands of the largest decode bucket "
@@ -388,6 +394,11 @@ class ModelRunner:
             "count": 0, "seconds": 0.0,
             "trace": 0.0, "lower": 0.0, "compile": 0.0, "run": 0.0,
         }
+        # the decode kernel's block per (batch, pages) bucket dispatched:
+        # pages a grid cell consumes as one tile and blocks in its VMEM
+        # ring, as ops/pallas/paged_attention.py derived them from the
+        # shapes (engine stats() exports it as decode_kernel_blocks)
+        self.decode_blocks: dict[str, dict] = {}
         # `with self.section("stage"):` books host->device staging into the
         # engine loop's section accounting; the engine sets it, a runner on
         # its own gets the profiler's span alone
@@ -495,6 +506,8 @@ class ModelRunner:
         fd["seconds"] += wall
         for p, secs in split.items():
             fd[p] += secs
+        if ids_shape[1] == 1 and self.attn.decode != "xla":
+            self._note_decode_block(*pages_shape)
         # ONE event per first dispatch ties the compile seconds (which the
         # jax.monitoring listener records without a shape) to the serving
         # shape that caused them. Steady-state serving records none: a stream
@@ -511,6 +524,25 @@ class ModelRunner:
             ),
         )
         return out
+
+    def _note_decode_block(self, batch: int, max_pages: int) -> None:
+        """Written once, at a decode bucket's first dispatch: the block the
+        kernel's derivation chose for it."""
+        from production_stack_tpu.ops.pallas.paged_attention import (
+            decode_block_shape,
+        )
+
+        cfg = self.cfg
+        n, ring = decode_block_shape(
+            max_pages, self.page_size,
+            cfg.num_kv_heads // dict(self.mesh.shape).get("tp", 1),
+            cfg.head_dim, np.dtype(self.kv_pool_dtype).itemsize,
+            getattr(cfg, "decode_pages_per_block", 0) or None,
+            getattr(cfg, "decode_prefetch_pages", 0) or None,
+        )
+        self.decode_blocks[f"{batch}x{max_pages}"] = {
+            "pages_per_block": n, "ring_blocks": ring,
+        }
 
     def _get_step(self, want_lp: bool, want_pen: bool):
         sig = (want_lp, want_pen)

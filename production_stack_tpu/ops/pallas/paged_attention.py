@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: ragged paged attention for the decode step (v2).
+"""Pallas TPU kernel: ragged paged attention for the decode step.
 
 Why a kernel (SURVEY.md §7 hard part #1): the XLA reference path
 (ops/attention.py paged_attention_decode) gathers each sequence's pages into a
@@ -6,45 +6,53 @@ contiguous [B, S, KH, D] tensor in HBM *before* attending — that copy is pure
 HBM-bandwidth waste in the bandwidth-bound decode regime. This kernel streams
 each page HBM->VMEM exactly once instead.
 
-v2 restructures the memory pipeline around two ideas (its achieved
-page-streaming rate on the current chip attachment is not measured —
-PERF.md):
+Three ideas carry it:
 
-1. **Ragged packed grid.** v1 ran grid = (B, max_pages_bucket): a 50-page
-   sequence in a 256-page bucket still executed ~200 dead grid cells whose
-   index map clamped to the last page (refetch + masked compute). v2 derives
-   each sequence's LIVE block count from ``kv_lens`` (and the sliding
-   window) on the host side, packs all live (sequence, block) cells into a
-   1D grid, and pads with no-op cells whose index maps alias the last live
-   cell (no DMA, no compute). Decode cost therefore scales with the batch's
-   REAL total context, not with B x bucket — which is what makes
-   mixed-length decode batches (the multi-round-QA shape) cheap.
+1. **Ragged packed grid.** Each sequence's LIVE page range follows from
+   ``kv_lens``, the sliding window and the burst's stale tail; the wrapper
+   cuts it into blocks of N pages, packs all live (sequence, block) cells
+   into a 1D grid sized for the bucket's worst case, and pads with no-op
+   cells (no DMA, no compute). Decode cost scales with the batch's REAL
+   total context, not with B x bucket.
 
-2. **Deep page prefetch.** v1 fetched N pages per cell as N separate small
-   BlockSpec inputs, so at most one cell's worth of page DMAs overlapped
-   compute and per-cell pipeline overhead dominated at small pages. v2
-   leaves the pools in HBM
-   (``memory_space=ANY``) and drives a manually multi-buffered VMEM ring of
-   page copies with ``pltpu.make_async_copy``: R page DMAs stay in flight
-   across cell boundaries (R = ``prefetch_pages``), so the HBM pipeline
-   stays full regardless of page size or cell shape.
+2. **A ring of blocks.** The pools stay in HBM (``memory_space=ANY``). One
+   grid cell consumes one block: N pages copied by N DMAs into one slot of a
+   VMEM ring of RB blocks (``pltpu.make_async_copy``); while a cell computes,
+   the next RB-1 blocks' copies are in flight, across cell and row
+   boundaries. Only pages inside a row's live range are copied.
 
-Layout within a cell is unchanged from v1: query/kv heads stay packed
-[KH, G, D] so all heads of a page are one batched MXU call, and the
-(m, l, acc) VMEM scratch persists across a sequence's consecutive cells —
-the classic flash-decode accumulation.
+3. **The block is ONE tile.** A page is read as the pool stores it:
+   ``[page_size * KH, D]`` rows in (token, kv head) order — a free view of
+   ``[page_size, KH, D]``, never cast, split by head or transposed. A cell
+   runs ONE score matmul of all NH query heads against the block's
+   ``N * page_size * KH`` rows (the KV rows are the MXU's latched operand,
+   the query rows stream; bf16 in, float32 out), ONE mask — a row counts for
+   the G query heads of its own kv head, inside ``[lo, paged_end)`` — ONE
+   online-softmax update of the float32 (m, l, acc) scratch, which persists
+   across a sequence's cells, and ONE PV matmul. Scores, softmax state and
+   probabilities are float32; every product is exact. The cross product
+   costs KH times the arithmetic of a per-head kernel, on an MXU that has it
+   to spare: what a cell costs beside its bytes is a chain of dependent
+   steps, about 1 us on a v5e whether the tile holds one page or thirty-two
+   (PERF.md, PR 31). So N is as large as the derivation below allows, and
+   the kernel runs at 62-85% of the HBM roofline at the benchmark's shapes
+   where its per-page predecessor ran at 20-32% (my chip runs, PR 31).
 
-Sliding-window attention (Mistral, Gemma-2's even layers) is handled by
-starting each sequence's live range at the first page containing a visible
-KV slot (``(kv_len - window) // page_size``), so a 4096-window sequence at
-128k context streams ~window bytes, not ~context bytes. The window arrives
-as a scalar-prefetch operand, so per-layer window sizes (Gemma-2
-interleaves local/global) ride the decoder's layer scan. Logit softcapping
-(Gemma-2) is a static transform on the scores.
+N and RB are DERIVED (``_auto_pages_per_block``, ``_auto_ring_blocks``) from
+the page size, the kv heads a shard, the head dim, the pool's itemsize, the
+ring's VMEM budget and the bucket's ``max_pages``; N sizes the grid too
+(``ceil(max_pages / N)`` cells a row). ``GET /stats``
+``decode_kernel_blocks`` reports both per bucket dispatched.
 
-Measure the achieved page-streaming HBM GB/s with
-``scripts/profile_decode.py`` (per (batch, context, page_size) bucket, plus
-a mixed-length case that checks cost scales with real ``kv_lens``).
+Sliding-window attention (Mistral, Gemma-2's even layers) starts each
+sequence's live range at the first page containing a visible KV slot
+(``(kv_len - window) // page_size``), so a 4096-window sequence at 128k
+context streams ~window bytes, not ~context bytes. The window arrives as a
+scalar-prefetch operand, so per-layer window sizes (Gemma-2 interleaves
+local/global) ride the decoder's layer scan. Logit softcapping (Gemma-2) is
+a static transform on the scores. int8 pools carry one scale per (page, kv
+head): a score or probability COLUMN belongs to one of each, so the scale
+multiplies the column and the int8 values enter the MXU as they are.
 
 Equivalent role in the reference: vLLM's CUDA PagedAttention decode kernel
 (executed inside the engine image; configured by
@@ -66,15 +74,34 @@ NEG_INF = -1e30
 
 def _scale_column(row):
     """[1, KH] scale row (kv heads on lanes) -> [KH, 1] column (kv heads on
-    sublanes), so it broadcasts against a [page, KH, D] page. Mosaic has no
-    lane->sublane shape cast for a KH-wide vector; a masked lane reduction
-    over a [KH, KH] identity is the transpose it does support."""
+    sublanes). Mosaic has no lane->sublane shape cast for a KH-wide vector;
+    a masked lane reduction over a [KH, KH] identity is the transpose it
+    does support."""
     KH = row.shape[1]
     eye = (
         lax.broadcasted_iota(jnp.int32, (KH, KH), 0)
         == lax.broadcasted_iota(jnp.int32, (KH, KH), 1)
     )
     return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _div_mod(x, n: int):
+    """(x // n, x % n) of a non-negative int32 vector by a static n; shifts
+    where n is a power of two (every preset's KH), the VPU's division else."""
+    if n & (n - 1) == 0:
+        return x >> (n.bit_length() - 1), x & (n - 1)
+    return lax.div(x, jnp.int32(n)), lax.rem(x, jnp.int32(n))
+
+
+def _scale_lanes(row, cols: int):
+    """[1, KH] scale row -> [1, cols] lane vector holding scale[c % KH] at
+    column c: the scale of every (token, kv head)-major column of a page."""
+    KH = row.shape[1]
+    _, kh = _div_mod(lax.broadcasted_iota(jnp.int32, (KH, cols), 1), KH)
+    mine = kh == lax.broadcasted_iota(jnp.int32, (KH, cols), 0)
+    return jnp.sum(
+        jnp.where(mine, _scale_column(row), 0.0), axis=0, keepdims=True
+    )
 
 
 def _decode_kernel(
@@ -92,26 +119,25 @@ def _decode_kernel(
     total_ref,   # [1] int32 total live cells
     # inputs
     q_ref,       # [1, NH, D] (current cell's row)
-    kp_hbm,      # [L, P, page_size, KH, D], memory_space=ANY (stays in HBM)
+    kp_hbm,      # [L, P, page_size * KH, D], memory_space=ANY (stays in HBM)
     vp_hbm,
     *refs,       # [ks_ref, vs_ref ([1, P, KH] f32 scale slabs, quantized),]
-                 # [k_cur_ref, v_cur_ref ([1, C, KH, D]),] o_ref,
-                 # k_buf/v_buf ([R, page, KH, D] VMEM ring), ksem/vsem,
-                 # m/l/acc scratch
+                 # [k_cur_ref, v_cur_ref ([1, C * KH, D]),] o_ref,
+                 # k_buf/v_buf ([RB, N * page_size * KH, D] VMEM ring of
+                 # blocks), ksem/vsem ([RB]), m/l/acc scratch
     sm_scale: float,
     kv_heads: int,
+    page_size: int,
     logit_softcap: float | None,
     has_cur: bool,
     pages_per_block: int,
-    prefetch: int,
+    ring_blocks: int,
     quantized: bool = False,
 ):
     i0 = 0
     if quantized:
-        # int8 pools: the current layer's [P, KH] scale slabs ride as
-        # whole VMEM blocks (constant index map — fetched once), and each
-        # page dequantizes right after its DMA lands in the ring. The fp
-        # values never exist in HBM — only the halved int8 byte stream does.
+        # int8 pools: the current layer's [P, KH] scale slabs ride as whole
+        # VMEM blocks (constant index map — fetched once)
         ks_ref, vs_ref = refs[0], refs[1]
         i0 = 2
     if has_cur:
@@ -124,12 +150,12 @@ def _decode_kernel(
         (o_ref, k_buf, v_buf, ksem, vsem,
          m_ref, l_ref, acc_ref) = refs[i0:]
     N = pages_per_block
-    R = prefetch
-    page_size = k_buf.shape[1]
+    RB = ring_blocks
+    KH = kv_heads
+    rows = page_size * KH  # one page as (token, kv head)-major rows
     max_pages = pt_ref.shape[1]
     n_cells = seq_ref.shape[0]
-    NH, D = q_ref.shape[1], q_ref.shape[2]
-    KH = kv_heads
+    NH = q_ref.shape[1]
     G = NH // KH
     lyr = layer_ref[0]
 
@@ -139,31 +165,92 @@ def _decode_kernel(
     b = seq_ref[c]
     p = blk_ref[c]
 
-    def _copies(g):
-        """DMA descriptors (and their go/no-go predicate) for global
-        page-stream index g = cell*N + i. A page is fetched iff its cell is
-        live and it lies inside its row's live page range (livepg_ref, the
-        same array the host packed the grid from) — the SAME predicate
-        gates start and wait, so semaphore counts always pair. Also returns
-        the page id so the quantized path can look up its scale row."""
-        cc = jnp.minimum(g // N, n_cells - 1)
+    def _block(g):
+        """Packed cell g's batch row, first page (an offset into the row's
+        page table) and number of pages to fetch: those inside the row's
+        live range (livepg_ref, the array the host packed the grid from),
+        none for a dead cell. Start and wait both loop over that count, so
+        semaphore counts always pair."""
+        cc = jnp.minimum(g, n_cells - 1)
         bb = seq_ref[cc]
-        pi = blk_ref[cc] * N + g % N  # page offset within the live range
+        first = blk_ref[cc] * N
         lo_pg = jnp.maximum(lens_ref[bb] - win_ref[0], 0) // page_size
-        ok = (g < total * N) & (pi < livepg_ref[bb])
-        pid = pt_ref[bb, jnp.minimum(lo_pg + pi, max_pages - 1)]
-        s = g % R
-        kcp = pltpu.make_async_copy(kp_hbm.at[lyr, pid], k_buf.at[s], ksem.at[s])
-        vcp = pltpu.make_async_copy(vp_hbm.at[lyr, pid], v_buf.at[s], vsem.at[s])
-        return ok, pid, kcp, vcp
+        n_ok = jnp.where(g < total, jnp.clip(livepg_ref[bb] - first, 0, N), 0)
+        return bb, lo_pg + first, n_ok
+
+    def _page_id(bb, pg0, i):
+        return pt_ref[bb, jnp.minimum(pg0 + i, max_pages - 1)]
+
+    def _copies(g, bb, pg0, i):
+        """Page i of cell g's block: pool page -> its rows of ring slot g % RB."""
+        pid = _page_id(bb, pg0, i)
+        s = g % RB
+        dst = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        return (
+            pltpu.make_async_copy(kp_hbm.at[lyr, pid], k_buf.at[s, dst], ksem.at[s]),
+            pltpu.make_async_copy(vp_hbm.at[lyr, pid], v_buf.at[s, dst], vsem.at[s]),
+        )
+
+    def _each_page(g, act):
+        """``act`` on both copies of every page cell g's block fetches."""
+        bb, pg0, n_ok = _block(g)
+
+        def body(i, carry):
+            for cp in _copies(g, bb, pg0, i):
+                act(cp)
+            return carry
+
+        lax.fori_loop(0, n_ok, body, 0)
+        return bb, pg0, n_ok
 
     def _start(g):
-        ok, _, kcp, vcp = _copies(g)
+        _each_page(g, lambda cp: cp.start())
 
-        @pl.when(ok)
-        def _():
-            kcp.start()
-            vcp.start()
+    def _fold(k2, v2, pos0, lo, hi, k_scale=None, v_scale=None):
+        """ONE online-softmax update of (m, l, acc) with a tile of KV rows.
+
+        k2/v2 are [cols, D] in the pool's own (token, kv head)-major order:
+        row t * KH + kh holds kv head kh of position pos0 + t. The score
+        matmul runs every query head against every row — the KV rows are the
+        MXU's latched operand, the NH query rows stream — and the mask keeps
+        a row for its own kv head's G query heads only, so no page is ever
+        split by head or transposed. Visible: lo <= position < hi."""
+        q = q_ref[0]
+        mxu = (
+            jnp.bfloat16
+            if q.dtype == jnp.bfloat16 and k2.dtype in (jnp.bfloat16, jnp.int8)
+            else jnp.float32
+        )
+        s = lax.dot_general(
+            q.astype(mxu), k2.astype(mxu), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * sm_scale                                        # [NH, cols]
+        if k_scale is not None:
+            s = s * k_scale
+        if logit_softcap is not None:
+            s = logit_softcap * jnp.tanh(s / logit_softcap)
+        cols = k2.shape[0]
+        t, kh = _div_mod(lax.broadcasted_iota(jnp.int32, (1, cols), 1), KH)
+        pos = pos0 + t
+        own, _ = _div_mod(lax.broadcasted_iota(jnp.int32, (NH, 1), 0), G)
+        keep = (pos >= lo) & (pos < hi) & (kh == own)
+        s = jnp.where(keep, s, NEG_INF)
+
+        m_prev = m_ref[...]                                 # [NH, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
+        pij = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + pij.sum(axis=1, keepdims=True)
+        if v_scale is not None:
+            pij = pij * v_scale
+        # V widens to float32 (exact) for a float32 x float32 matmul: the
+        # probabilities are never rounded. Measured no slower than bf16.
+        pv = lax.dot_general(
+            pij, v2.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                   # [NH, D]
+        acc_ref[...] = acc_ref[...] * alpha + pv
 
     @pl.when(live & (p == 0))
     def _():
@@ -173,9 +260,13 @@ def _decode_kernel(
 
     @pl.when(c == 0)
     def _():
-        # warm-up: fill the ring; steady state below tops it off with copy
-        # g+R-1 as it consumes copy g, so R page DMAs stay in flight
-        for g in range(R - 1):
+        # a block whose row ends inside it leaves its last pages' rows
+        # unfetched: they are masked out of the scores, and must be finite
+        # (not whatever VMEM held) to be 0 x V in the PV matmul
+        v_buf[...] = jnp.zeros_like(v_buf)
+        # warm-up: fill the ring; steady state below tops it off with block
+        # c+RB-1 as it consumes block c, so RB-1 blocks stay in flight
+        for g in range(RB - 1):
             _start(jnp.int32(g))
 
     kv_len = lens_ref[b]
@@ -183,118 +274,120 @@ def _decode_kernel(
     # cl_ref[b] slots (the in-register window) are stale in the pool
     paged_end = kv_len - cl_ref[b] if has_cur else kv_len
     lo = jnp.maximum(kv_len - win_ref[0], 0)   # first visible KV slot
-    lo_pg = lo // page_size
 
-    for i in range(N):
+    @pl.when(live)
+    def _():
+        _start(c + RB - 1)
+        bb, pg0, n_ok = _each_page(c, lambda cp: cp.wait())
 
-        @pl.when(live)
-        def _(i=i):
-            g = c * N + i
-            _start(g + R - 1)
-            ok, pid, kcp, vcp = _copies(g)
+        @pl.when(n_ok > 0)
+        def _():
+            s = c % RB
+            k_scale = v_scale = None
+            if quantized:
+                # scale per page per kv head: a score column / probability
+                # column belongs to one (page, kv head), so the scale
+                # multiplies the column — the int8 values enter the MXU as
+                # they are and no dequantised page is ever built
+                def lanes(ref):
+                    return jnp.concatenate(
+                        [_scale_lanes(ref[0, pl.ds(_page_id(bb, pg0, i), 1), :], rows)
+                         for i in range(N)], axis=1)
 
-            @pl.when(ok)
-            def _():
-                kcp.wait()
-                vcp.wait()
-                s = g % R
-                q = (q_ref[0].astype(jnp.float32) * sm_scale).reshape(KH, G, D)
-                k = k_buf[s].astype(jnp.float32)  # [page, KH, D]
-                v = v_buf[s].astype(jnp.float32)
-                if quantized:
-                    # dequant at the VMEM ring exit: per-page per-kv-head
-                    # scale rows looked up from the resident slab
-                    k = k * _scale_column(ks_ref[0, pl.ds(pid, 1), :])[None]
-                    v = v * _scale_column(vs_ref[0, pl.ds(pid, 1), :])[None]
-                k = k.transpose(1, 0, 2)  # [KH, page, D]
-                v = v.transpose(1, 0, 2)
-                # batched over KH: [KH, G, D] x [KH, page, D] -> [KH, G, page]
-                scores = lax.dot_general(
-                    q, k, (((2,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32,
-                )
-                if logit_softcap is not None:
-                    scores = logit_softcap * jnp.tanh(scores / logit_softcap)
-                start = (lo_pg + p * N + i) * page_size
-                idx = start + lax.broadcasted_iota(
-                    jnp.int32, (1, 1, page_size), 2
-                )
-                visible = (idx >= lo) & (idx < paged_end)
-                scores = jnp.where(visible, scores, NEG_INF)
-
-                m_prev, l_prev = m_ref[...], l_ref[...]
-                m_new = jnp.maximum(m_prev, scores.max(axis=-1))
-                alpha = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
-                pij = jnp.exp(scores - m_new[..., None])
-                pij = jnp.where(visible, pij, 0.0)
-                m_ref[...] = m_new
-                l_ref[...] = l_prev * alpha + pij.sum(axis=-1)
-                # [KH, G, page] x [KH, page, D] -> [KH, G, D]
-                pv = lax.dot_general(
-                    pij, v, (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32,
-                )
-                acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
+                k_scale, v_scale = lanes(ks_ref), lanes(vs_ref)
+            _fold(k_buf[s], v_buf[s], pg0 * page_size, lo, paged_end,
+                  k_scale, v_scale)
 
     @pl.when(live & (p == cells_ref[b] - 1))
     def _():
-        m_prev, l_prev, acc = m_ref[...], l_ref[...], acc_ref[...]
         if has_cur:
-            # one extra online-softmax update over the in-register window
-            # (entries j < cl at positions paged_end + j; the final entry,
-            # the current token, is always causally visible)
-            q = (q_ref[0].astype(jnp.float32) * sm_scale).reshape(KH, G, D)
-            kc = k_cur_ref[0].astype(jnp.float32).transpose(1, 0, 2)  # [KH, C, D]
-            vc = v_cur_ref[0].astype(jnp.float32).transpose(1, 0, 2)
-            C = kc.shape[1]
-            s_cur = lax.dot_general(
-                q, kc, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )  # [KH, G, C]
-            if logit_softcap is not None:
-                s_cur = logit_softcap * jnp.tanh(s_cur / logit_softcap)
-            j = lax.broadcasted_iota(jnp.int32, (1, 1, C), 2)
-            pos_j = paged_end + j
-            vis = (j < cl_ref[b]) & (pos_j >= lo)
-            s_cur = jnp.where(vis, s_cur, NEG_INF)
-            m_new = jnp.maximum(m_prev, s_cur.max(axis=-1))
-            alpha = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
-            p_cur = jnp.exp(s_cur - m_new[..., None])
-            p_cur = jnp.where(vis, p_cur, 0.0)
-            l_prev = l_prev * alpha + p_cur.sum(axis=-1)
-            pv = lax.dot_general(
-                p_cur, vc, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            acc = acc * alpha[..., None] + pv
-        out = acc / jnp.maximum(l_prev, 1e-30)[..., None]
-        o_ref[0] = out.reshape(NH, D).astype(o_ref.dtype)
+            # one more update over the in-register window (entries j < cl at
+            # positions paged_end + j; the final entry, the current token, is
+            # always causally visible)
+            _fold(k_cur_ref[0], v_cur_ref[0], paged_end, lo, kv_len)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _auto_pages_per_block(max_pages: int, page_size: int, itemsize: int) -> int:
-    """~128 KV slots of bookkeeping per cell for short-context buckets;
-    long-context buckets (>=128 pages) use ~512 — with the DMA ring the cell
-    size no longer bounds fetch depth, it only amortizes the per-cell
-    grid/index-map overhead. int8 pools double the slot target: each slot
-    costs half the bytes, so the same VMEM/DMA budget amortizes twice the
-    bookkeeping (re-sweep with scripts/profile_decode.py --impl pallas_int8
-    when retuning)."""
-    target = 512 if max_pages >= 128 else 128
-    if itemsize == 1:
-        target *= 2
-    return max(1, min(target // page_size, max_pages))
+# What one grid cell costs beside its bytes is a chain of dependent steps
+# (score matmul -> row max -> exp -> PV matmul -> rescale), about 1 us on a
+# v5e whatever the tile holds (PERF.md, PR 31): a block has to be worth
+# several of those in HBM time, and 4 MiB of K + V is 5 us at 819 GB/s.
+# Larger blocks gained nothing and a row's masked tail (computed, never
+# fetched) grows with them.
+_BLOCK_BYTES = 4 << 20
+# ... and at most this many (token, kv head) rows: the [NH, rows] score and
+# probability tiles and the float32 view of the V block scale with them
+# (int8 pools halve the bytes a row, not the rows).
+_BLOCK_ROWS = 8192
+# VMEM the ring of K and V blocks may take, both arrays together: three
+# blocks of _BLOCK_BYTES (one consumed, two in flight).
+_RING_VMEM_BYTES = 12 << 20
+# Scoped VMEM of the whole call: the ring, the tiles above and Mosaic's own
+# temporaries (a v5e core has 128 MiB; the default scope is 16).
+_VMEM_LIMIT_BYTES = 32 << 20
+
+
+def _auto_pages_per_block(
+    max_pages: int, page_size: int, kv_heads: int, head_dim: int, itemsize: int
+) -> int:
+    """Pages one grid cell consumes as ONE tile — a pure function of what
+    the call can see: as many as make ``_BLOCK_BYTES`` of K + V or
+    ``_BLOCK_ROWS`` (token, kv head) rows, whichever is fewer, while two
+    such blocks fit the ring's VMEM budget; never more than the bucket
+    holds. It sizes the grid too: ``ceil(max_pages / N)`` cells a row."""
+    page_bytes = 2 * page_size * kv_heads * head_dim * itemsize
+    n = min(
+        min(_BLOCK_BYTES, _RING_VMEM_BYTES // 2) // page_bytes,
+        _BLOCK_ROWS // (page_size * kv_heads),
+    )
+    return max(1, min(n, max_pages))
+
+
+def _auto_ring_blocks(
+    pages_per_block: int, page_size: int, kv_heads: int, head_dim: int,
+    itemsize: int,
+) -> int:
+    """Blocks in the VMEM ring (one consumed, the rest in flight): what the
+    budget holds, 2 or 3 (a third block in flight measured 3-7% faster
+    than two, a fourth nothing)."""
+    block_bytes = 2 * pages_per_block * page_size * kv_heads * head_dim * itemsize
+    return max(2, min(3, _RING_VMEM_BYTES // block_bytes))
+
+
+def decode_block_shape(
+    max_pages: int, page_size: int, kv_heads: int, head_dim: int,
+    itemsize: int, pages_per_block: int | None = None,
+    prefetch_pages: int | None = None,
+) -> tuple[int, int]:
+    """(pages a block, blocks in the ring) the kernel runs one (batch,
+    pages) bucket with; the two overrides are engine/config.py's."""
+    if pages_per_block is None:
+        pages_per_block = _auto_pages_per_block(
+            max_pages, page_size, kv_heads, head_dim, itemsize
+        )
+    n = max(1, min(int(pages_per_block), max_pages))
+    if prefetch_pages is None:
+        ring = _auto_ring_blocks(n, page_size, kv_heads, head_dim, itemsize)
+    else:
+        ring = max(2, -(-int(prefetch_pages) // n))
+    return n, ring
 
 
 def decode_smem_bytes(
-    batch: int, max_pages: int, page_size: int, itemsize: int
+    batch: int, max_pages: int, page_size: int, kv_heads: int, head_dim: int,
+    itemsize: int,
 ) -> int:
     """SMEM the decode kernel's scalar-prefetch operands take at one
-    (batch, pages) bucket with auto ``pages_per_block``: the [B, max_pages]
-    page table, the two packed cell maps of B * n_blocks entries, and five
-    [B] vectors — all int32. engine/runner.kernel_refusal holds the largest
-    bucket against the chip's SMEM."""
-    n_blocks = -(-max_pages // _auto_pages_per_block(max_pages, page_size, itemsize))
-    return 4 * batch * (max_pages + 2 * n_blocks + 5)
+    (batch, pages) bucket with the derived block: the [B, max_pages] page
+    table, the two packed cell maps of B * n_blocks entries, four [B]
+    vectors (lengths, window entries, cells and live pages a row) and three
+    scalars (window, layer, total) — all int32.
+    engine/runner.kernel_refusal holds the largest bucket against the
+    chip's SMEM."""
+    n = _auto_pages_per_block(max_pages, page_size, kv_heads, head_dim, itemsize)
+    n_blocks = -(-max_pages // n)
+    return 4 * (batch * (max_pages + 2 * n_blocks + 4) + 3)
 
 
 @functools.partial(
@@ -326,12 +419,14 @@ def ragged_paged_attention_decode(
 ) -> jnp.ndarray:
     """Decode attention over paged KV, streaming pages HBM->VMEM.
 
-    With ``k_scales/v_scales`` (int8 pools, ops/quant.py contract) each
-    page dequantizes right after its DMA lands in the VMEM ring — HBM
-    streams HALF the bytes and fp values never round-trip through it. The
-    current layer's [P, KH] scale slabs stay VMEM-resident (fetched once,
-    constant index map; P*KH*4 bytes each — ~256 KB at 8k pages x 8 heads).
-    ``k_cur/v_cur`` stay fp: the in-register window never quantizes.
+    With ``k_scales/v_scales`` (int8 pools, ops/quant.py contract) HBM
+    streams HALF the bytes; the int8 values enter the matmuls as they are
+    (exact in bf16 and float32) and a page's per-kv-head scale multiplies
+    its score and probability columns in float32 — no dequantised page is
+    ever built. The current layer's [P, KH] scale slabs stay VMEM-resident
+    (fetched once, constant index map; P*KH*4 bytes each — ~256 KB at 8k
+    pages x 8 heads). ``k_cur/v_cur`` stay fp: the in-register window never
+    quantizes.
 
     With ``k_cur/v_cur`` (write-after-attend mode), pool slots at positions
     >= ``seq_lens - cur_lens`` are treated as stale and the in-register
@@ -350,14 +445,13 @@ def ragged_paged_attention_decode(
     at ~1.5 ms/step on v5e), because XLA cannot fuse a slice into a
     pallas_call operand.
 
-    ``pages_per_block``: pages processed per packed grid cell (auto: ~128 KV
-    slots per cell, ~512 for >=128-page buckets). With the v2 DMA ring this
-    mostly sets grid-bookkeeping granularity, not pipeline depth.
+    ``pages_per_block``: pages one packed grid cell consumes as ONE tile
+    (auto: ``_auto_pages_per_block`` — 4 MiB of K + V or 8192 (token, kv
+    head) rows, whichever is fewer). It sizes the grid as well.
 
-    ``prefetch_pages``: depth of the VMEM page-copy ring — how many page
-    DMAs stay in flight ahead of compute (auto: up to 8, bounded by a ~2 MB
-    per-array VMEM budget). This is what keeps the HBM pipeline full at
-    small pages (v1's per-cell BlockSpec fetches could not).
+    ``prefetch_pages``: pages the VMEM ring holds, rounded up to whole blocks
+    (auto: ``_auto_ring_blocks`` — three blocks where 12 MiB hold them, one
+    consumed and two in flight; never fewer than two).
 
     The grid itself is RAGGED: live (sequence, block) cells pack to the
     front of a 1D grid sized for the bucket's worst case, and trailing dead
@@ -374,27 +468,26 @@ def ragged_paged_attention_decode(
             k_scales = k_scales[None]
             v_scales = v_scales[None]
         layer = 0
-    _, P_pool, page_size, KH, _ = k_pages.shape
+    L, P_pool, page_size, KH, _ = k_pages.shape
     max_pages = page_table.shape[1]
-    G = NH // KH
     scale = sm_scale if sm_scale is not None else D**-0.5
+    # a page as the kernel consumes it: (token, kv head)-major rows of D.
+    # Row-major, so the pool's own bytes — no page is relaid out anywhere.
+    rows = page_size * KH
+    k_pages = k_pages.reshape(L, P_pool, rows, D)
+    v_pages = v_pages.reshape(L, P_pool, rows, D)
     has_cur = k_cur is not None
-    if has_cur and k_cur.ndim == 3:
-        k_cur = k_cur[:, None]  # [B, KH, D] -> C=1 window
-        v_cur = v_cur[:, None]
-    if pages_per_block is None:
-        pages_per_block = _auto_pages_per_block(
-            max_pages, page_size, jnp.dtype(k_pages.dtype).itemsize
-        )
-    N = max(1, min(pages_per_block, max_pages))
+    if has_cur:
+        # [B, KH, D] is a C=1 window; [B, C, KH, D] -> the same row order
+        k_cur = k_cur.reshape(B, -1, D)
+        v_cur = v_cur.reshape(B, -1, D)
+    kv_itemsize = jnp.dtype(k_pages.dtype).itemsize  # 1 for int8 pools
+    N, RB = decode_block_shape(
+        max_pages, page_size, KH, D, kv_itemsize, pages_per_block,
+        prefetch_pages,
+    )
     n_blocks = -(-max_pages // N)
     n_cells = B * n_blocks
-    if prefetch_pages is None:
-        # ring depth: up to 8 pages in flight, bounded by ~2 MB of VMEM per
-        # pool array (k and v each get a ring this size)
-        slot_bytes = page_size * KH * D * jnp.dtype(k_pages.dtype).itemsize
-        prefetch_pages = max(2, min(8, (2 << 20) // max(slot_bytes, 1)))
-    R = max(2, int(prefetch_pages))
     win = (
         jnp.full((1,), 2**30, jnp.int32)
         if window is None
@@ -432,9 +525,6 @@ def ragged_paged_attention_decode(
     def row3(c, pt, lens, w, _cl, l, so, bo, ce, lp, tot):
         return (so[c], 0, 0)
 
-    def row4(c, pt, lens, w, _cl, l, so, bo, ce, lp, tot):
-        return (so[c], 0, 0, 0)
-
     def srow(c, pt, lens, w, _cl, l, so, bo, ce, lp, tot):
         # scale slabs: the whole [P, KH] slice of the CURRENT layer; the
         # constant block index means the pipeline fetches it once
@@ -453,10 +543,9 @@ def ragged_paged_attention_decode(
         ]
         operands += [k_scales, v_scales]
     if has_cur:
-        C = k_cur.shape[1]
         in_specs += [
-            pl.BlockSpec((1, C, KH, D), row4),
-            pl.BlockSpec((1, C, KH, D), row4),
+            pl.BlockSpec((1, k_cur.shape[1], D), row3),
+            pl.BlockSpec((1, k_cur.shape[1], D), row3),
         ]
         operands += [k_cur, v_cur]
 
@@ -466,26 +555,26 @@ def ragged_paged_attention_decode(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, NH, D), row3),
         scratch_shapes=[
-            pltpu.VMEM((R, page_size, KH, D), k_pages.dtype),
-            pltpu.VMEM((R, page_size, KH, D), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((R,)),
-            pltpu.SemaphoreType.DMA((R,)),
-            pltpu.VMEM((KH, G), jnp.float32),
-            pltpu.VMEM((KH, G), jnp.float32),
-            pltpu.VMEM((KH, G, D), jnp.float32),
+            pltpu.VMEM((RB, N * rows, D), k_pages.dtype),
+            pltpu.VMEM((RB, N * rows, D), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((RB,)),
+            pltpu.SemaphoreType.DMA((RB,)),
+            pltpu.VMEM((NH, 1), jnp.float32),
+            pltpu.VMEM((NH, 1), jnp.float32),
+            pltpu.VMEM((NH, D), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _decode_kernel, sm_scale=scale, kv_heads=KH,
+        _decode_kernel, sm_scale=scale, kv_heads=KH, page_size=page_size,
         logit_softcap=logit_softcap, has_cur=has_cur, pages_per_block=N,
-        prefetch=R, quantized=quantized,
+        ring_blocks=RB, quantized=quantized,
     )
-    kv_itemsize = jnp.dtype(k_pages.dtype).itemsize  # 1 for int8 pools
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NH, D), q.dtype),
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         cost_estimate=pl.CostEstimate(
             flops=4 * B * NH * D * max_pages * page_size,
             bytes_accessed=(

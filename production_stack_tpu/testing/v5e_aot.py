@@ -178,6 +178,55 @@ def preset_shapes() -> dict:
     return {v: k for k, v in seen.items()}
 
 
+# every decode (batch, pages) bucket the benchmark's two cells dispatch
+# (PERF_LEDGER.jsonl's breakdowns: bf16[64|32,32,128] in mistral-7b-d16.chat,
+# bf16[32|16|8,28,128] in qwen2.5-7b-d14.sessions; contexts to 64 pages), at
+# the cells' pool sizes and depths, with the burst window of 8 and the block
+# the kernel derives: id -> compile_decode_kernel arguments
+CELL_BUCKETS = {
+    f"{cell}/b{B}xp{pages}": dict(
+        B=B, max_pages=pages, pool_pages=pool_pages, layers=layers, **shape
+    )
+    for cell, shape, pool_pages, layers, buckets in (
+        ("mistral-7b-d16.chat", dict(NH=32, KH=8, window=4096), 953, 16,
+         [(B, p) for B in (64, 32) for p in (16, 32, 64)]),
+        ("qwen2.5-7b-d14.sessions", dict(NH=28, KH=4, window=None), 2285, 14,
+         [(32, 32)] + [(B, 64) for B in (32, 16, 8)]),
+    )
+    for B, pages in buckets
+}
+
+
+def smem_operand_bytes(*, B: int, max_pages: int, NH: int, KH: int) -> int:
+    """Bytes of the decode kernel's scalar-prefetch operands as the traced
+    ``pallas_call`` holds them (what ``decode_smem_bytes`` must equal)."""
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        ragged_paged_attention_decode,
+    )
+
+    D, page, C = 128, 64, 8
+    sd = jax.ShapeDtypeStruct
+    pool = sd((2, 8, page, KH, D), jnp.bfloat16)
+    win = sd((B, C, KH, D), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(
+        lambda q, kp, vp, pt, lens, kc, vc, cl: ragged_paged_attention_decode(
+            q, kp, vp, pt, lens, None, k_cur=kc, v_cur=vc, cur_lens=cl, layer=0
+        )
+    )(sd((B, NH, D), jnp.bfloat16), pool, pool, sd((B, max_pages), jnp.int32),
+      sd((B,), jnp.int32), win, win, sd((B,), jnp.int32))
+
+    def calls(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    (eqn,) = calls(jaxpr.jaxpr)
+    n = eqn.params["grid_mapping"].num_index_operands
+    return sum(v.aval.size * v.aval.dtype.itemsize for v in eqn.invars[:n])
+
+
 # the prefill kernel takes 5-10 s per shape: the tier-1 run compiles the
 # widest bf16 one, ``--slow`` the rest (and the SMEM boundary)
 TIER1_PREFILL = (32, 8, 128, False)
@@ -195,7 +244,8 @@ def run_matrix(slow: bool = False) -> dict:
     from production_stack_tpu.engine import runner
     from production_stack_tpu.models import llama
 
-    out: dict = {"decode": {}, "prefill": {}, "smem": {}, "tp4_step": {}}
+    out: dict = {"decode": {}, "prefill": {}, "prefill_refused": {},
+                 "smem": {}, "tp4_step": {}}
     for cid, (NH, KH, D, int8) in preset_shapes().items():
         refusal = runner.kernel_refusal(
             head_dim=D, kv_heads_per_shard=KH, pool_itemsize=1 if int8 else 2
@@ -205,11 +255,20 @@ def run_matrix(slow: bool = False) -> dict:
             out["decode"][cid] = dict(
                 _attempt(compile_decode_kernel, B=8, **kw), refusal=refusal
             )
+        if slow and refusal and "sublane tile" in refusal:
+            out["prefill_refused"][cid] = _attempt(
+                compile_prefill_kernel, B=4, T=512, **kw
+            )
         tier1_shape = (NH, KH, D, int8) == TIER1_PREFILL
         if refusal is None and tier1_shape != slow:
             # prefill_batch x prefill_chunk of the default config, fused write
             out["prefill"][cid] = _attempt(
                 compile_prefill_kernel, B=4, T=512, **kw
+            )
+    if not slow:
+        for cid, kw in CELL_BUCKETS.items():
+            out["decode"][cid] = dict(
+                _attempt(compile_decode_kernel, **kw), refusal=None
             )
     if slow:
         for rows in (64, 128):  # 64 x 2048 fits the rule's budget, 128 does not

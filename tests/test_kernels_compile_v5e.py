@@ -21,6 +21,7 @@ import sys
 import pytest
 
 from production_stack_tpu.engine import runner
+from production_stack_tpu.testing import v5e_aot
 from production_stack_tpu.testing.procs import REPO_ROOT, cpu_env
 
 
@@ -49,16 +50,51 @@ def slow_half(tmp_path_factory):
 
 def test_decode_kernel_compiles_or_rule_excludes(tier1):
     assert len(tier1["decode"]) >= 8
+    decode_only = set()
     for cid, r in tier1["decode"].items():
         if r["refusal"] is None:
             assert r["compiled"], f"{cid}: {r['error']}"
+        elif "sublane tile" in r["refusal"]:
+            # kv heads a shard that do not fill a sublane tile: the decode
+            # kernel reads a page as [page * KH, D] rows and compiles; the
+            # rule stands for the PREFILL kernel, which DMAs [page, KH, D]
+            # (the slow half shows Mosaic refusing it), and because no chip
+            # run has checked the decode kernel's numbers at these shapes
+            assert r["compiled"], f"{cid}: {r['error']}"
+            decode_only.add(cid)
         else:
             # the rule must not hide a shape that works: Mosaic does refuse
             assert not r["compiled"], f"{cid} compiles but the rule excludes it"
             assert "aligned to tiling" in r["error"], f"{cid}: {r['error']}"
+    assert decode_only == {"llama-3-8b/tp4/int8", "qwen2.5-7b/tp4/bf16",
+                           "qwen2.5-7b/tp4/int8"}
     compiled = {c for c, r in tier1["decode"].items() if r["compiled"]}
     assert {"llama-3-8b/tp1/bf16", "llama-3-8b/tp1/int8",
             "qwen2.5-7b/tp1/bf16", "llama-3-8b/tp4/bf16"} <= compiled
+    # every (batch, pages) bucket the benchmark's two cells dispatch, with
+    # the block the kernel derives there (PR 27 died at one of these on the
+    # chip with every interpret-mode test green)
+    assert len(v5e_aot.CELL_BUCKETS) == 10
+    assert set(v5e_aot.CELL_BUCKETS) <= compiled
+
+
+@pytest.mark.parametrize(
+    "bucket",
+    [dict(B=64, max_pages=2048, NH=32, KH=8),   # the largest default bucket
+     dict(B=64, max_pages=64, NH=32, KH=8),     # mistral-7b-d16.chat
+     dict(B=32, max_pages=64, NH=28, KH=4)],    # qwen2.5-7b-d14.sessions
+    ids=["b64xp2048", "chat-b64xp64", "sessions-b32xp64"],
+)
+def test_decode_smem_bytes_is_the_scalar_prefetch_operands_size(bucket):
+    """The rule's SMEM count (kernel_refusal holds it against 960 KiB) is
+    the traced pallas_call's scalar-prefetch operands, byte for byte."""
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        decode_smem_bytes,
+    )
+
+    assert v5e_aot.smem_operand_bytes(**bucket) == decode_smem_bytes(
+        bucket["B"], bucket["max_pages"], 64, bucket["KH"], 128, 2
+    )
 
 
 def test_prefill_kernel_compiles_at_the_widest_shape(tier1):
@@ -78,6 +114,16 @@ def test_prefill_kernel_compiles_at_every_other_shape(slow_half):
     assert len(slow_half["prefill"]) >= 4
     for cid, r in slow_half["prefill"].items():
         assert r["compiled"], f"{cid}: {r['error']}"
+
+
+@pytest.mark.slow
+def test_prefill_kernel_is_refused_where_the_sublane_rule_says(slow_half):
+    """The shapes at which the decode kernel alone compiles (above): the
+    rule's sublane constraint is the prefill kernel's page DMA."""
+    assert len(slow_half["prefill_refused"]) == 3
+    for cid, r in slow_half["prefill_refused"].items():
+        assert not r["compiled"], f"{cid} compiles but the rule excludes it"
+        assert "aligned to tiling" in r["error"], f"{cid}: {r['error']}"
 
 
 @pytest.mark.slow

@@ -713,3 +713,179 @@ class TestGemma2ShardedDecode:
             with pytest.raises(ValueError, match="sequence/pipeline"):
                 ModelRunner(cfg, mesh=make_mesh(**kw), num_pages=16,
                             page_size=8, seed=0)
+
+
+# -- the two benchmark configurations' decode shapes ---------------------------
+# mistral-7b-d16 (KH 8, 4 query heads a kv head, window 4096) and
+# qwen2.5-7b-d14 (KH 4, 7 a kv head, no window) at the real head dim and page
+# size, every batch bucket x page bucket their cells dispatch (PERF.md
+# section 4). A 4096 window cannot start inside a bucket of <= 64 pages, so
+# the windowed configuration runs it capped to half the bucket plus 37 slots:
+# every long row then starts mid-page, mid-block. The pool dtype and the
+# burst window's size rotate so that each (configuration, page bucket) meets
+# all four combinations over its batches.
+_BENCH_CONFIGS = {"kh8-g4-window": (8, 4, 4096), "kh4-g7": (4, 7, None)}
+_BENCH_COMBOS = [("bf16", 8), ("int8", 8), ("bf16", 1), ("int8", 1)]
+
+
+def _bench_cases():
+    for cfg in _BENCH_CONFIGS:
+        for bi, B in enumerate((8, 16, 32, 64)):
+            for pi, bucket in enumerate((16, 32, 64)):
+                pool, C = _BENCH_COMBOS[(bi + pi) % 4]
+                yield pytest.param(
+                    cfg, B, bucket, pool, C,
+                    id=f"{cfg}-b{B}-p{bucket}-{pool}-c{C}",
+                )
+
+
+class TestBenchmarkDecodeShapes:
+    @pytest.mark.parametrize("cfg,B,bucket,pool,C", list(_bench_cases()))
+    def test_matches_oracle(self, cfg, B, bucket, pool, C):
+        from production_stack_tpu.ops.pallas.paged_attention import (
+            decode_block_shape,
+        )
+        from production_stack_tpu.ops.quant import quantize_page_host
+
+        KH, G, window = _BENCH_CONFIGS[cfg]
+        D, page, P = 128, 64, 96
+        if window is not None:
+            window = min(window, bucket * page // 2 + 37)
+        N, _ = decode_block_shape(bucket, page, KH, D, 1 if pool == "int8" else 2)
+        rng = np.random.RandomState(B * 1000 + bucket)
+        q = jnp.asarray(rng.randn(B, KH * G, D), jnp.bfloat16)
+        kf = rng.randn(P, page, KH, D).astype(np.float32)
+        vf = rng.randn(P, page, KH, D).astype(np.float32)
+        pt = jnp.asarray(rng.randint(0, P, (B, bucket)), jnp.int32)
+        cap = bucket * page
+        lens = rng.randint(1, cap + 1, size=B)
+        # rows every batch holds: the full bucket (under the window: a start
+        # mid-block), a live range that ends one page into its last block,
+        # one page, only the burst window (no paged slot at all), a padded
+        # row, and a range that ends exactly on a block boundary
+        lens[:6] = [cap, min(cap, (N + 1) * page + C - 7), 40, C, 0,
+                    min(cap, N * page + C)]
+        cur = np.minimum(rng.randint(1, C + 1, size=B), np.maximum(lens, 1))
+        cur[3] = C
+        lens, cur = jnp.asarray(lens, jnp.int32), jnp.asarray(cur, jnp.int32)
+        kc = jnp.asarray(rng.randn(B, C, KH, D), jnp.bfloat16)
+        vc = jnp.asarray(rng.randn(B, C, KH, D), jnp.bfloat16)
+        kw = dict(window=window, k_cur=kc, v_cur=vc, cur_lens=cur)
+        if pool == "int8":
+            # the host quantizer reads the pool's pages as its layer axis:
+            # one scale per (page, kv head), the pool's contract
+            (kp, ks), (vp, vs) = quantize_page_host(kf), quantize_page_host(vf)
+            kp, vp = jnp.asarray(kp), jnp.asarray(vp)
+            kw.update(k_scales=jnp.asarray(ks), v_scales=jnp.asarray(vs))
+        else:
+            kp, vp = jnp.asarray(kf, jnp.bfloat16), jnp.asarray(vf, jnp.bfloat16)
+        ref = paged_attention_decode(q, kp, vp, pt, lens, **kw)
+        out = ragged_paged_attention_decode(
+            q, kp, vp, pt, lens, interpret=True, **kw
+        )
+        out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+        assert not np.isnan(out).any()
+        np.testing.assert_array_equal(out[4], 0.0)  # the padded row
+        np.testing.assert_allclose(out, ref, atol=3e-2, rtol=3e-2)
+
+
+class TestBlockDerivation:
+    """The block of pages a grid cell consumes is derived from the shapes
+    the call sees (ops/pallas/paged_attention._auto_pages_per_block)."""
+
+    def test_is_a_pure_function_of_the_shapes(self):
+        import inspect
+
+        from production_stack_tpu.ops.pallas import paged_attention as pa
+
+        assert list(inspect.signature(pa._auto_pages_per_block).parameters) == [
+            "max_pages", "page_size", "kv_heads", "head_dim", "itemsize",
+        ]
+        # the two benchmark configurations at their 64-page bucket: 4 MiB of
+        # K + V a block (8192 rows), three blocks in the ring
+        assert pa.decode_block_shape(64, 64, 8, 128, 2) == (16, 3)
+        assert pa.decode_block_shape(64, 64, 4, 128, 2) == (32, 3)
+        # int8 pools: the row cap holds the tile where the bytes halve
+        assert pa.decode_block_shape(64, 64, 8, 128, 1) == (16, 3)
+        # never past the bucket; the overrides stay overrides
+        assert pa.decode_block_shape(4, 64, 8, 128, 2) == (4, 3)
+        assert pa.decode_block_shape(64, 64, 8, 128, 2, 2, 8) == (2, 4)
+        assert pa.decode_block_shape(64, 64, 8, 128, 2, None, 8) == (16, 2)
+        for args in [(64, 64, 8, 128, 2), (2048, 16, 2, 256, 1)]:
+            assert pa._auto_pages_per_block(*args) == pa._auto_pages_per_block(*args)
+
+    @pytest.mark.parametrize("family", ["llama", "gemma2", "opt"])
+    def test_ring_and_block_stay_inside_the_vmem_budget(self, family):
+        import importlib
+
+        from production_stack_tpu.ops.pallas import paged_attention as pa
+
+        presets = importlib.import_module(
+            f"production_stack_tpu.models.{family}"
+        ).PRESETS
+        seen = 0
+        for cfg in presets.values():
+            for tp in (1, 2, 4, 8):
+                if cfg.num_heads % tp or cfg.num_kv_heads % tp:
+                    continue
+                KH, NH, D = cfg.num_kv_heads // tp, cfg.num_heads // tp, cfg.head_dim
+                for itemsize in (1, 2, 4):
+                    for page in (16, 64, 128):
+                        for max_pages in (1, 4, 64, 2048):
+                            n, ring = pa.decode_block_shape(
+                                max_pages, page, KH, D, itemsize
+                            )
+                            assert 1 <= n <= max_pages and ring in (2, 3)
+                            rows = n * page * KH
+                            block = 2 * rows * D * itemsize
+                            if n > 1:
+                                assert rows <= pa._BLOCK_ROWS
+                                assert block <= pa._BLOCK_BYTES
+                            assert ring * block <= pa._RING_VMEM_BYTES or n == 1
+                            # the ring, the float32 V view, and the score,
+                            # probability and mask tiles of the block
+                            tiles = rows * D * 4 + 4 * max(NH, 8) * rows * 4
+                            assert ring * block + tiles <= pa._VMEM_LIMIT_BYTES or n == 1
+                            seen += 1
+        assert seen >= 36
+
+    def test_stats_show_the_block_of_a_dispatched_bucket(self):
+        import asyncio
+
+        from production_stack_tpu.engine.config import EngineConfig
+        from production_stack_tpu.engine.engine import LLMEngine
+        from production_stack_tpu.engine.scheduler import SamplingParams
+        from production_stack_tpu.ops.pallas.paged_attention import (
+            decode_block_shape,
+        )
+
+        eng = LLMEngine(EngineConfig(
+            model="llama-debug", max_model_len=128, max_num_seqs=2,
+            num_pages=32, page_size=8, prefill_chunk=32,
+            attn_impl="pallas_interpret",
+        ))
+        assert eng.stats()["decode_kernel_blocks"] == {}
+        eng.start()
+        try:
+            async def go():
+                async for _ in eng.generate(
+                    "blk-1", prompt="which block",
+                    params=SamplingParams(
+                        max_tokens=4, temperature=0.0, ignore_eos=True
+                    ),
+                ):
+                    pass
+
+            asyncio.run(go())
+            blocks = eng.stats()["decode_kernel_blocks"]
+        finally:
+            eng.stop()
+        assert blocks, "no decode bucket was dispatched"
+        cfg = eng.runner.cfg
+        for bucket, got in blocks.items():
+            pages = int(bucket.split("x")[1])
+            n, ring = decode_block_shape(
+                pages, 8, cfg.num_kv_heads, cfg.head_dim,
+                np.dtype(eng.runner.kv_pool_dtype).itemsize,
+            )
+            assert got == {"pages_per_block": n, "ring_blocks": ring}
