@@ -956,7 +956,7 @@ class EngineServer:
         lines.extend(_latency_hist.render(f'model_name="{m}"'))
         # per-phase histograms (tracing subsystem): queue wait, prefill,
         # time-per-output-token, offload restore — the dashboard's
-        # phase-breakdown panels and bench.py's attribution read these
+        # phase-breakdown panels read these
         from production_stack_tpu.tracing import (
             render_collector_metrics,
             render_flightrecorder_metrics,

@@ -115,8 +115,8 @@ class EngineConfig:
     # landed CONTIGUOUSLY per packed grid cell and folded as one wide
     # matmul (0 = auto: ~512 slots). prefill_prefetch_pages: page DMAs kept
     # in flight ahead of the cell being consumed (0 = auto: ~2 cells'
-    # worth). Retune with scripts/profile_prefill.py, which reports
-    # achieved HBM GB/s + tok/s per (chunk, context) bucket.
+    # worth). Neither has been swept on this chip: no benchmark cell is
+    # prefill-bound yet, and one must exist first (ROADMAP S4 / S7).
     prefill_pages_per_block: int = 0
     prefill_prefetch_pages: int = 0
     # fused paged-KV write: the prefill kernel commits the chunk's K/V to
@@ -134,9 +134,9 @@ class EngineConfig:
     # never round-trips through HBM); quantization inside the fused prefill
     # write and on the decode feedback commit. Offload/warm-start/
     # directory/migration blobs ship the int8 bytes + scales (serde v3,
-    # CRC-framed, tp split/join-aware). Quality: ~1-1.5% relative logit
-    # error measured (docs/benchmarking.md); bench.py records the greedy
-    # token-match delta. Requires kv_write_mode=post; not compatible with
+    # CRC-framed, tp split/join-aware). Quality on the chip: not measured
+    # (no int8 cell; tests/test_kv_quant.py bounds the logit error against
+    # fp pools on the CPU). Requires kv_write_mode=post; not compatible with
     # speculative_k>0, sp/pp meshes, disagg kv_role, or device KV transfer.
     kv_cache_dtype: str = "auto"
     # tensor parallelism: attention heads + MLP hidden shard over the tp mesh
@@ -318,7 +318,7 @@ class EngineConfig:
     # timings, JAX compiles — exported via the debug-gated
     # GET /v1/debug/flightrecorder and auto-dumped to disk on anomalies.
     # Default ON: the hot-path cost is one dict append per dispatch
-    # (bench.py asserts < 2% decode overhead as flightrecorder_overhead_ratio).
+    # (recorder-off against recorder-on on the chip: not measured).
     flight_recorder: bool = True
     flight_recorder_capacity: int = 8192
     # anomaly-dump directory (engine crash / SIGTERM drain / shed burst /
@@ -358,8 +358,8 @@ _FLAG_HELP = {
     ),
     "prefill_pages_per_block": (
         "prefill kernel: KV pages landed contiguously per packed grid cell "
-        "and folded as one wide matmul (0 = auto ~512 KV slots; retune with "
-        "scripts/profile_prefill.py)"
+        "and folded as one wide matmul (0 = auto ~512 KV slots; never swept "
+        "on the chip)"
     ),
     "prefill_prefetch_pages": (
         "prefill kernel: page DMAs kept in flight ahead of the cell being "

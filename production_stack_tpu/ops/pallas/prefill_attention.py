@@ -52,8 +52,8 @@ cost nothing.
 Equivalent role in the reference: vLLM's CUDA prefill (flash-attn) kernels
 inside the engine image; PAPERS "Ragged Paged Attention" is the direct
 blueprint. Tests assert equivalence against the XLA oracle
-(tests/test_pallas_prefill.py); scripts/profile_prefill.py measures the
-achieved page-streaming HBM GB/s and the ragged-scaling property on chip.
+(tests/test_pallas_prefill.py); the achieved page-streaming HBM GB/s on
+the chip is not measured: no benchmark cell is prefill-bound (PERF.md).
 """
 
 from __future__ import annotations
@@ -607,8 +607,8 @@ def ragged_paged_attention_prefill(
         # 128-lane S dim busy, small enough that the f32 score temporaries
         # ([KH, TQ, KB]) stay a few MB. int8 pools double the target —
         # half the ring bytes per slot buys a wider fold for the same VMEM
-        # (the f32 score temporaries grow, hence x2 not x4; re-sweep with
-        # scripts/profile_prefill.py --impl pallas_int8 when retuning)
+        # (the f32 score temporaries grow, hence x2 not x4; neither target
+        # has been swept on this chip: ROADMAP S4 / S7 need a cell first)
         target = 1024 if quantized else 512
         pages_per_block = max(1, min(target // page_size, max_pages))
     N = max(1, min(pages_per_block, max_pages))

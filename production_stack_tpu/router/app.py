@@ -456,7 +456,7 @@ class RouterApp:
         # per-phase histograms (tracing subsystem): the engine observes
         # these; a router-only process exposes them zero-count so either
         # scrape job satisfies the dashboard. In a co-hosted process
-        # (bench.py) both endpoints render the same process-global counts
+        # (the e2e tests) both endpoints render the same process-global counts
         # under different labels, so the dashboard's phase panels filter on
         # model_name!="" to count the engine's series exactly once
         from production_stack_tpu.tracing import (

@@ -1,5 +1,5 @@
 """Deterministic trace-driven workload generator (docs/failure-handling.md
-priority classes; bench.py --qa trace phase, chaos mixed-class-overload).
+priority classes; tests/test_slo_classes.py, chaos mixed-class-overload).
 
 Synthesizes the arrival process the multi-tenant SLO work is judged under:
 
@@ -97,7 +97,7 @@ def generate_trace(
 
 
 def trace_summary(trace: list) -> dict:
-    """Shape digest for logs and assertions (bench embeds it in results)."""
+    """Shape digest for logs and assertions."""
     if not trace:
         return {"n": 0}
     by_class = {"interactive": 0, "batch": 0}

@@ -15,7 +15,7 @@ and the engine device thread), so the collector is deliberately minimal:
   ``1.0`` records everything.
 
 The process-global collector is shared by every server hosted in the process
-(router and engine both, when co-hosted as in bench.py), which is exactly
+(router and engine both, when co-hosted as in the e2e tests), which is exactly
 what lets ``/v1/traces`` on either endpoint stitch a full trace together.
 """
 

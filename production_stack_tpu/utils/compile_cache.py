@@ -5,10 +5,10 @@ variants, seconds each on a v5e host (PERF.md "Bring-up" has the count and
 the total as set-up time). The reference stack never pays this (vLLM ships precompiled CUDA
 kernels); the TPU-native equivalent is JAX's persistent compilation cache,
 which serves every repeat compile from disk — across engine restarts, test
-runs, and bench invocations.
+runs, and benchmark runs.
 
-Called from engine startup (engine/engine.py), the test harness
-(tests/conftest.py), and bench.py. In Kubernetes the cache directory is a
+Called from engine startup (engine/engine.py) and the test harness
+(tests/conftest.py). In Kubernetes the cache directory is a
 PVC mounted into the engine pod (helm/templates/deployment-engine.yaml) so
 restarts and same-model replicas skip straight to warm starts.
 """

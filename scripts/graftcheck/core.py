@@ -38,9 +38,9 @@ REPO = pathlib.Path(__file__).resolve().parent.parent.parent
 META_RULES = ("GC-SUPPRESS-REASON", "GC-SUPPRESS-UNUSED", "GC-BASELINE")
 
 # default scan surface: the package plus the asyncio/JAX-driving entrypoints
-# (bench + chaos/profile scripts + the benchmark load generator). tests/ are
+# (chaos scripts + the benchmark load generator). tests/ are
 # deliberately out of scope — fixture files MUST violate rules.
-DEFAULT_ROOTS = ("production_stack_tpu", "scripts", "benchmarks", "bench.py")
+DEFAULT_ROOTS = ("production_stack_tpu", "scripts", "benchmarks")
 
 _SUPPRESS_RE = re.compile(
     r"#\s*graftcheck:\s*disable=((?:GC\d{3})(?:\s*,\s*GC\d{3})*)"

@@ -24,9 +24,6 @@ surrounded the request:
     curl -s $ENGINE/v1/debug/flightrecorder > fr.json
     python scripts/trace_report.py r.json e.json \
         --flightrecorder fr.json --trace-id <32-hex id>
-
-``bench.py`` imports ``merge_exports`` / ``phase_table`` / ``render_table``
-to emit the same attribution from its in-run trace scrapes.
 """
 
 from __future__ import annotations

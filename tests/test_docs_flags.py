@@ -1,10 +1,16 @@
 """Doc-rot guard: every engine/router CLI flag mentioned in tutorials and
 docs must actually exist in the parsers. The tutorials are the reference
-curriculum's parity surface — a renamed flag silently breaks them."""
+curriculum's parity surface — a renamed flag silently breaks them. Likewise
+every file of this repository a page cites must be in the tree: a deleted
+tool that a page still sends people to is the same rot."""
 
 import argparse
+import functools
+import os
 import pathlib
 import re
+
+import pytest
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
@@ -25,6 +31,7 @@ def _known_flags() -> set:
                 ("production_stack_tpu", "testing", "fake_engine.py"),
                 ("production_stack_tpu", "kvoffload", "cache_server.py"),
                 ("benchmarks", "multi_round_qa.py"),
+                ("perfbench", "run.py"),
                 ("scripts", "chaos_check.py"),
                 ("scripts", "trace_report.py"),
                 ("scripts", "hostspans.py"),
@@ -69,3 +76,111 @@ def test_doc_flags_exist():
                 continue
             missing.setdefault(md.name, []).append(flag)
     assert not missing, f"flags documented but not implemented: {missing}"
+
+
+# -- cited files exist ----------------------------------------------------------
+
+CITING_PAGES = (
+    "README.md",
+    "docs/benchmarking.md",
+    "docs/developer-guide.md",
+    "docs/observability.md",
+    "docs/tracing.md",
+    "docs/static-analysis.md",
+    "docs/multichip-serving.md",
+    "docs/kv-fabric.md",
+    "tutorials/07-benchmark-multi-round-qa-single-tpu.md",
+)
+_CITED_SUFFIXES = (".py", ".sh", ".json", ".md")
+# files the commands on those pages write or download, named without a
+# directory: not files of the repository
+_MADE_BY_COMMANDS = {"r.json", "e.json", "fr.json", "sharegpt_processed.json"}
+
+
+def _ignored() -> set:
+    """Paths .gitignore keeps out of the tree (run outputs, caches)."""
+    lines = (REPO / ".gitignore").read_text().splitlines()
+    return {ln.strip().strip("/") for ln in lines
+            if ln.strip() and not ln.startswith("#")} | {".git"}
+
+
+@functools.cache
+def _tree_files() -> frozenset:
+    """Every file git would commit, as a repo-relative posix path (from the
+    filesystem: the checkout under test need not be a git repository)."""
+    skip, out = _ignored(), set()
+    for root, dirs, files in os.walk(REPO):
+        rel = pathlib.Path(root).relative_to(REPO)
+        dirs[:] = [d for d in dirs
+                   if d != "__pycache__" and (rel / d).as_posix() not in skip]
+        out.update((rel / f).as_posix() for f in files)
+    return frozenset(out - skip)
+
+
+def _cited_paths(text: str) -> set:
+    """File names in backticks or fenced commands that end like a file of
+    ours; ``{a,b}`` alternatives are spelled out."""
+    fenced = re.findall(r"```.*?```", text, re.S)
+    inline = re.findall(r"`([^`\n]+)`", re.sub(r"```.*?```", "", text, flags=re.S))
+    cited = set()
+    for span in fenced + inline:
+        for tok in re.findall(r"[\w./{},*-]+", span):
+            tok = tok.rstrip(".,:;")
+            if not tok.endswith(_CITED_SUFFIXES):
+                continue
+            m = re.fullmatch(r"(.*)\{([^{}]+)\}(.*)", tok)
+            if m:
+                cited.update(m[1] + alt + m[3] for alt in m[2].split(","))
+            else:
+                cited.add(tok)
+    return cited
+
+
+@pytest.mark.parametrize("page", CITING_PAGES)
+def test_cited_files_exist(page):
+    """A path that starts with a top-level directory of the tree, or with a
+    directory of the package, must be a file there (``*`` as a glob); a bare
+    file name must be the name of some file in the tree."""
+    files = _tree_files()
+    names = {f.rsplit("/", 1)[-1] for f in files}
+    top_dirs = {f.split("/", 1)[0] for f in files if "/" in f}
+    package_dirs = {f.split("/")[1] for f in files
+                    if f.startswith("production_stack_tpu/") and f.count("/") > 1}
+    dead = []
+    for cited in sorted(_cited_paths((REPO / page).read_text())):
+        first, _, rest = cited.partition("/")
+        if not rest:
+            ok = cited in names or cited in _MADE_BY_COMMANDS
+        elif first in top_dirs:
+            ok = any(REPO.glob(cited)) if "*" in cited else cited in files
+        elif first in package_dirs:
+            ok = "production_stack_tpu/" + cited in files
+        else:
+            continue  # another project's path (the reference's, a cluster's)
+        if not ok:
+            dead.append(cited)
+    assert not dead, f"{page} cites files that are not in the tree: {dead}"
+
+
+def test_no_generated_number_blocks():
+    """The doc-number generator is gone (PR 34): a page that still carries
+    its marker would promise numbers nothing renders."""
+    marker = "BENCH_" + "NUMBERS_START"  # split: a grep for the name finds only the records
+    records = {"ISSUE.md", "CHANGES.md"}  # the order and the record may name it
+    holding = [
+        f for f in _tree_files() - records
+        if marker in (REPO / f).read_text(errors="replace")
+    ]
+    assert not holding, f"generated-number markers left in: {holding}"
+
+
+def test_graftcheck_default_roots_exist():
+    import sys
+
+    sys.path.insert(0, str(REPO / "scripts"))
+    try:
+        from graftcheck import core
+    finally:
+        sys.path.pop(0)
+    missing = [r for r in core.DEFAULT_ROOTS if not (REPO / r).exists()]
+    assert not missing, f"graftcheck scans paths that do not exist: {missing}"

@@ -239,7 +239,7 @@ HOT_SET_PAGES = 8 + 5 * USERS
 
 @pytest.fixture(scope="module")
 def overload_server():
-    """Real CPU engine + API server, in-process (bench.py hosting pattern),
+    """Real CPU engine + API server, in-process,
     with a page pool ~12% smaller than the workload's hot set and admission
     control on: 3 seats, 3 waiting, 1 s Retry-After. queue_deadline_s is set
     (generously) so the deferred-headers shed path is live on every

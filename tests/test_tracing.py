@@ -441,8 +441,8 @@ def test_collector_counts_ring_wrap_and_sampling_drops():
 
 def test_flightrecorder_hot_path_overhead_micro():
     """Satellite (ISSUE 7): the recorder rides the engine's dispatch path —
-    its per-event cost must stay micro-scale (the bench-level guarantee is
-    flightrecorder_overhead_ratio >= 0.98; this is the unit-scale tripwire).
+    its per-event cost must stay micro-scale (its cost in decode throughput
+    on the chip is not measured; this is the unit-scale tripwire).
     Bounds are deliberately loose for noisy CI hosts."""
     from production_stack_tpu.tracing import FlightRecorder
 
