@@ -915,6 +915,16 @@ class EngineServer:
         for phase in ("trace", "lower", "compile", "run"):
             emit(f"first_dispatch_{phase}_seconds_total", "counter",
                  s.get(f"first_dispatch_{phase}_seconds_total", 0.0))
+        # the store of exported step programs beside the compile cache
+        emit("step_program_store_hits_total", "counter",
+             s.get("step_program_store_hits_total", 0),
+             "first dispatches that found their exported step program")
+        emit("step_program_store_writes_total", "counter",
+             s.get("step_program_store_writes_total", 0),
+             "step programs exported and written beside the compile cache")
+        emit("step_program_store_errors_total", "counter",
+             s.get("step_program_store_errors_total", 0),
+             "blobs deleted and rebuilt + programs jax.export refused")
         for k in sorted(s):  # kv offload / transfer / spec / warm-start / loop
             if k.startswith(("kv_", "spec_decode_", "engine_loop_", "engine_dispatch_",
                              "warm_start_")):

@@ -29,6 +29,7 @@ from production_stack_tpu.engine.runner import (
     StepInput,
 )
 from production_stack_tpu.engine.lora import LoRAManager
+from production_stack_tpu.engine.step_programs import store_stats
 from production_stack_tpu.engine.scheduler import SamplingParams, ScheduledBatch, Scheduler, Sequence
 from production_stack_tpu.engine.tokenizer import load_tokenizer
 from production_stack_tpu.tracing import profiler
@@ -2484,6 +2485,10 @@ class LLMEngine:
         out["first_dispatch_seconds_total"] = round(fd["seconds"], 4)
         for phase in ("trace", "lower", "compile", "run"):
             out[f"first_dispatch_{phase}_seconds_total"] = round(fd[phase], 4)
+        # how often those first dispatches found their exported program
+        # beside the compile cache, wrote it, or could not use the store
+        # (step_program_store_bypassed: program -> why it runs its plain jit)
+        out.update(store_stats(self.runner.step_store))
         # per decode (batch x pages) bucket dispatched: the block of pages and
         # the ring depth the kernel's derivation chose (a dict, so /metrics,
         # which names its keys, leaves it to GET /stats)

@@ -24,6 +24,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from production_stack_tpu import models
 from production_stack_tpu.engine import devicemon
+from production_stack_tpu.engine.step_programs import (
+    StepProgramStore,
+    abstract_args,
+    program_key,
+)
 from production_stack_tpu.ops.attention import write_kv_pages_all_layers
 from production_stack_tpu.ops.sampling import (
     apply_logit_bias,
@@ -388,7 +393,13 @@ class ModelRunner:
         # donated layouts.
         self._rep = NamedSharding(self.mesh, P())
         self._steps: dict[bool, Any] = {}  # want_logprobs -> jitted step
-        self._ran: set = set()  # (family, sig, shapes) that dispatched once
+        # exported step programs beside the compile cache (None: no cache
+        # directory, so no store), how each jitted step was jitted (the
+        # store's wrapper repeats it), and the wrapper each (family, sig,
+        # shapes) runs through once it has dispatched (None: the plain jit)
+        self.step_store = StepProgramStore.beside_compile_cache()
+        self._jit_kw: dict[Any, dict] = {}
+        self._programs: dict[tuple, Any] = {}
         # what first dispatches cost, by phase (engine stats() exports it)
         self.first_dispatch = {
             "count": 0, "seconds": 0.0,
@@ -420,7 +431,7 @@ class ModelRunner:
             return self._stage_inputs(inp, with_limits)
 
     def _stage_inputs(self, inp: StepInput, with_limits: bool) -> dict:
-        self._rng, key = jax.random.split(self._rng)
+        self._rng, key = _next_key(self._rng)
         if self.mesh.devices.size == 1:
             # single chip: hand numpy straight to the jitted call — one
             # transfer batch instead of a device_put round trip per array.
@@ -474,13 +485,81 @@ class ModelRunner:
             )
         return staged
 
+    def _jit(self, program, donate: tuple, outs: tuple):
+        """``jax.jit`` a step program and remember how: what comes back from
+        the store is jitted the same way (_step_program)."""
+        kw = {"donate_argnums": donate, "out_shardings": outs}
+        fn = jax.jit(program, **kw)
+        self._jit_kw[fn] = kw
+        return fn
+
+    def _step_program(self, fn, family: str, sig, args: tuple, refused=None):
+        """What the first dispatch of a shape runs, and how the store took
+        part: ``jit(exported.call)`` over the module the store holds ("hit")
+        or holds from now on ("write"; "error" where the file that was there
+        had to be deleted: ``refused`` is what its call raised), the plain jit
+        where there is no store ("off") or ``jax.export`` refuses the program
+        ("error"; ``/stats`` names it). Cold or warm, XLA compiles the same
+        module, so the persistent compile cache's key is the same too."""
+        store, kw = self.step_store, self._jit_kw.get(fn)
+        if store is None or kw is None:
+            return fn, "off"
+        name = fn.__name__
+        try:
+            key = program_key(
+                {
+                    "program": name, "family": family, "sig": repr(sig),
+                    "module": self.module.__name__, "cfg": repr(self.cfg),
+                    "page_size": self.page_size,
+                    "pool_dtype": str(np.dtype(self.kv_pool_dtype)),
+                    "runner": [self._kv_burst_ok, self.kv_quant],
+                    "mesh": list(self.mesh.shape.items()),
+                    "processes": jax.process_count(),
+                    "args": abstract_args(args, self.mesh.devices.size),
+                    "donate": kw["donate_argnums"],
+                    "outs": [o and f"{tuple(o.mesh.shape.items())}{o.spec}"
+                             for o in kw["out_shardings"]],
+                },
+                self.mesh.devices.flat[0],
+            )
+            if refused is not None:
+                store.discard(key, f"{type(refused).__name__}: {refused}")
+            exported, status = store.exported(key, fn, args)
+        except Exception as e:  # noqa: BLE001 - whatever jax.export refuses
+            store.bypass(name, f"{type(e).__name__}: {e}")
+            return fn, "error"
+        program = jax.jit(_named_program(name, exported.call), **kw)
+        return program, "error" if refused is not None else status
+
+    def _first_call(self, fn, family: str, sig, args: tuple):
+        """(program, how the store took part, the batch's result) of a
+        shape's first dispatch."""
+        program, store = self._step_program(fn, family, sig, args)
+        try:
+            return program, store, jax.block_until_ready(program(*args))
+        except Exception as e:  # noqa: BLE001 - judged by `store`
+            if store != "hit":
+                raise
+            refused = e
+        # the blob deserialised, but its call does not trace or lower
+        # (nothing is donated before it does): once more from the step
+        # function itself
+        program, store = self._step_program(
+            fn, family, sig, args, refused=refused
+        )
+        return program, store, jax.block_until_ready(program(*args))
+
     def _dispatch(self, fn, family: str, sig, s: dict, args: tuple):
-        """Call a jitted step program. The first call of each (family, sig,
-        ids shape, pages shape) is timed to its result and split by phase; a
-        failure there is a ProgramBuildError, not a per-batch fault."""
-        key = (family, sig, s["input_ids"].shape, s["page_table"].shape)
-        if key in self._ran:
-            return fn(*args)
+        """Call a step program. The first call of each (family, sig, ids
+        shape, pages shape, structure and shapes of the other batch
+        arguments) resolves the program through the store, is timed to its
+        result and split by phase; a failure there is a ProgramBuildError, not
+        a per-batch fault."""
+        batch_args, tree = jax.tree.flatten(args[3:])
+        key = (family, sig, s["input_ids"].shape, s["page_table"].shape, tree,
+               tuple(x.shape for x in batch_args))
+        if key in self._programs:
+            return (self._programs[key] or fn)(*args)
         devicemon.install_compile_listener()
         ids_shape, pages_shape = list(key[2]), list(key[3])
         t0 = time.perf_counter()
@@ -489,14 +568,16 @@ class ModelRunner:
                 "pstpu.first_dispatch", family=family, sig=repr(sig),
                 ids=str(ids_shape), pages=str(pages_shape),
             ), devicemon.capture_first_dispatch() as phases:
-                out = jax.block_until_ready(fn(*args))
+                program, store, out = _roomy(
+                    self._first_call, fn, family, sig, args
+                )
         except Exception as e:
             raise ProgramBuildError(
                 f"{family}{sig} ids{key[2]} pages{key[3]}: "
                 f"{type(e).__name__}: {str(e)[:2000]}"
             ) from e
         wall = time.perf_counter() - t0
-        self._ran.add(key)
+        self._programs[key] = None if program is fn else program
         # what JAX reported on this thread for the call; the rest is the
         # first execution, the transfers and the executable's load
         split = {p: phases[p] for p in ("trace", "lower", "compile")}
@@ -522,6 +603,13 @@ class ModelRunner:
                 else "miss" if phases["cache_misses"]
                 else "uncached" if phases["compile"] else "none"
             ),
+            store=store,
+        )
+        logger.info(
+            "first dispatch %s%s ids%s pages%s: %.2f s (trace %.2f, lower %.2f, "
+            "compile or load %.2f, run %.2f), store %s", family, sig, ids_shape,
+            pages_shape, wall, *(split[p] for p in ("trace", "lower", "compile", "run")),
+            store,
         )
         return out
 
@@ -553,13 +641,12 @@ class ModelRunner:
             if self.kv_quant:
                 outs = outs + (n, n)  # updated scales pools
                 donate = (1, 2, 15)   # kv_scales tuple rides at arg 15
-            self._steps[sig] = jax.jit(
+            self._steps[sig] = self._jit(
                 _named_program(
                     "pstpu_step" + _flags(want_lp, want_pen),
                     _step_fn, self._forward, self.cfg, want_lp, want_pen,
                 ),
-                donate_argnums=donate,
-                out_shardings=outs,
+                donate, outs,
             )
         return self._steps[sig]
 
@@ -632,13 +719,12 @@ class ModelRunner:
                 # single burst commit is the quantizer
                 outs = outs + (n, n)
                 donate = (1, 2, 16)
-            self._multi_steps[sig] = jax.jit(
+            self._multi_steps[sig] = self._jit(
                 _named_program(
                     f"pstpu_multi_step_k{k}" + _flags(want_logprobs, want_pen),
                     fn, self._forward, self.cfg, k, want_logprobs, want_pen,
                 ),
-                donate_argnums=donate,
-                out_shardings=outs,
+                donate, outs,
             )
         args = (
             self.params, self.k_pages, self.v_pages,
@@ -774,13 +860,12 @@ class ModelRunner:
             )
         sig = (steps, spec_k, ngram)
         if sig not in self._spec_fns:
-            self._spec_fns[sig] = jax.jit(
+            self._spec_fns[sig] = self._jit(
                 _named_program(
                     f"pstpu_spec_s{steps}_k{spec_k}_n{ngram}",
                     _spec_fn, self._forward, self.cfg, steps, spec_k, ngram,
                 ),
-                donate_argnums=(1, 2),
-                out_shardings=(self._rep, None, None),
+                (1, 2), (self._rep, None, None),
             )
         s = self._stage(inp, with_limits=True)
         hist = jax.device_put(jnp.asarray(history, jnp.int32), self._row_sh) \
@@ -1347,6 +1432,34 @@ def _named_program(name: str, fn, *static):
     return program
 
 
+def _roomy(f, *args):
+    """``f(*args)``, called from a frame of 512 KiB. CPython (3.11 on) keeps
+    a thread's frames on a data stack of 16 KiB chunks and frees a chunk the
+    moment its first frame returns, so a loop whose calls straddle a chunk
+    boundary pays an mmap + munmap a call: 7.7 us against 0.05 us in the
+    sandbox, ~25 us on the chip's sandboxed host. JAX traces 150-250 frames
+    deep and, for most shapes, some boundary falls under one of its hot
+    leaves: tracing the prefill step cost the engine 5-9 s where an idle
+    process takes 1.0 s, every Python call of it 10-25 x slower (PERF.md
+    section 6, PR 35). A frame larger than a chunk gets a chunk of its own
+    (1 MiB here), and every frame called from it lives there."""
+    return f(*args)
+
+
+_roomy.__code__ = _roomy.__code__.replace(co_stacksize=1 << 16)
+
+
+@jax.jit
+def _next_key(rng):
+    """Split the runner's key: (the key it keeps, the step's key as RAW key
+    data). Every step program takes ``uint32[2]`` and wraps it inside: a typed
+    key under a ``NamedSharding`` does not cross ``jax.export``'s boundary
+    (jax 0.9.0: "'sdy.sharding_constraint' op sharding doesn't match tensor
+    rank: 0 != 1")."""
+    rng, key = jax.random.split(rng)
+    return rng, jax.random.key_data(key)
+
+
 def _flags(want_lp: bool, want_pen: bool) -> str:
     return ("_lp" if want_lp else "") + ("_pen" if want_pen else "")
 
@@ -1382,7 +1495,7 @@ def _multi_step_fn(forward, cfg, k, want_lp, want_pen, params, k_pages,
         v_blk = jnp.take(v_pages, flat, axis=1)
     local_pt = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
     kw = {} if lora is None else {"lora": lora, "lora_ids": lora_ids}
-    keys = jax.random.split(key, k)
+    keys = jax.random.split(jax.random.wrap_key_data(key), k)
     if want_pen:
         hist0, plens, pres, freq, rep = pen
         H = hist0.shape[1]
@@ -1481,7 +1594,7 @@ def _multi_step_deferred_fn(forward, cfg, k, want_lp, want_pen, params,
     kw = {} if lora is None else {"lora": lora, "lora_ids": lora_ids}
     if quant:
         kw["kv_scales"] = kv_scales
-    keys = jax.random.split(key, k)
+    keys = jax.random.split(jax.random.wrap_key_data(key), k)
     if want_pen:
         hist0, plens, pres, freq, rep = pen
         H = hist0.shape[1]
@@ -1622,7 +1735,7 @@ def _spec_fn(forward, cfg, steps, k, n, params, k_pages, v_pages, history,
         v_blk = jnp.take(v_pages, flat, axis=1)
     local_pt = jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
     kw = {} if lora is None else {"lora": lora, "lora_ids": lora_ids}
-    keys = jax.random.split(key, steps)
+    keys = jax.random.split(jax.random.wrap_key_data(key), steps)
     rows = jnp.arange(B, dtype=jnp.int32)[:, None]
     j = jnp.arange(T, dtype=jnp.int32)[None, :]
     rep = lambda x: jnp.repeat(x, T, axis=0)  # [B] -> [B*T] row params
@@ -1685,6 +1798,7 @@ def _step_fn(forward, cfg, want_lp, want_pen, params, k_pages, v_pages,
              top_p, key, lora=None, lora_ids=None, pen=None, bias=None,
              kv_scales=None):
     kw = {} if lora is None else {"lora": lora, "lora_ids": lora_ids}
+    key = jax.random.wrap_key_data(key)
     quant = kv_scales is not None
     if quant:
         kw["kv_scales"] = kv_scales

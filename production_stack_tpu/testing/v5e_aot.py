@@ -22,10 +22,12 @@ import argparse
 import dataclasses
 import functools
 import json
+import math
 import sys
 
 import jax
 import jax.numpy as jnp
+from jax import export as jax_export
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from production_stack_tpu.parallel import shardings
@@ -106,14 +108,11 @@ def compile_prefill_kernel(
     ).compile()
 
 
-def compile_step_program(
-    cfg, *, tp=1, B=4, T=512, max_pages=64, num_pages=512, page_size=64,
-    decode_steps=0,
-):
-    """AOT-compile the serving step ModelRunner would jit for ``cfg`` on a
-    v5e mesh of ``tp`` chips: a prefill/decode ``step`` (``decode_steps=0``)
-    or the ``decode_steps``-token deferred burst. ``cfg.attn_impl`` must
-    already be resolved (engine/runner.resolve_attn_impl)."""
+def _step_program(cfg, *, tp, B, T, max_pages, num_pages, page_size,
+                  decode_steps):
+    """(jitted step, how it was jitted, abstract arguments on a v5e mesh of
+    ``tp`` chips) as ModelRunner builds them: a prefill/decode ``step``
+    (``decode_steps=0``) or the ``decode_steps``-token deferred burst."""
     from production_stack_tpu import models
     from production_stack_tpu.engine import runner
 
@@ -139,23 +138,64 @@ def compile_step_program(
     )
     row = lambda n: on_mesh((B, n), jnp.int32)  # noqa: E731
     vec = lambda dt: on_mesh((B,), dt)  # noqa: E731
-    key = jax.eval_shape(lambda: jax.random.key(0))
-    key = jax.ShapeDtypeStruct(
-        key.shape, key.dtype, sharding=NamedSharding(mesh, P())
-    )
-    sampling = (vec(jnp.float32), vec(jnp.int32), vec(jnp.float32), key)
+    # the step's key crosses as raw key data (runner._next_key)
+    sampling = (vec(jnp.float32), vec(jnp.int32), vec(jnp.float32),
+                on_mesh((2,), jnp.uint32))
+    rep = NamedSharding(mesh, P())
     if decode_steps:
-        fn = functools.partial(
-            runner._multi_step_deferred_fn, forward, cfg, decode_steps,
-            False, False,
+        program = runner._named_program(
+            f"pstpu_multi_step_k{decode_steps}", runner._multi_step_deferred_fn,
+            forward, cfg, decode_steps, False, False,
         )
         args = (params, pool, pool, row(1), row(1), row(max_pages),
                 vec(jnp.int32), vec(jnp.int32), *sampling)
+        outs = (rep, rep, None, None)
     else:
-        fn = functools.partial(runner._step_fn, forward, cfg, False, False)
+        program = runner._named_program(
+            "pstpu_step", runner._step_fn, forward, cfg, False, False
+        )
         args = (params, pool, pool, row(T), row(T), row(max_pages),
                 vec(jnp.int32), *sampling)
-    return jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile()
+        outs = (rep, None, None, None)
+    kw = {"donate_argnums": (1, 2), "out_shardings": outs}
+    return jax.jit(program, **kw), kw, args
+
+
+def compile_step_program(
+    cfg, *, tp=1, B=4, T=512, max_pages=64, num_pages=512, page_size=64,
+    decode_steps=0, report=None,
+):
+    """AOT-compile the serving step ModelRunner would dispatch for ``cfg`` on
+    a v5e mesh of ``tp`` chips, the way ``runner._dispatch`` runs it: exported,
+    serialised, deserialised, and ``jit(exported.call)`` with the pools
+    donated. ``cfg.attn_impl`` must already be resolved
+    (engine/runner.resolve_attn_impl). A ``report`` dict is filled with what
+    tests/test_kernels_compile_v5e.py holds the store's path to: the blob's
+    size, the ``tpu_custom_call``s in the plain lowering and in the blob, and
+    the executable's aliased bytes against both pools' bytes a chip."""
+    from production_stack_tpu.engine import runner
+
+    jitted, kw, args = _step_program(
+        cfg, tp=tp, B=B, T=T, max_pages=max_pages, num_pages=num_pages,
+        page_size=page_size, decode_steps=decode_steps,
+    )
+    blob = jax_export.export(jitted, platforms=["tpu"])(*args).serialize()
+    exported = jax_export.deserialize(blob)
+    compiled = jax.jit(
+        runner._named_program(jitted.__name__, exported.call), **kw
+    ).lower(*args).compile()
+    if report is not None:
+        pool = args[1]
+        report.update(
+            blob_bytes=len(blob),
+            custom_calls_plain=jitted.lower(*args).as_text().count(
+                "tpu_custom_call"),
+            custom_calls_blob=exported.mlir_module().count("tpu_custom_call"),
+            alias_bytes=compiled.memory_analysis().alias_size_in_bytes,
+            pool_bytes=2 * pool.dtype.itemsize * math.prod(
+                pool.sharding.shard_shape(pool.shape)),
+        )
+    return compiled
 
 
 # -- the matrix tests/test_kernels_compile_v5e.py asserts on -------------------
@@ -194,6 +234,24 @@ CELL_BUCKETS = {
          [(32, 32)] + [(B, 64) for B in (32, 16, 8)]),
     )
     for B, pages in buckets
+}
+
+
+# whole step programs through the store's path (compile_step_program): one
+# decode bucket and the prefill step of both cells' widths at their pool
+# sizes, and the burst on a four-chip mesh: id -> preset + arguments
+STEP_PROGRAMS = {
+    "mistral-7b-d16.chat/burst-b64xp64": dict(
+        preset="mistral-7b", B=64, max_pages=64, num_pages=953, decode_steps=8),
+    "mistral-7b-d16.chat/prefill-b4xt512": dict(
+        preset="mistral-7b", B=4, T=512, max_pages=64, num_pages=953),
+    "qwen2.5-7b-d14.sessions/burst-b16xp64": dict(
+        preset="qwen2.5-7b", B=16, max_pages=64, num_pages=2285, decode_steps=8),
+    "qwen2.5-7b-d14.sessions/prefill-b4xt512": dict(
+        preset="qwen2.5-7b", B=4, T=512, max_pages=64, num_pages=2285),
+    "mistral-7b/tp4/burst-b8xp64": dict(
+        preset="mistral-7b", tp=4, B=8, max_pages=64, num_pages=128,
+        decode_steps=8),
 }
 
 
@@ -245,7 +303,7 @@ def run_matrix(slow: bool = False) -> dict:
     from production_stack_tpu.models import llama
 
     out: dict = {"decode": {}, "prefill": {}, "prefill_refused": {},
-                 "smem": {}, "tp4_step": {}}
+                 "smem": {}, "step_programs": {}}
     for cid, (NH, KH, D, int8) in preset_shapes().items():
         refusal = runner.kernel_refusal(
             head_dim=D, kv_heads_per_shard=KH, pool_itemsize=1 if int8 else 2
@@ -276,15 +334,24 @@ def run_matrix(slow: bool = False) -> dict:
                 compile_decode_kernel, B=rows, max_pages=2048, pool_pages=4096
             )
     else:
-        # the whole deferred-burst decode program at mistral-7b widths on a
-        # four-chip mesh (depth 2: the layer scan compiles one layer)
-        cfg = dataclasses.replace(
-            llama.PRESETS["mistral-7b"], num_layers=2, attn_impl="pallas"
-        )
-        out["tp4_step"] = _attempt(
-            compile_step_program, cfg=cfg, tp=4, B=8, max_pages=64,
-            num_pages=128, decode_steps=8,
-        )
+        for pid, kw in STEP_PROGRAMS.items():
+            kw = dict(kw)
+            tp = kw.get("tp", 1)
+            preset = llama.PRESETS[kw.pop("preset")]
+            # depth 2 (the layer scan compiles one layer), the attention
+            # path resolved as the engine resolves it on the chip
+            attn = runner.resolve_attn_impl(
+                "auto", platform="tpu", n_devices=tp, fwd_takes_mesh=True,
+                num_heads=preset.num_heads, num_kv_heads=preset.num_kv_heads,
+                head_dim=preset.head_dim, tp=tp, pool_itemsize=2,
+                max_batch=kw["B"], max_pages=kw["max_pages"],
+            )
+            cfg = dataclasses.replace(preset, num_layers=2, attn_impl=attn.impl)
+            report: dict = {}
+            out["step_programs"][pid] = dict(
+                _attempt(compile_step_program, cfg=cfg, report=report, **kw),
+                **report,
+            )
     return out
 
 

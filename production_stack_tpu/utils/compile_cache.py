@@ -35,6 +35,14 @@ _enabled_dir: str | None = None
 _base_dir: str | None = None
 
 
+def step_program_dir() -> str | None:
+    """Where the exported step programs live (engine/step_programs.py):
+    ``step_programs/`` inside the cache directory in effect, so whatever
+    carries the compile cache from one process to the next (a PVC, a fixed
+    path) carries them too. None where no cache directory was resolved."""
+    return os.path.join(_enabled_dir, "step_programs") if _enabled_dir else None
+
+
 def _cpu_feature_scope() -> str:
     """Subdirectory name isolating XLA:CPU AOT entries by writer configuration.
 

@@ -92,6 +92,9 @@ DASHBOARD_ALLOWLIST = {
     "vllm:first_dispatch_lower_seconds_total",
     "vllm:first_dispatch_compile_seconds_total",
     "vllm:first_dispatch_run_seconds_total",
+    "vllm:step_program_store_hits_total",    # how often a first dispatch found
+    "vllm:step_program_store_writes_total",  # its exported program: the same
+    "vllm:step_program_store_errors_total",  # start-up and bench surface
     "vllm:decode_dispatches_total",          # dispatch-shape bench telemetry
     "vllm:decode_chained_dispatches_total",
     "vllm:runahead_prefill_dispatches_total",

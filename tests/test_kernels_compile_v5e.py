@@ -106,7 +106,22 @@ def test_prefill_kernel_compiles_at_the_widest_shape(tier1):
 def test_tp4_decode_step_compiles_through_shard_map(tier1):
     """The kernel must sit in a region where EVERY mesh axis is manual, or
     XLA:TPU refuses: 'Mosaic kernels cannot be automatically partitioned'."""
-    assert tier1["tp4_step"]["compiled"], tier1["tp4_step"]["error"]
+    r = tier1["step_programs"]["mistral-7b/tp4/burst-b8xp64"]
+    assert r["compiled"], r["error"]
+
+
+@pytest.mark.parametrize("pid", list(v5e_aot.STEP_PROGRAMS))
+def test_step_program_compiles_through_the_store_with_its_pools_donated(tier1, pid):
+    """What runner._dispatch runs (export -> serialise -> deserialise ->
+    jit(exported.call)) compiles for the v5e, keeps every Mosaic kernel of the
+    plain lowering (the prefill step carries its kernel on one chip), and
+    still aliases both pools to its outputs: a step that copied them would
+    not fit the chip."""
+    r = tier1["step_programs"][pid]
+    assert r["compiled"], r["error"]
+    assert r["custom_calls_blob"] == r["custom_calls_plain"] >= 1
+    assert r["alias_bytes"] == r["pool_bytes"] > 0
+    assert r["blob_bytes"] < 256 << 10
 
 
 @pytest.mark.slow

@@ -68,11 +68,52 @@ def test_first_dispatch_is_counted_once_a_shape_and_split_by_phase():
         assert e["seconds"] == pytest.approx(
             e["trace_s"] + e["lower_s"] + e["compile_s"] + e["run_s"], abs=1e-3)
         assert e["cache"] in ("hit", "miss", "uncached")
+        # the store beside the test session's compile cache took part in each
+        assert e["store"] in ("hit", "write")
+    store = r.step_store
+    assert store.hits + store.writes == fd["count"] and store.errors == 0
+    assert [e["store"] for e in events].count("hit") == store.hits
     assert events[2]["sig"] == "(4, False, False)"
     # the marker this event replaces is gone
     assert not hasattr(r, "_note_program_variant")
     assert not [e for e in tracing.get_flightrecorder().events(kind="compile")
                 if e["data"].get("event") == "program_variant"]
+
+
+def test_a_first_dispatch_runs_from_a_frame_larger_than_a_data_stack_chunk():
+    """CPython frees a 16 KiB chunk of a thread's frame stack the moment it
+    empties; calls that straddle a boundary pay two system calls each. The
+    first dispatch (JAX's deep trace) runs below one frame that owns a chunk
+    of its own."""
+    import sys
+
+    from production_stack_tpu.engine.runner import _roomy
+
+    assert _roomy.__code__.co_stacksize * 8 >= 32 * 16 * 1024
+    assert _roomy(lambda a, b: (a, b), 1, 2) == (1, 2)
+    with pytest.raises(KeyError):
+        _roomy({}.__getitem__, "x")
+    # the frames called from it lie in its chunk: no depth costs a call more
+    # than a few times the median (a straddled boundary costs ~100 x)
+    def leaf(x):
+        return x
+
+    def hot():
+        t = time.perf_counter()
+        for _ in range(2000):
+            leaf(1)
+        return time.perf_counter() - t
+
+    def deep(n):
+        return hot() if n == 0 else deep(n - 1)
+
+    limit = sys.getrecursionlimit()
+    sys.setrecursionlimit(max(limit, 1000))
+    try:
+        costs = sorted(min(_roomy(deep, d) for _ in range(3)) for d in range(300))
+    finally:
+        sys.setrecursionlimit(limit)
+    assert costs[-1] < 20 * costs[len(costs) // 2]
 
 
 def test_a_trace_event_of_an_inner_jit_is_not_counted_twice():
@@ -246,6 +287,32 @@ def test_under_a_profile_the_trace_holds_the_loop_spans_and_the_program_names(en
     assert any("pstpu_step" in n or "pstpu_multi_step" in n for n in names)
     with open(path, "rb") as f:
         assert b"jit_pstpu_" in f.read()
+
+
+@pytest.mark.parametrize("what", ["hits", "writes", "errors"])
+def test_the_store_counters_stand_in_stats_and_in_metrics(engine, what):
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from production_stack_tpu.engine.api_server import EngineServer
+
+    _generate(engine, "a first dispatch or two", 4)
+    name = f"step_program_store_{what}_total"
+    stats = engine.stats()
+    store = engine.runner.step_store
+    assert stats[name] == getattr(store, what)
+    assert stats["step_program_store_dir"] == store.root
+    assert stats["step_program_store_bypassed"] == {}
+    assert (stats["step_program_store_hits_total"] + stats["step_program_store_writes_total"]
+            == stats["first_dispatches_total"] > 0)
+
+    async def scrape():
+        cfg = EngineConfig(model="mistral-debug")
+        async with TestClient(TestServer(EngineServer(cfg, engine).build_app())) as client:
+            return await (await client.get("/metrics")).text()
+
+    text = asyncio.run(scrape())
+    assert f"# TYPE vllm:{name} counter" in text
+    assert f'vllm:{name}{{model_name="mistral-debug"}} {stats[name]}' in text
 
 
 def test_profile_endpoints_ride_the_debug_gate(engine, tmp_path):
