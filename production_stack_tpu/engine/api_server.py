@@ -906,6 +906,20 @@ class EngineServer:
         emit("decode_kv_tokens_read_total", "counter",
              s.get("decode_kv_tokens_read_total", 0),
              "KV tokens the decoded tokens attended (min(context, window) each)")
+        if "ssm_state_slots" in s:
+            # a family with recurrent state beside its pages (models/jamba.py)
+            emit("ssm_state_slots", "gauge", s["ssm_state_slots"],
+                 "slots of the recurrent-state pool (one a running sequence)")
+            emit("ssm_state_slots_in_use", "gauge", s["ssm_state_slots_in_use"],
+                 "state slots held by admitted sequences")
+            emit("ssm_state_bytes", "gauge", s["ssm_state_bytes"],
+                 "bytes of the recurrent-state pool, the null slot included")
+            emit("ssm_prefill_tokens_total", "counter",
+                 s["ssm_prefill_tokens_total"],
+                 "prompt tokens the selective scan walked in prefill chunks")
+            emit("ssm_decode_tokens_total", "counter",
+                 s["ssm_decode_tokens_total"],
+                 "output tokens the selective scan stepped in decode bursts")
         emit("first_dispatches_total", "counter",
              s.get("first_dispatches_total", 0),
              "step-program shapes dispatched for the first time in this process")

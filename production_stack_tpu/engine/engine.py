@@ -123,6 +123,15 @@ class LLMEngine:
         model_mod, model_cfg, params = load_model(
             cfg.model, max_model_len=cfg.max_model_len
         )
+        # a family that keeps recurrent state beside its pages: what cannot
+        # serve it refuses to start, what is on by default is switched off,
+        # each with its reason (GET /stats repeats them)
+        self.state_family = hasattr(model_mod, "init_state")
+        self.state_family_refusals: dict[str, str] = {}
+        self.state_family_off: dict[str, str] = {}
+        if self.state_family:
+            cfg = self._restrict_to_state_family(cfg)
+            self.cfg = cfg
         if cfg.attn_impl != "auto":
             model_cfg = dataclasses.replace(model_cfg, attn_impl=cfg.attn_impl)
         if getattr(model_cfg, "kv_write_mode", "pre") != cfg.kv_write_mode:
@@ -206,8 +215,10 @@ class LLMEngine:
             1 if self.kv_quant
             else np.dtype(getattr(model_cfg, "dtype", None) or "bfloat16").itemsize
         )
+        # ``num_kv_layers``: the layers that hold pages (a family whose other
+        # layers keep recurrent state has fewer than ``num_layers``)
         page_bytes = (
-            2 * model_cfg.num_layers * cfg.page_size * model_cfg.num_kv_heads
+            2 * model_cfg.num_kv_layers * cfg.page_size * model_cfg.num_kv_heads
             * model_cfg.head_dim  # k+v
             * kv_itemsize
         )
@@ -215,7 +226,7 @@ class LLMEngine:
             # per-page scale rows ride the pool budget too (f32 per kv head,
             # k and v) — a rounding detail next to the 2x page shrink that
             # DOUBLES how many tokens the same kv_cache_memory_gb holds
-            page_bytes += 2 * model_cfg.num_layers * model_cfg.num_kv_heads * 4
+            page_bytes += 2 * model_cfg.num_kv_layers * model_cfg.num_kv_heads * 4
         # device telemetry (engine/devicemon.py): page footprint for the KV
         # pool-vs-headroom gauges, and the jax.monitoring compile listener
         # feeding vllm:compile_seconds_total + flight-recorder compile events
@@ -273,7 +284,7 @@ class LLMEngine:
             num_pages=num_pages, page_size=cfg.page_size, seed=cfg.seed,
             enable_lora=cfg.enable_lora, max_loras=cfg.max_loras,
             max_lora_rank=cfg.max_lora_rank, lora_targets=lora_targets,
-            max_batch=cfg.max_num_seqs,
+            max_batch=cfg.max_num_seqs, state_slots=cfg.max_num_seqs,
         )
         # KV quantization observability: bytes one token costs the pool
         # (the byte-wall number), and a startup quantize->dequantize
@@ -283,7 +294,7 @@ class LLMEngine:
         from production_stack_tpu.ops.quant import kv_bytes_per_token
 
         self.kv_bytes_per_token = kv_bytes_per_token(
-            model_cfg.num_layers, model_cfg.num_kv_heads, model_cfg.head_dim,
+            model_cfg.num_kv_layers, model_cfg.num_kv_heads, model_cfg.head_dim,
             cfg.page_size, self.kv_quant,
             np.dtype(getattr(model_cfg, "dtype", None) or "bfloat16").itemsize,
         )
@@ -296,7 +307,7 @@ class LLMEngine:
 
             rng_chk = np.random.RandomState(0)
             x = rng_chk.randn(
-                model_cfg.num_layers, cfg.page_size, model_cfg.num_kv_heads,
+                model_cfg.num_kv_layers, cfg.page_size, model_cfg.num_kv_heads,
                 model_cfg.head_dim,
             ).astype(np.float32)
             qx, sx = quantize_page_host(x)
@@ -353,6 +364,7 @@ class LLMEngine:
             num_pages, cfg.page_size, offload=self._offload,
             max_io_pages=self._max_io_pages,
             spill_watermark=cfg.kv_spill_watermark,
+            state_slots=self.runner.state_slots,
         )
         # warm-start manifests (kvoffload/warmstart.py): restore the previous
         # incarnation's hot working set into the pool BEFORE the API server
@@ -539,7 +551,7 @@ class LLMEngine:
                 ),
                 quant=self.kv_quant,
                 page_size=cfg.page_size,
-                nlayers=model_cfg.num_layers,
+                nlayers=model_cfg.num_kv_layers,
                 pages_fn=self._fabric_pages,
                 sink_fn=self._fabric_sink,
                 advertise_host=cfg.advertise_host or None,
@@ -618,6 +630,11 @@ class LLMEngine:
         # attend (min(context, sliding window) each — what a decode-attention
         # roofline prices)
         self.decode_kv_tokens_read_total = 0
+        # tokens the state-space layers' selective scan walked (a family with
+        # recurrent state): prompt tokens in prefill chunks, output tokens in
+        # decode bursts; each crosses every state-space layer once
+        self.ssm_prefill_tokens_total = 0
+        self.ssm_decode_tokens_total = 0
         # engine steps that raised (device thread is the only writer), and
         # the first step program that failed to BUILD (runner.
         # ProgramBuildError): every later batch of that shape fails the same
@@ -827,6 +844,73 @@ class LLMEngine:
                 "device kv transfer unavailable (%s); using TCP blobs", e
             )
             return None
+
+    def _restrict_to_state_family(self, cfg: EngineConfig) -> EngineConfig:
+        """A cache hit means "a run of pages"; for a family with recurrent
+        layers a run of pages without the state at its end is not a prefix of
+        the model, and nobody writes that state down at a page boundary yet.
+        So every plane that moves or reuses page runs refuses to start
+        (ValueError naming the option and the reason), and what is on by
+        default (prefix caching, migration) is switched off with its reason
+        logged. No stand-ins, no silent fallbacks."""
+        no_snapshot = (
+            "a run of pages without the recurrent state at its end is not a "
+            "prefix of this model, and no state snapshot is written at page "
+            "boundaries yet"
+        )
+        one_device = (
+            "the recurrent-state pool lives whole on one device: nothing "
+            "shards or replicates it yet"
+        )
+        refusals = {
+            "--kv-offload-cpu-gb / --kv-offload-dir / --kv-remote-url / "
+            "--kv-controller-url (KV offload)": (
+                cfg.kv_offload_cpu_gb > 0 or cfg.kv_offload_dir
+                or cfg.kv_remote_url or cfg.kv_controller_url, no_snapshot),
+            "--warm-start": (cfg.warm_start, no_snapshot),
+            "--kv-directory-url / --warm-prefetch-on-boot (KV directory)": (
+                cfg.kv_directory_url or cfg.warm_prefetch_on_boot > 0,
+                no_snapshot),
+            "--kv-fabric": (cfg.kv_fabric, no_snapshot),
+            "--kv-role / --kv-transfer-device (disaggregated prefill)": (
+                cfg.kv_role != "none" or cfg.kv_transfer_device, no_snapshot),
+            "--speculative-k": (
+                cfg.speculative_k > 0,
+                "a rejected draft token cannot be taken back out of the "
+                "recurrent state"),
+            "--enable-lora": (
+                cfg.enable_lora,
+                "adapters are defined for the llama family's projections only"),
+            "--kv-cache-dtype int8": (
+                cfg.kv_cache_dtype == "int8",
+                "the family's attention layers read fp pages (no quantised "
+                "read path)"),
+            "--tensor-parallel-size / --data-parallel-size / "
+            "--sequence-parallel-size / --expert-parallel-size / "
+            "--pipeline-parallel-size > 1, --distributed-num-processes > 1": (
+                max(cfg.tensor_parallel_size, cfg.data_parallel_size,
+                    cfg.sequence_parallel_size, cfg.expert_parallel_size,
+                    cfg.pipeline_parallel_size,
+                    cfg.distributed_num_processes) > 1, one_device),
+        }
+        self.state_family_refusals = {k: why for k, (_, why) in refusals.items()}
+        for option, (asked, why) in refusals.items():
+            if asked:
+                raise ValueError(
+                    f"model {cfg.model!r} keeps recurrent state beside its KV "
+                    f"pages and cannot start with {option}: {why}"
+                )
+        off = {}
+        if cfg.enable_prefix_caching:
+            off["prefix_caching"] = no_snapshot
+        if cfg.migration:
+            off["migration"] = no_snapshot
+        for what, why in off.items():
+            logger.warning("%s is OFF for model %r: %s", what, cfg.model, why)
+        self.state_family_off = off
+        return dataclasses.replace(
+            cfg, enable_prefix_caching=False, migration=False
+        )
 
     def _make_offload_connector(self, cfg: EngineConfig):
         """Build the LMCache-equivalent offload connector when any tier or the
@@ -1322,7 +1406,10 @@ class LLMEngine:
         decode's KV tokens read are also added to the total."""
         n = len(batch.seqs)
         if batch.kind == "prefill":
-            return {"prefill_tokens": int(sum(batch.chunk_sizes))}
+            tokens = int(sum(batch.chunk_sizes))
+            if self.state_family:
+                self.ssm_prefill_tokens_total += tokens
+            return {"prefill_tokens": tokens}
         steps = max(1, self.scheduler.decode_steps) * batch.bursts
         kv_len = batch.kv_lens[:n]
         if batch.kv_limits is not None:
@@ -1332,6 +1419,10 @@ class LLMEngine:
             kv_len, steps, getattr(self.model_cfg, "sliding_window", None)
         )
         self.decode_kv_tokens_read_total += read
+        if self.state_family:
+            self.ssm_decode_tokens_total += int(
+                np.sum(np.maximum(np.broadcast_to(steps, (n,)), 0))
+            )
         return {"kv_tokens_read": read}
 
     def _dispatch_batch(self, batch):
@@ -1346,6 +1437,7 @@ class LLMEngine:
             batch.input_ids, batch.positions, batch.page_table,
             batch.kv_lens, batch.temperature, batch.top_k, batch.top_p,
             lora_ids=batch.lora_ids, kv_limits=batch.kv_limits,
+            state_slots=batch.state_slots,
         )
         if batch.want_penalties:
             inp.history = batch.history
@@ -1512,7 +1604,7 @@ class LLMEngine:
                     self.runner.step_multi(inp, self.scheduler.decode_steps)
                 )  # [B, k]
         elif batch.kind == "prefill" and not any(
-            s.num_computed + c >= len(s.prompt_ids)
+            s.num_computed + c >= s.prefill_len
             for s, c in zip(batch.seqs, batch.chunk_sizes)
         ):
             # every chunk in this step is intermediate — nobody's
@@ -1614,10 +1706,10 @@ class LLMEngine:
             inp = StepInput(
                 ra.input_ids, ra.positions, ra.page_table, ra.kv_lens,
                 ra.temperature, ra.top_k, ra.top_p, lora_ids=ra.lora_ids,
-                kv_limits=ra.kv_limits,
+                kv_limits=ra.kv_limits, state_slots=ra.state_slots,
             )
             if not any(
-                s.num_computed + c >= len(s.prompt_ids)
+                s.num_computed + c >= s.prefill_len
                 for s, c in zip(ra.seqs, ra.chunk_sizes)
             ):
                 # all-intermediate chunks: skip-fetch (same optimization as
@@ -2477,6 +2569,20 @@ class LLMEngine:
             ),
             "decode_kv_tokens_read_total": self.decode_kv_tokens_read_total,
         }
+        if self.state_family:
+            # the second kind of state (models/jamba.py): slots of the
+            # recurrent-state pool, the scan's work, the implementation the
+            # platform resolved to, and what this family switches off or
+            # refuses to start with, each with its reason
+            out["ssm_state_slots"] = self.kv.state_slots
+            out["ssm_state_slots_in_use"] = self.kv.slots_in_use()
+            out["ssm_state_bytes"] = self.runner.state_pool_bytes()
+            out["ssm_prefill_tokens_total"] = self.ssm_prefill_tokens_total
+            out["ssm_decode_tokens_total"] = self.ssm_decode_tokens_total
+            out["ssm_kernel"] = self.runner.ssm_impl
+            out["ssm_kernel_reason"] = self.runner.ssm_reason
+            out["state_family_off"] = dict(self.state_family_off)
+            out["state_family_refusals"] = dict(self.state_family_refusals)
         # first dispatches of step-program shapes (runner._dispatch): how
         # many, their wall seconds, and the split trace / lower / compile
         # (or cache load) / run (first execution and the rest)

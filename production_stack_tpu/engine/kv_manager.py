@@ -84,9 +84,19 @@ class KVPageManager:
     def __init__(
         self, num_pages: int, page_size: int, offload=None,
         max_io_pages: int = 0, spill_watermark: float = 0.9,
+        state_slots: int = 0,
     ):
         self.num_pages = num_pages
         self.page_size = page_size
+        # the second kind of state this manager owns (a family with recurrent
+        # layers, models/jamba.py): one fixed-size slot a running sequence in
+        # the runner's state pool, whatever the sequence's length. Slot
+        # ``state_slots`` itself is the null slot padded rows write; it is
+        # never handed out. 0 = the family keeps pages only.
+        self.state_slots = state_slots
+        self.free_slots: list[int] = list(  # owned-by: device-thread
+            range(state_slots - 1, -1, -1)
+        )
         # per-operation offload I/O budget (pages); 0 = unbounded. See
         # EngineConfig.kv_offload_max_io_pages: on slow host<->device links
         # recompute beats restore past a few pages, and an uncapped spill
@@ -306,6 +316,21 @@ class KVPageManager:
             if w_all:
                 self.directory.withdraw(w_all, "all")
         return out
+
+    def allocate_slot(self) -> Optional[int]:
+        """A state slot for a sequence being admitted (None: all taken). Its
+        contents are whatever the last owner left: the step program starts a
+        sequence's first chunk from zero, so nothing is cleared here."""
+        return self.free_slots.pop() if self.free_slots else None
+
+    def free_slot(self, slot: int) -> None:
+        assert 0 <= slot < self.state_slots and slot not in self.free_slots, (
+            f"double free of state slot {slot}"
+        )
+        self.free_slots.append(slot)
+
+    def slots_in_use(self) -> int:
+        return self.state_slots - len(self.free_slots)
 
     def free(self, page_ids: Sequence[int]) -> None:
         for pid in page_ids:

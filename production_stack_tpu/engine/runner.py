@@ -209,6 +209,9 @@ class StepInput:
     # OpenAI logit_bias (set together when any row has one):
     bias_ids: Any = None     # [B, K] int32 token ids, >= vocab_size = unused
     bias_vals: Any = None    # [B, K] f32 additive biases
+    # [B] int32 slot of each row in the recurrent-state pool (a family with
+    # ``init_state``; the null slot for padded rows)
+    state_slots: Any = None
 
 
 class ModelRunner:
@@ -229,9 +232,13 @@ class ModelRunner:
         max_lora_rank: int = 16,
         lora_targets: tuple[str, ...] = ("wq", "wk", "wv", "wo"),
         max_batch: Optional[int] = None,
+        state_slots: Optional[int] = None,
     ):
         # ``max_batch``: the scheduler's largest decode batch, when the
-        # caller knows it — sizes the kernel's SMEM check (kernel_refusal)
+        # caller knows it — sizes the kernel's SMEM check (kernel_refusal).
+        # ``state_slots``: sequences that can run at once, for a family that
+        # keeps recurrent state beside the pages (``init_state``): the state
+        # pool holds one slot each and a null slot for padded rows
         self.module = module if module is not None else models.module_for_config(cfg)
         self.cfg = cfg
         self.page_size = page_size
@@ -327,6 +334,38 @@ class ModelRunner:
             and getattr(cfg, "kv_write_mode", "pre") == "post"
             and self._pp == 1
         )
+
+        # a family with recurrent state beside the pages (models/jamba.py):
+        # the pool rides every step program next to the page pools, donated
+        self.has_state = hasattr(self.module, "init_state")
+        self.state = None
+        self.state_slots = 0
+        self.ssm_impl, self.ssm_reason = "", ""
+        if self.has_state:
+            family = self.module.__name__.rsplit(".", 1)[-1]
+            # (what the family cannot serve with, parallel sizes and
+            # speculation included, is refused with its reason at start-up:
+            # engine._restrict_to_state_family)
+            if not self._kv_burst_ok:
+                raise ValueError(
+                    f"model family {family!r} advances its recurrent state "
+                    "inside the deferred decode burst: kv_write_mode='post'"
+                )
+            from production_stack_tpu.ops.pallas.ssm_scan import resolve_ssm_impl
+
+            self.ssm_impl, self.ssm_reason = (
+                resolve_ssm_impl(jax.default_backend())
+                if cfg.ssm_impl == "auto" else (cfg.ssm_impl, "requested")
+            )
+            cfg = dataclasses.replace(cfg, ssm_impl=self.ssm_impl)
+            self.cfg = cfg
+            self.state_slots = int(state_slots or max_batch or 8)
+            logger.info(
+                "selective scan: %s%s; %d state slots of %d bytes",
+                self.ssm_impl,
+                f" ({self.ssm_reason})" if self.ssm_reason else "",
+                self.state_slots, cfg.state_bytes_per_slot,
+            )
 
         if self.kv_quant:
             fwd_params = inspect.signature(self.module.forward).parameters
@@ -483,7 +522,23 @@ class ModelRunner:
                 row(inp.bias_ids, jnp.int32),
                 row(inp.bias_vals, jnp.float32),
             )
+        if self.has_state:
+            if inp.state_slots is None:
+                raise ValueError(
+                    "this model family keeps recurrent state: the batch needs "
+                    "state_slots (the scheduler fills them in)"
+                )
+            staged["state_slots"] = vec(inp.state_slots, jnp.int32)
         return staged
+
+    def _with_state(self, args: tuple, s: dict, scales_at: int) -> tuple:
+        """``args`` with the state pool and the rows' slots behind them (the
+        slot a quantised pool's scales would take stays empty)."""
+        if not self.has_state:
+            return args
+        return args + (None,) * (scales_at - len(args)) + (
+            self.state, s["state_slots"],
+        )
 
     def _jit(self, program, donate: tuple, outs: tuple):
         """``jax.jit`` a step program and remember how: what comes back from
@@ -641,6 +696,9 @@ class ModelRunner:
             if self.kv_quant:
                 outs = outs + (n, n)  # updated scales pools
                 donate = (1, 2, 15)   # kv_scales tuple rides at arg 15
+            if self.has_state:
+                outs = outs + (n,)    # the state pool, updated in place
+                donate = (1, 2, 16)   # it rides at arg 16, its slots at 17
             self._steps[sig] = self._jit(
                 _named_program(
                     "pstpu_step" + _flags(want_lp, want_pen),
@@ -664,10 +722,13 @@ class ModelRunner:
         )
         if self.kv_quant:
             args = args + ((self.k_scales, self.v_scales),)
+        args = self._with_state(args, s, 16)
         out = self._dispatch(
             self._get_step(want_logprobs, want_pen), "step",
             (want_logprobs, want_pen), s, args,
         )
+        if self.has_state:
+            *out, self.state = out
         if self.kv_quant:
             *out, self.k_scales, self.v_scales = out
         if want_logprobs:
@@ -719,6 +780,11 @@ class ModelRunner:
                 # single burst commit is the quantizer
                 outs = outs + (n, n)
                 donate = (1, 2, 16)
+            if self.has_state:
+                # the deferred burst (enforced at construction): the state
+                # pool is the burst scan's carry
+                outs = outs + (n,)
+                donate = (1, 2, 17)
             self._multi_steps[sig] = self._jit(
                 _named_program(
                     f"pstpu_multi_step_k{k}" + _flags(want_logprobs, want_pen),
@@ -734,7 +800,10 @@ class ModelRunner:
         )
         if self.kv_quant:
             args = args + ((self.k_scales, self.v_scales),)
+        args = self._with_state(args, s, 17)
         out = self._dispatch(self._multi_steps[sig], "multi_step", sig, s, args)
+        if self.has_state:
+            *out, self.state = out
         if self.kv_quant:
             *out, self.k_scales, self.v_scales = out
         if want_logprobs:
@@ -1315,7 +1384,7 @@ class ModelRunner:
         != 0) reports the full replicated footprint per device."""
         KH = getattr(self.cfg, "num_kv_heads", 1)
         shape = (
-            self.cfg.num_layers, self.num_pages, self.page_size,
+            self.cfg.num_kv_layers, self.num_pages, self.page_size,
             KH, self.cfg.head_dim,
         )
         sh = self._kv_sharding()
@@ -1324,7 +1393,7 @@ class ModelRunner:
         if self.kv_quant:
             ssh = self._kv_scales_sharding()
             per += 2 * 4 * int(
-                np.prod(ssh.shard_shape((self.cfg.num_layers, self.num_pages, KH)))
+                np.prod(ssh.shard_shape((self.cfg.num_kv_layers, self.num_pages, KH)))
             )
         return [
             (f"{d.platform}:{d.id}", per) for d in self.mesh.devices.flat
@@ -1350,12 +1419,20 @@ class ModelRunner:
         spec = self._kv_sharding().spec
         return NamedSharding(self.mesh, P(spec[0], spec[1], spec[3]))
 
+    def state_pool_bytes(self) -> int:
+        """Bytes of the recurrent-state pool, the null slot included (0: the
+        family keeps pages only)."""
+        if not self.has_state:
+            return 0
+        return (self.state_slots + 1) * self.cfg.state_bytes_per_slot
+
     def drop_kv_pools(self) -> None:
         """Release the KV pools' device memory (sleep level 1+)."""
         self.k_pages = None
         self.v_pages = None
         self.k_scales = None
         self.v_scales = None
+        self.state = None
 
     def offload_params(self) -> None:
         """Move params to host RAM (sleep level 2). Each process fetches its
@@ -1411,12 +1488,20 @@ class ModelRunner:
             self.module.init_kv_pages,
             (self.cfg, self.num_pages, self.page_size, dt), (kv_sh, kv_sh),
         )
+        if self.has_state:
+            self.state = jax.jit(
+                functools.partial(
+                    self.module.init_state, self.cfg, self.state_slots
+                ),
+                # whole on the one device the family serves on
+                out_shardings=NamedSharding(self.mesh, P()),
+            )()
         self.k_scales = self.v_scales = None
         if self.kv_quant:
             KH = getattr(self.cfg, "num_kv_heads", 1)
             sc_sh = self._kv_scales_sharding()
             self.k_scales, self.v_scales = shardings.build_sharded(
-                _scales_pools, (self.cfg.num_layers, self.num_pages, KH),
+                _scales_pools, (self.cfg.num_kv_layers, self.num_pages, KH),
                 (sc_sh, sc_sh),
             )
 
@@ -1569,7 +1654,8 @@ def _multi_step_deferred_fn(forward, cfg, k, want_lp, want_pen, params,
                             k_pages, v_pages, input_ids, positions,
                             page_table, kv_lens, kv_limits, temperature,
                             top_k, top_p, key, lora=None, lora_ids=None,
-                            pen=None, bias=None, kv_scales=None):
+                            pen=None, bias=None, kv_scales=None, state=None,
+                            state_slots=None):
     """k fused decode steps with DEFERRED KV scatters (kv_burst mode).
 
     The classic _multi_step_fn gathers the batch's pages into a local block
@@ -1579,7 +1665,11 @@ def _multi_step_deferred_fn(forward, cfg, k, want_lp, want_pen, params,
     Here the pools are scan CONSTANTS (read-only), each step appends its
     K/V to a tiny [L, B, k, KH, D] window that attention folds in via the
     kernel's masked multi-token k_cur, and ONE batched scatter commits the
-    whole burst afterwards."""
+    whole burst afterwards.
+
+    A family with recurrent state (``state``: its pool, ``state_slots``: the
+    rows' slots) advances it inside the burst: the pool is a carry of the
+    scan, updated in place by every step, and comes back as the last output."""
     B = input_ids.shape[0]
     L, _, page_size, KH, D = k_pages.shape
     C = k
@@ -1603,11 +1693,19 @@ def _multi_step_deferred_fn(forward, cfg, k, want_lp, want_pen, params,
         hist0 = jnp.zeros((B, 1), jnp.int32)  # inert carry
 
     def body(carry, key_i):
-        ids, pos, lens, counts, ka, va, hist = carry
-        logits, ka_new, va_new = forward(
-            params, cfg, ids, pos, k_pages, v_pages, page_table, lens,
-            kv_burst=(ka, va, counts), **kw
-        )
+        ids, pos, lens, counts, ka, va, hist, st = carry
+        if st is None:
+            logits, ka_new, va_new = forward(
+                params, cfg, ids, pos, k_pages, v_pages, page_table, lens,
+                kv_burst=(ka, va, counts), **kw
+            )
+        else:
+            # rows that ran out of budget (pos -1) leave their state as it is
+            logits, ka_new, va_new, st = forward(
+                params, cfg, ids, pos, k_pages, v_pages, page_table, lens,
+                kv_burst=(ka, va, counts), state=st, state_slots=state_slots,
+                **kw
+            )
         with jax.named_scope("sample"):
             sample_from = logits
             if want_pen:
@@ -1641,12 +1739,14 @@ def _multi_step_deferred_fn(forward, cfg, k, want_lp, want_pen, params,
         pos = jnp.where(active, pos[:, 0] + 1, -1)[:, None]
         lens = lens + active.astype(lens.dtype)
         ids = jnp.where(active, nxt, 0)[:, None]
-        return (ids, pos, lens, counts, ka, va, hist), emit
+        return (ids, pos, lens, counts, ka, va, hist, st), emit
 
-    (_, _, _, counts_f, k_acc, v_acc, hist_f), emitted = jax.lax.scan(
-        body, (input_ids, positions, kv_lens, counts, k_acc, v_acc, hist0),
+    (_, _, _, counts_f, k_acc, v_acc, hist_f, state), emitted = jax.lax.scan(
+        body,
+        (input_ids, positions, kv_lens, counts, k_acc, v_acc, hist0, state),
         keys,
     )
+    tail = () if state is None else (state,)
     toks = emitted[0] if want_lp else emitted
     # one commit for the whole burst: window entry j of row b holds the
     # token at absolute position pos0 + j (valid for j < counts_f)
@@ -1682,8 +1782,8 @@ def _multi_step_deferred_fn(forward, cfg, k, want_lp, want_pen, params,
     if want_lp:
         _, lp, tids, tlp = emitted
         return (toks.T, lp.T, jnp.swapaxes(tids, 0, 1),
-                jnp.swapaxes(tlp, 0, 1), hist_f, k_pages, v_pages)
-    return toks.T, hist_f, k_pages, v_pages  # [B, k]
+                jnp.swapaxes(tlp, 0, 1), hist_f, k_pages, v_pages, *tail)
+    return (toks.T, hist_f, k_pages, v_pages, *tail)  # [B, k]
 
 
 def _ngram_draft(buf, pos, n, k):
@@ -1796,16 +1896,24 @@ def _spec_fn(forward, cfg, steps, k, n, params, k_pages, v_pages, history,
 def _step_fn(forward, cfg, want_lp, want_pen, params, k_pages, v_pages,
              input_ids, positions, page_table, kv_lens, temperature, top_k,
              top_p, key, lora=None, lora_ids=None, pen=None, bias=None,
-             kv_scales=None):
+             kv_scales=None, state=None, state_slots=None):
     kw = {} if lora is None else {"lora": lora, "lora_ids": lora_ids}
     key = jax.random.wrap_key_data(key)
     quant = kv_scales is not None
+    tail = ()  # what follows the page pools in the result
     if quant:
         kw["kv_scales"] = kv_scales
         logits, k_pages, v_pages, k_sc, v_sc = forward(
             params, cfg, input_ids, positions, k_pages, v_pages, page_table,
             kv_lens, **kw,
         )
+        tail = (k_sc, v_sc)
+    elif state is not None:
+        logits, k_pages, v_pages, state = forward(
+            params, cfg, input_ids, positions, k_pages, v_pages, page_table,
+            kv_lens, state=state, state_slots=state_slots, **kw,
+        )
+        tail = (state,)
     else:
         logits, k_pages, v_pages = forward(
             params, cfg, input_ids, positions, k_pages, v_pages, page_table,
@@ -1827,10 +1935,6 @@ def _step_fn(forward, cfg, want_lp, want_pen, params, k_pages, v_pages,
             ids, lp, tids, tlp = sample_with_logprobs(
                 logits, key, temperature, top_k, top_p, sample_from=sample_from
             )
-            if quant:
-                return ids, logits, lp, tids, tlp, k_pages, v_pages, k_sc, v_sc
-            return ids, logits, lp, tids, tlp, k_pages, v_pages
+            return (ids, logits, lp, tids, tlp, k_pages, v_pages, *tail)
         ids = sample(sample_from, key, temperature, top_k, top_p)
-        if quant:
-            return ids, logits, k_pages, v_pages, k_sc, v_sc
-        return ids, logits, k_pages, v_pages
+        return (ids, logits, k_pages, v_pages, *tail)

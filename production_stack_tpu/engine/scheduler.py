@@ -71,6 +71,15 @@ class Sequence:
     arrival_time: float = field(default_factory=time.monotonic)
     output_ids: list[int] = field(default_factory=list)
     pages: list[int] = field(default_factory=list)
+    # the sequence's slot in the runner's recurrent-state pool (families with
+    # state-space layers; None = none held), owned like ``pages``: taken at
+    # admission, released at finish / abort / preemption
+    state_slot: Optional[int] = None
+    # tokens a re-admitted sequence has to compute again before it decodes:
+    # its prompt AND the output it had produced when it was preempted (all
+    # but the last token, which is the next decode step's input). 0 = never
+    # preempted: the prompt alone.
+    recompute_len: int = 0
     # high-watermark of pages ever owned (SLO terminal records report it —
     # the request's real KV footprint, which free() at finish erases)
     pages_peak: int = 0
@@ -123,8 +132,12 @@ class Sequence:
         return len(self.prompt_ids) + len(self.output_ids)
 
     @property
+    def prefill_len(self) -> int:
+        return max(len(self.prompt_ids), self.recompute_len)
+
+    @property
     def in_prefill(self) -> bool:
-        return self.num_computed < len(self.prompt_ids)
+        return self.num_computed < self.prefill_len
 
 
 @dataclass
@@ -152,6 +165,9 @@ class ScheduledBatch:
     # are set when true
     want_penalties: bool = False
     prompt_lens: np.ndarray = None  # [B] int32 (penalty batches)
+    # [B] int32 slot of each row in the recurrent-state pool, the null slot
+    # for padded rows (None: the family keeps pages only)
+    state_slots: np.ndarray = None
 
 
 def _bucket(n: int, buckets: tuple[int, ...]) -> int:
@@ -409,7 +425,7 @@ class Scheduler:
                 else:
                     shared, cached = [], 0
                 need = self._pages_needed(
-                    min(len(seq.prompt_ids) + 16, self.max_model_len + 1)
+                    min(seq.num_tokens + 16, self.max_model_len + 1)
                 ) - len(shared)
                 fresh = self.kv.allocate(max(need, 0))
             finally:
@@ -417,6 +433,11 @@ class Scheduler:
             if fresh is None:
                 self.kv.free(shared)
                 return
+            if self.kv.state_slots:
+                seq.state_slot = self.kv.allocate_slot()
+                if seq.state_slot is None:  # every slot held (frozen rows)
+                    self.kv.free(shared + fresh)
+                    return
             seq.pages = shared + fresh
             seq.pages_peak = max(seq.pages_peak, len(seq.pages))
             seq.num_cached = cached
@@ -472,12 +493,20 @@ class Scheduler:
             self.kv.register_filled(
                 seq.prompt_ids + seq.output_ids, seq.pages, seq.cache_salt
             )
-        self.kv.free(seq.pages)
-        seq.pages = []
+        self._release(seq)
         if seq in self.running:
             self.running.remove(seq)
         if seq in self.waiting:
             self.waiting.remove(seq)
+
+    def _release(self, seq: Sequence) -> None:
+        """Give back everything the manager lent ``seq``: its pages and, where
+        the family keeps recurrent state, its slot."""
+        self.kv.free(seq.pages)
+        seq.pages = []
+        if seq.state_slot is not None:
+            self.kv.free_slot(seq.state_slot)
+            seq.state_slot = None
 
     # -- step planning ------------------------------------------------------
 
@@ -505,7 +534,7 @@ class Scheduler:
         # round trip the interleaved burst costs. Small decode batches with
         # a short backlog keep the fast strict-priority path: the flurry
         # clears in a dispatch or two.
-        backlog = sum(len(s.prompt_ids) - s.num_computed for s in prefilling)
+        backlog = sum(s.prefill_len - s.num_computed for s in prefilling)
         demand = len(decoding)
         alternate = (
             demand > 0
@@ -693,7 +722,7 @@ class Scheduler:
         prefilling.sort(
             key=lambda s: (
                 getattr(s, "priority", "interactive") == "batch",
-                len(s.prompt_ids) - s.num_computed,
+                s.prefill_len - s.num_computed,
             )
         )
         take = prefilling[: self.prefill_batch]
@@ -721,7 +750,7 @@ class Scheduler:
 
     def _plan_prefill(self, seqs: list[Sequence]) -> ScheduledBatch:
         chunks = [
-            min(len(s.prompt_ids) - s.num_computed, self.prefill_chunk) for s in seqs
+            min(s.prefill_len - s.num_computed, self.prefill_chunk) for s in seqs
         ]
         T = _bucket(max(chunks), self.CHUNK_BUCKETS)
         B = self._batch_bucket(len(seqs))
@@ -750,7 +779,8 @@ class Scheduler:
                 want_pen = False  # context beyond the top bucket: skip penalties
         for i, (s, c) in enumerate(zip(seqs, chunks)):
             lo = s.num_computed
-            input_ids[i, :c] = s.prompt_ids[lo : lo + c]
+            ids = s.prompt_ids + s.output_ids if s.recompute_len else s.prompt_ids
+            input_ids[i, :c] = ids[lo : lo + c]
             positions[i, :c] = np.arange(lo, lo + c)
             pages = s.pages[:max_pages]
             page_table[i, : len(pages)] = pages
@@ -768,7 +798,17 @@ class Scheduler:
             temperature, top_k, top_p, lora_ids=lora_ids, chunk_sizes=chunks,
             want_logprobs=any(s.params.logprobs is not None for s in seqs),
             want_penalties=want_pen, history=history, prompt_lens=prompt_lens,
+            state_slots=self._state_slots(seqs, B),
         )
+
+    def _state_slots(self, seqs: list[Sequence], B: int) -> Optional[np.ndarray]:
+        """[B] slot of each row in the recurrent-state pool; padded rows get
+        the null slot. None where the family keeps pages only."""
+        if not self.kv.state_slots:
+            return None
+        slots = np.full((B,), self.kv.state_slots, np.int32)
+        slots[: len(seqs)] = [s.state_slot for s in seqs]
+        return slots
 
     def _plan_decode(
         self, seqs: list[Sequence], bursts: int = 1
@@ -890,14 +930,17 @@ class Scheduler:
             history=history, bursts=bursts,
             want_logprobs=any(s.params.logprobs is not None for s in ready),
             want_penalties=want_pen, prompt_lens=prompt_lens,
+            state_slots=self._state_slots(ready, B),
         )
 
     def _preempt(self, seq: Sequence) -> None:
-        """Return a running sequence to the waiting queue, dropping its KV."""
-        self.kv.free(seq.pages)
-        seq.pages = []
+        """Return a running sequence to the waiting queue, dropping its KV
+        (and its recurrent state): re-admitted, it computes its prompt and the
+        output it had produced again, then goes on decoding."""
+        self._release(seq)
         seq.num_computed = 0
         seq.num_cached = 0
+        seq.recompute_len = seq.num_tokens - 1 if seq.output_ids else 0
         seq.preempted = True  # vllm:num_requests_swapped until re-admitted
         self.preemptions_total += 1
         if seq in self.running:
@@ -960,6 +1003,12 @@ class Scheduler:
                     self.kv.register_filled(
                         s.prompt_ids, s.pages, s.cache_salt
                     )
+                if s.recompute_len:
+                    # recomputed after a preemption: the token this step
+                    # sampled follows output the client already has; the
+                    # next decode step feeds the last of it back in
+                    s.recompute_len = 0
+                    continue
                 if s.first_token_time is None:
                     s.first_token_time = time.monotonic()
                 consume(s, int(tokens[i, 0]), i, 0)

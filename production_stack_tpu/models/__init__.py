@@ -9,6 +9,11 @@ engine/runner.py and engine/model_loader.py:
 - ``init_params(cfg, key)`` / ``init_kv_pages(cfg, num_pages, page_size)``
 - ``forward(params, cfg, input_ids, positions, k_pages, v_pages, page_table,
   kv_lens) -> (logits, k_pages, v_pages)``
+- ``Config.num_kv_layers``: the layers that hold pages (``num_layers`` counts
+  the model's; the two differ where not every layer attends)
+- optionally ``init_state(cfg, slots)``: a family that keeps recurrent state
+  beside the pages (models/jamba.py); its ``forward`` takes ``state=`` and
+  ``state_slots=`` and returns the updated state as a fourth value
 
 Sharding specs are name-based (parallel/shardings.py) so new families only
 need to reuse the leaf-name vocabulary or extend the spec tables.
@@ -16,10 +21,10 @@ need to reuse the leaf-name vocabulary or extend the spec tables.
 
 from __future__ import annotations
 
-from production_stack_tpu.models import gemma2, llama, opt
+from production_stack_tpu.models import gemma2, jamba, llama, opt
 
 #: module search order for preset names and HF architectures
-MODULES = (llama, opt, gemma2)
+MODULES = (llama, opt, gemma2, jamba)
 
 _ARCH_TO_MODULE = {
     "LlamaForCausalLM": llama,
@@ -28,6 +33,7 @@ _ARCH_TO_MODULE = {
     "MixtralForCausalLM": llama,
     "OPTForCausalLM": opt,
     "Gemma2ForCausalLM": gemma2,
+    "JambaForCausalLM": jamba,
 }
 
 
@@ -49,6 +55,8 @@ def module_for_config(cfg):
         return opt
     if isinstance(cfg, gemma2.Gemma2Config):
         return gemma2
+    if isinstance(cfg, jamba.JambaConfig):
+        return jamba
     raise ValueError(f"unknown model config type {type(cfg).__name__}")
 
 

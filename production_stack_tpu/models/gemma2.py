@@ -72,6 +72,10 @@ class Gemma2Config:
     def tie_word_embeddings(self) -> bool:
         return True  # Gemma always ties the LM head to the embedding
 
+    @property
+    def num_kv_layers(self) -> int:
+        return self.num_layers
+
     @staticmethod
     def from_hf_config(cfg: dict) -> "Gemma2Config":
         hidden = cfg["hidden_size"]

@@ -102,6 +102,11 @@ class LlamaConfig:
     # ModelRunner builds the scales pools and threads them as ``kv_scales``.
     kv_cache_dtype: str = "auto"
 
+    @property
+    def num_kv_layers(self) -> int:
+        """Layers that hold pages: every layer attends."""
+        return self.num_layers
+
     @staticmethod
     def from_hf_config(cfg: dict) -> "LlamaConfig":
         """Build from a HuggingFace `config.json` dict. Handles

@@ -64,6 +64,10 @@ class OPTConfig:
         return self.hidden_size // self.num_heads
 
     @property
+    def num_kv_layers(self) -> int:
+        return self.num_layers
+
+    @property
     def tie_word_embeddings(self) -> bool:
         return True
 

@@ -143,6 +143,26 @@ def write_kv_pages_all_layers(
     L, P, page_size, KH, D = k_pages.shape
     B, T = positions.shape
     flat = _kv_flat_indices(page_table, positions, page_size, P)
+    if KH == 1:
+        # one kv head: scatter [KH, D] ROWS into the pools seen as [L * P *
+        # page, KH, D] (a bitcast of the layout the pools arrive in). The
+        # [L, KH, D] windows below make XLA:TPU prefer the layer axis beside
+        # the head dimension where KH is 1 and L small, and it then relays
+        # BOTH POOLS out and back at every dispatch (the v5e compile of a
+        # 2-layer, 1-kv-head pool showed two pool-sized copies in, two out)
+        slots = P * page_size
+        rows = jnp.where(
+            flat[None, :] < slots,
+            flat[None, :] + (jnp.arange(L, dtype=flat.dtype) * slots)[:, None],
+            L * slots,
+        ).reshape(-1)
+
+        def put(pool, new):
+            return pool.reshape(L * slots, KH, D).at[rows].set(
+                new.reshape(L * B * T, KH, D), mode="drop"
+            ).reshape(pool.shape)
+
+        return put(k_pages, k_new), put(v_pages, v_new)
     k_flat = k_pages.reshape(L, P * page_size, KH, D)
     v_flat = v_pages.reshape(L, P * page_size, KH, D)
     k_flat = k_flat.at[:, flat].set(k_new.reshape(L, B * T, KH, D), mode="drop")

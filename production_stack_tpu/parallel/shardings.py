@@ -60,6 +60,21 @@ _LAYER_SPECS = {
     "moe_gate": P("ep", None, "tp"),
     "moe_up": P("ep", None, "tp"),
     "moe_down": P("ep", "tp", None),
+    # state-space mixer (models/jamba.py): replicated, the family serves on
+    # one chip (tp > 1 is refused at start-up)
+    "mixer_norm": P(None),
+    "in_proj": P(None, None),
+    "conv_w": P(None, None),
+    "conv_b": P(None),
+    "x_proj": P(None, None),
+    "dt_norm": P(None),
+    "b_norm": P(None),
+    "c_norm": P(None),
+    "dt_proj": P(None, None),
+    "dt_bias": P(None),
+    "a_log": P(None, None),
+    "d_skip": P(None),
+    "out_proj": P(None, None),
 }
 
 # [L, P, page_size, KH, D] pools: shard kv heads over tp.
@@ -87,7 +102,7 @@ def param_specs_for(params: dict, pp: bool = False) -> dict:
     layer_lead = "pp" if pp else None
     specs: dict = {}
     for k, v in params.items():
-        if k == "layers":
+        if isinstance(v, dict):  # a layer-stacked group ("layers", "attn_layers")
             specs[k] = {n: P(layer_lead, *_LAYER_SPECS[n]) for n in v}
         else:
             specs[k] = _TOP_SPECS[k]

@@ -108,8 +108,38 @@ def compile_prefill_kernel(
     ).compile()
 
 
+def compile_ssm_scan(*, B=64, T=1, Di=5120, N=16, layers=26, slots=64):
+    """AOT-compile the selective-scan kernel (ops/pallas/ssm_scan.py) for one
+    v5e chip: ``T = 1`` is ``ssm_step_decode``, ``T > 1`` ``ssm_scan_prefill``;
+    the state pool is donated. Returns (compiled, the kernel's custom-call
+    name as the trace will show it)."""
+    from production_stack_tpu.ops.pallas import ssm_scan
+
+    def f(u, delta, z, b_mat, c_mat, a, d, pool, slot, first, lens, layer):
+        return ssm_scan.selective_scan(
+            u, delta, z, b_mat, c_mat, a, d, pool, slot, first, lens, layer[0],
+            impl="pallas",
+        )
+
+    f32 = jnp.float32
+    seq, bc = _on_chip((B, T, Di), f32), _on_chip((B, T, N), f32)
+    compiled = jax.jit(f, donate_argnums=(7,)).lower(
+        seq, seq, seq, bc, bc, _on_chip((N, Di), f32), _on_chip((Di,), f32),
+        _on_chip(ssm_scan.state_pool_shape(layers, slots, N, Di), f32),
+        _on_chip((B,), jnp.int32), _on_chip((B,), jnp.bool_),
+        _on_chip((B,), jnp.int32), _on_chip((1,), jnp.int32),
+    ).compile()
+    name = "ssm_step_decode" if T == 1 else "ssm_scan_prefill"
+    if f"%{name}" not in compiled.as_text():
+        raise AssertionError(f"no custom call named {name} in the compiled program")
+    pool_bytes = 4 * math.prod(ssm_scan.state_pool_shape(layers, slots, N, Di))
+    if compiled.memory_analysis().alias_size_in_bytes != pool_bytes:
+        raise AssertionError("the state pool is not updated in place")
+    return compiled
+
+
 def _step_program(cfg, *, tp, B, T, max_pages, num_pages, page_size,
-                  decode_steps):
+                  decode_steps, state_slots=64):
     """(jitted step, how it was jitted, abstract arguments on a v5e mesh of
     ``tp`` chips) as ModelRunner builds them: a prefill/decode ``step``
     (``decode_steps=0``) or the ``decode_steps``-token deferred burst."""
@@ -130,12 +160,19 @@ def _step_program(cfg, *, tp, B, T, max_pages, num_pages, page_size,
         shapes, shardings.param_specs_for(shapes),
     )
     pool = on_mesh(
-        (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim),
+        (cfg.num_kv_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim),
         cfg.dtype, shardings.KV_PAGES_SPEC,
     )
     forward = (
         functools.partial(module.forward, mesh=mesh) if tp > 1 else module.forward
     )
+    # a family with recurrent state: the pool and the rows' slots ride behind
+    # the (empty) slot of a quantised pool's scales, the pool donated
+    state = ()
+    if hasattr(module, "init_state"):
+        pools = jax.eval_shape(lambda: module.init_state(cfg, state_slots))
+        state = (jax.tree.map(lambda s: on_mesh(s.shape, s.dtype), pools),
+                 on_mesh((B,), jnp.int32))
     row = lambda n: on_mesh((B, n), jnp.int32)  # noqa: E731
     vec = lambda dt: on_mesh((B,), dt)  # noqa: E731
     # the step's key crosses as raw key data (runner._next_key)
@@ -149,15 +186,19 @@ def _step_program(cfg, *, tp, B, T, max_pages, num_pages, page_size,
         )
         args = (params, pool, pool, row(1), row(1), row(max_pages),
                 vec(jnp.int32), vec(jnp.int32), *sampling)
-        outs = (rep, rep, None, None)
+        outs, scales_at = (rep, rep, None, None), 17
     else:
         program = runner._named_program(
             "pstpu_step", runner._step_fn, forward, cfg, False, False
         )
         args = (params, pool, pool, row(T), row(T), row(max_pages),
                 vec(jnp.int32), *sampling)
-        outs = (rep, None, None, None)
-    kw = {"donate_argnums": (1, 2), "out_shardings": outs}
+        outs, scales_at = (rep, None, None, None), 16
+    donate = (1, 2)
+    if state:
+        args = args + (None,) * (scales_at - len(args)) + state
+        outs, donate = outs + (None,), (1, 2, scales_at)
+    kw = {"donate_argnums": donate, "out_shardings": outs}
     return jax.jit(program, **kw), kw, args
 
 
@@ -194,6 +235,11 @@ def compile_step_program(
             alias_bytes=compiled.memory_analysis().alias_size_in_bytes,
             pool_bytes=2 * pool.dtype.itemsize * math.prod(
                 pool.sharding.shard_shape(pool.shape)),
+            # a family with recurrent state donates that pool too
+            state_bytes=sum(
+                x.dtype.itemsize * math.prod(x.shape)
+                for i in kw["donate_argnums"][2:] for x in jax.tree.leaves(args[i])
+            ),
         )
     return compiled
 
@@ -252,6 +298,19 @@ STEP_PROGRAMS = {
     "mistral-7b/tp4/burst-b8xp64": dict(
         preset="mistral-7b", tp=4, B=8, max_pages=64, num_pages=128,
         decode_steps=8),
+    # the state family: pools of the attention layers alone + the state pool
+    "jamba2-3b.chat/burst-b64xp64": dict(
+        preset="jamba2-3b", B=64, max_pages=64, num_pages=4096, decode_steps=8),
+    "jamba2-3b.chat/prefill-b4xt512": dict(
+        preset="jamba2-3b", B=4, T=512, max_pages=64, num_pages=4096),
+}
+
+# the selective scan at the shapes the jamba2-3b.chat cell dispatches
+SSM_SCANS = {
+    "decode-b64": dict(B=64, T=1),
+    "decode-b8": dict(B=8, T=1),
+    "prefill-b4xt512": dict(B=4, T=512),
+    "prefill-b1xt16": dict(B=1, T=16),
 }
 
 
@@ -302,8 +361,10 @@ def run_matrix(slow: bool = False) -> dict:
     from production_stack_tpu.engine import runner
     from production_stack_tpu.models import llama
 
+    from production_stack_tpu.models import jamba
+
     out: dict = {"decode": {}, "prefill": {}, "prefill_refused": {},
-                 "smem": {}, "step_programs": {}}
+                 "smem": {}, "step_programs": {}, "ssm_scan": {}}
     for cid, (NH, KH, D, int8) in preset_shapes().items():
         refusal = runner.kernel_refusal(
             head_dim=D, kv_heads_per_shard=KH, pool_itemsize=1 if int8 else 2
@@ -337,21 +398,32 @@ def run_matrix(slow: bool = False) -> dict:
         for pid, kw in STEP_PROGRAMS.items():
             kw = dict(kw)
             tp = kw.get("tp", 1)
-            preset = llama.PRESETS[kw.pop("preset")]
+            name = kw.pop("preset")
+            recurrent = name in jamba.PRESETS
+            preset = (jamba if recurrent else llama).PRESETS[name]
             # depth 2 (the layer scan compiles one layer), the attention
             # path resolved as the engine resolves it on the chip
             attn = runner.resolve_attn_impl(
-                "auto", platform="tpu", n_devices=tp, fwd_takes_mesh=True,
+                "auto", platform="tpu", n_devices=tp,
+                fwd_takes_mesh=not recurrent,
                 num_heads=preset.num_heads, num_kv_heads=preset.num_kv_heads,
                 head_dim=preset.head_dim, tp=tp, pool_itemsize=2,
                 max_batch=kw["B"], max_pages=kw["max_pages"],
             )
             cfg = dataclasses.replace(preset, num_layers=2, attn_impl=attn.impl)
+            if recurrent:
+                # one period S S A S: both kinds of layer, two scanned runs
+                cfg = dataclasses.replace(
+                    cfg, num_layers=4, attn_layer_period=4, attn_layer_offset=2,
+                    ssm_impl="pallas",
+                )
             report: dict = {}
             out["step_programs"][pid] = dict(
                 _attempt(compile_step_program, cfg=cfg, report=report, **kw),
                 **report,
             )
+        for sid, kw in SSM_SCANS.items():
+            out["ssm_scan"][sid] = _attempt(compile_ssm_scan, **kw)
     return out
 
 

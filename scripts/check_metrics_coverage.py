@@ -95,6 +95,11 @@ DASHBOARD_ALLOWLIST = {
     "vllm:step_program_store_hits_total",    # how often a first dispatch found
     "vllm:step_program_store_writes_total",  # its exported program: the same
     "vllm:step_program_store_errors_total",  # start-up and bench surface
+    "vllm:ssm_state_slots",                  # a family with recurrent state
+    "vllm:ssm_state_slots_in_use",           # (models/jamba.py) alone emits
+    "vllm:ssm_state_bytes",                  # these; GET /stats shows them, no
+    "vllm:ssm_prefill_tokens_total",         # dashboard and no benchmark
+    "vllm:ssm_decode_tokens_total",          # reader reads them yet
     "vllm:decode_dispatches_total",          # dispatch-shape bench telemetry
     "vllm:decode_chained_dispatches_total",
     "vllm:runahead_prefill_dispatches_total",
