@@ -11,7 +11,10 @@ or one per-layer metric is a file of its own under `perfbench/`:
 A later PR adds files and never edits one; `python3 perfbench/manifest.py
 --write` then regenerates the lists of BENCHMARK.json (configs, workloads, the
 metrics and the cells each is reported in) and keeps `command`, `paths` and
-`run_seconds` as they are. `--check` fails when BENCHMARK.json is out of date.
+`run_seconds` as they are. Every list keeps the order BENCHMARK.json has and
+new names are appended, sorted among themselves: an entry put first or in the
+middle reads as a change to an accepted one. `--check` fails when
+BENCHMARK.json is out of date.
 """
 
 from __future__ import annotations
@@ -50,6 +53,12 @@ def names(kind: str, base: str = HERE) -> list[str]:
     return sorted(f[:-5] for f in os.listdir(d) if f.endswith(".json"))
 
 
+def accepted_first(found: list[str], accepted: list[dict]) -> list[str]:
+    """`found` in the order the accepted list names them, then the new names."""
+    have = [e["name"] for e in accepted if e["name"] in found]
+    return have + [n for n in found if n not in have]
+
+
 def peaks(device_kind: str, base: str = HERE) -> dict:
     table = load_json("peaks.json", base=base)
     if device_kind not in table:
@@ -60,8 +69,9 @@ def peaks(device_kind: str, base: str = HERE) -> dict:
 
 def build(current: dict, base: str = HERE) -> dict:
     rel = os.path.basename(base)
-    cells = {n: load_json("cells", n + ".json", base=base) for n in names("cells", base)}
-    used = sorted({c["config"] for c in cells.values()})
+    cells = {n: load_json("cells", n + ".json", base=base)
+             for n in accepted_first(names("cells", base), current.get("workloads", []))}
+    used = accepted_first(sorted({c["config"] for c in cells.values()}), current.get("configs", []))
     out = {
         "command": current["command"], "paths": current["paths"],
         "run_seconds": current["run_seconds"], "configs": [], "workloads": [],
@@ -79,7 +89,7 @@ def build(current: dict, base: str = HERE) -> dict:
             "chips": c["chips"], "why": c["why"],
         })
     for kind, key in (("end_to_end", "end_to_end"), ("layer_metrics", "per_layer")):
-        for n in names(kind, base):
+        for n in accepted_first(names(kind, base), current.get(key, [])):
             m = load_json(kind, n + ".json", base=base)
             where = [c for c, cell in cells.items() if n in cell[key]]
             if not where:

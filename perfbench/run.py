@@ -20,6 +20,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import gc
+import hashlib
 import json
 import os
 import shutil
@@ -45,7 +46,10 @@ from readers_common import prom  # noqa: E402
 REQUEST_TIMEOUT_S = 120.0
 SETUP_TIMEOUT_S = 1000.0  # a set-up phase on a cell's first run compiles every shape it meets
 TRACE_SECONDS = 3.0
-STALL_S = 1.0  # the load generator may run this late before the run is void
+STALL_S = 1.0  # the load generator may run this late before a window is void
+MAX_WINDOWS = 3  # a void window is measured again, twice at the most
+RUN_LIMIT_S, RUN_MARGIN_S = 360.0, 30.0  # the driver cuts a run at the first; a further window has to end this far under it
+TAIL_S = 40.0  # what follows a window: its requests awaited, the checks, the readers, the children stopped (14-39 s warm)
 
 
 class BenchFailure(Exception):
@@ -160,6 +164,7 @@ class Load:
         self.session, self.base, self.model = session, base, model
         self.log: list[dict] = []
         self.tasks: set = set()
+        self.waiting: set = set()  # open arrivals that are not due yet
 
     async def fire(self, req: dict, due: float, *, greedy=False, logprobs=0,
                    timeout=REQUEST_TIMEOUT_S) -> dict:
@@ -240,7 +245,12 @@ class Load:
 
     async def open_arrival(self, req: dict, t0: float, timeout=REQUEST_TIMEOUT_S) -> dict:
         due = t0 + req["due_s"]
-        await asyncio.sleep(max(0.0, due - time.monotonic()))
+        me = asyncio.current_task()
+        self.waiting.add(me)
+        try:
+            await asyncio.sleep(max(0.0, due - time.monotonic()))
+        finally:
+            self.waiting.discard(me)
         return await self.fire(req, due, timeout=timeout)
 
     async def client(self, c: dict, first_due: float, stop: asyncio.Event) -> None:
@@ -263,17 +273,106 @@ class Load:
         raise BenchFailure(f"a closed-loop client ran out of its {len(c['turns'])} generated "
                            "turns: lower min_turn_s")
 
-    async def heartbeat(self, stalls: list, every: float = 0.05) -> None:
-        """The load generator's own pulse: how late each 50 ms sleep returned.
-        The engine and the router are other processes on the same host: a
-        frozen host, or a system that takes the cores this process needs, makes
-        it late, and then the window measured the host."""
+    async def heartbeat(self, w: dict, void_from: float, void: asyncio.Event,
+                        every: float = 0.05) -> None:
+        """The load generator's own pulse beside window `w`: how late each
+        50 ms sleep returned, and beside each stall what the parent can read of
+        who had the cores (`host_sample`). The engine and the router are other
+        processes on the same host: a frozen host, or a system that takes the
+        cores this process needs, makes it late, and then the window measured
+        the host: past STALL_S it is void from that beat on, not from t1."""
+        before = host_sample()
         while True:
             t = time.monotonic()
             await asyncio.sleep(every)
             over = time.monotonic() - t - every
+            after = host_sample()
             if over > 0.05:
-                stalls.append((t, over))
+                w["stalls"].append((t, over, stall_cause(over + every, before, after)))
+            if over > STALL_S and void_from <= t < w["t1"] and not void.is_set():
+                w["void"] = ran_late(over)
+                void.set()
+            before = after
+
+
+SCHEDSTAT = "/proc/self/schedstat"  # the main thread's: run ns, run-queue wait ns, slices
+
+
+def host_sample() -> dict:
+    """This process's CPU seconds and, where the host shows it, the seconds
+    its thread waited on a run queue. (The sealed machine that holds the chip
+    does not: its `/proc` has no schedstat and no pressure, and its load
+    average reads 0.00 under any load, so neither is read here.)"""
+    out = {"cpu_s": time.process_time(), "waited_s": None}
+    if os.path.exists(SCHEDSTAT):
+        with open(SCHEDSTAT) as f:
+            out["waited_s"] = int(f.read().split()[1]) / 1e9
+    return out
+
+
+def ran_late(late_s: float) -> str:
+    return (f"the load generator ran {late_s * 1000:.0f} ms late in the window (limit "
+            f"{STALL_S * 1000:.0f}): the host was frozen or starved")
+
+
+def stall_cause(span_s: float, before: dict, after: dict) -> dict:
+    """What a stalled beat of `span_s` seconds was: `busy` where the parent's
+    own CPU time advanced by half of it or more (its one thread was at work),
+    `starved` where it stood as long on a run queue (it had no core), `stopped`
+    where neither moved (the process or the whole host stood still), and
+    `starved_or_stopped` where the host does not show the run queue."""
+    cpu_s = after["cpu_s"] - before["cpu_s"]
+    waited_s = None if after["waited_s"] is None else after["waited_s"] - before["waited_s"]
+    if cpu_s >= 0.5 * span_s:
+        kind = "busy"
+    elif waited_s is None:
+        kind = "starved_or_stopped"
+    else:
+        kind = "starved" if waited_s >= 0.5 * span_s else "stopped"
+    return {"kind": kind, "cpu_s": cpu_s, "waited_s": waited_s}
+
+
+def streams_beside(log: list, t: float, over: float, look: float = 0.25) -> str:
+    """Whether the engine went on while the load generator stood still, told
+    from what the generator read once it woke: the chunks of `over` seconds lay
+    in its sockets and come within `look` seconds, or only `look` seconds' worth
+    come, and the engine (or the whole host) stood too."""
+    end = t + 0.05 + over
+    times = [c for r in log for c in r["chunks"] if t - 2.0 <= c < end + look]
+    before = sum(1 for c in times if c < t) / 2.0  # chunks a second, the 2 s before the stall
+    after = sum(1 for c in times if c >= end)
+    if before * look < 5:
+        return f"{after} chunks in the {look} s after it, too few streams to tell"
+    went_on = after >= 0.5 * before * (over + look)
+    return (f"{after} chunks in the {look} s after it against {before * look:.0f} in such a span "
+            f"before: the engine {'went on' if went_on else 'stood too'}")
+
+
+def stall_table(stalls: list, t0: float, log: list) -> str:
+    """One line a stall: when (s into the window), late by, and what it was."""
+    def ms(v):
+        return "-" if v is None else f"{v * 1000:.0f}"
+    return "\n".join(
+        f"    stall at {t - t0:+7.2f}s late {over * 1000:5.0f} ms: {c['kind']}; own cpu "
+        f"{ms(c['cpu_s'])} ms, on a run queue {ms(c['waited_s'])} ms"
+        + ("; " + streams_beside(log, t, over) if over > STALL_S / 2 else "")
+        for t, over, c in stalls)
+
+
+def window_seed(seed: int, k: int) -> int:
+    """The traffic seed of a run's window k: `--seed` itself for the first,
+    and for a further one a number made of `--seed` and k alone, so that two
+    runs of one seed that void alike measure again alike."""
+    if k == 0:
+        return seed
+    return int.from_bytes(hashlib.sha256(f"{seed}/window/{k}".encode()).digest()[:4], "big")
+
+
+def room_for_a_window(elapsed_s: float, window_s: float) -> bool:
+    """Whether a further window of `window_s` (its set-up phases, lead-in and
+    measured seconds), opened `elapsed_s` into the run, and what follows a
+    window end under the driver's cut less the margin."""
+    return elapsed_s + window_s + TAIL_S <= RUN_LIMIT_S - RUN_MARGIN_S
 
 
 # -- one run --------------------------------------------------------------------
@@ -339,8 +438,10 @@ class Serving:
         await self.session.close()
         self.children.stop_all()
 
-    async def setup_phases(self, plan) -> None:
-        for phase in plan["setup"]:
+    async def setup_phases(self, phases: list) -> dict:
+        """Each phase done before the next; the seconds each took, by name."""
+        took = {}
+        for phase in phases:
             t = time.monotonic()
             recs = await self.load.phase(phase)
             bad = [r for r in recs if not r["ok"]]
@@ -349,6 +450,8 @@ class Serving:
                  + (f" ({bad[0]['error']})" if bad else ""))
             if bad:
                 raise BenchFailure(f"set-up phase {phase['name']} failed: {bad[0]['error']}")
+            took[phase["name"]] = time.monotonic() - t
+        return took
 
     async def snapshot(self) -> dict:
         s, (_, m) = await asyncio.gather(
@@ -367,83 +470,181 @@ def load_cell(cell_name: str, base_dir: str, args):
     return cell, doc, generator, out_dir
 
 
+async def sleep_until(t: float, void: asyncio.Event) -> bool:
+    """Sleeps to `t` on the monotonic clock, or until the window is void;
+    says which."""
+    delay = t - time.monotonic()
+    if delay > 0 and not void.is_set():
+        try:
+            await asyncio.wait_for(void.wait(), delay)
+        except asyncio.TimeoutError:
+            pass
+    return void.is_set()
+
+
+async def take_trace(sv, out_dir: str, seconds: float, w: dict, taken: list) -> dict:
+    """The profiler for TRACE_SECONDS, and the reduction of what it wrote (a
+    child, awaited off the loop). `bad` says why a trace should be taken again:
+    the load generator stalled beside it, or its device plane is empty (the
+    engine stood at a first dispatch all through)."""
+    nth = f".{len(taken)}" if taken else ""
+    trace_dir = os.path.join(out_dir, "trace" + nth)
+    ta = time.monotonic()
+    await post_json(sv.session, sv.cbase + "/profile/start", {"dir": trace_dir})
+    tb = time.monotonic()
+    await asyncio.sleep(min(TRACE_SECONDS, max(0.5, 0.2 * seconds)))
+    tc = time.monotonic()
+    await post_json(sv.session, sv.cbase + "/profile/stop", {}, timeout=600)
+    sub = {"start_lo": ta, "start_hi": tb, "stop_lo": tc, "stop_hi": time.monotonic(),
+           "dir": trace_dir, "trace": None, "bad": None}
+    taken.append(sub)
+    try:
+        sub["trace"] = await asyncio.get_running_loop().run_in_executor(
+            None, reduce_trace, sub, os.path.join(out_dir, f"trace_reduced{nth}.json"))
+    except BenchFailure as e:
+        if "no device plane" not in str(e) and sv.allow_platform == "tpu":
+            raise
+        sub["bad"] = f"the trace holds no device plane with events ({str(e)[-300:].strip()})"
+    beside = [over for t, over, _ in w["stalls"]
+              if over > STALL_S / 2 and t < sub["stop_hi"] and t + over + 0.05 > ta]
+    if beside and not sub["bad"]:
+        sub["bad"] = f"the load generator stalled {max(beside) * 1000:.0f} ms beside the trace"
+    return sub
+
+
+async def measure_window(sv, plan: dict, seconds: float, traced: bool, out_dir: str,
+                         taken: list, another_window) -> dict:
+    """One window over `plan`: its traffic spawned, the wait to t0, the
+    snapshot, in a traced run the profile, the wait to t1, the snapshot, the
+    generator's lateness; then nothing new is sent and what is in flight is
+    awaited. The window comes back with `void` None where it held, and else
+    with why it measured nothing: it is abandoned the moment the heartbeat sees
+    the stall, not at t1. `taken` holds the run's traces so far: a bad one is
+    taken again ONCE in a run, in this window where t1 leaves the time and
+    else in a further one, if `another_window()` says the run has room."""
+    session, load = sv.session, sv.load
+    stop, void = asyncio.Event(), asyncio.Event()
+    # warm-up traffic runs from now; the window opens `warm_seconds` later
+    t0 = time.monotonic() + plan["warm_seconds"] + 0.05
+    t1 = t0 + seconds
+    w = {"t0": t0, "t1": t1, "plan": plan, "void": None, "sub": None, "snap0": None,
+         "snap1": None, "stalls": []}
+    beat = asyncio.ensure_future(load.heartbeat(w, t0 - 1.0, void))
+    for c in plan["clients"]:
+        load.spawn(load.client(c, t0 + c["first_due_s"], stop))
+    for req in plan["open"]:
+        load.spawn(load.open_arrival(req, t0))
+    if not await sleep_until(t0, void):
+        if traced:
+            await post_json(session, sv.ebase + "/metrics/reset", {})
+        w["snap0"] = await sv.snapshot()
+        m0 = w["snap0"]["metrics"]
+        note(f"window opens {t0 - T_START:.2f}s into the run (engine healthy at "
+             f"{sv.load_s:.1f}s; {prom(m0, 'vllm:compile_events_total'):.0f} compile events, "
+             f"{prom(m0, 'vllm:compile_seconds_total'):.1f}s of compile or cache load)")
+    if traced and not await sleep_until(t0 + 0.4 * seconds, void):
+        first = not any(sub["bad"] for sub in taken)
+        w["sub"] = sub = await take_trace(sv, out_dir, seconds, w, taken)
+        took = sub["stop_hi"] - sub["start_lo"]
+        if sub["bad"] and first and sv.allow_platform == "tpu" and not void.is_set():
+            if time.monotonic() + took + 1.0 <= t1:
+                note(f"trace taken again in this window: {sub['bad']}")
+                w["sub"] = await take_trace(sv, out_dir, seconds, w, taken)
+            elif another_window():
+                w["void"] = f"{sub['bad']}, and t1 leaves no {took:.0f} s to take it again"
+                void.set()
+    await sleep_until(t1, void)
+    if not void.is_set():
+        w["snap1"] = await sv.snapshot()
+        # TTFT counts from the time a request was DUE, so lateness up to the
+        # limit is in the numbers. Past it the window measured the host, not
+        # the system: it is void, and the run measures another (`run_cell`).
+        late = max([over for t, over, _ in w["stalls"] if t0 - 1.0 <= t < t1] + [
+            r["sent"] - r["due"] for r in load.log if t0 <= r["due"] < t1], default=0.0)
+        note(f"the load generator ran at most {late * 1000:.0f} ms late in the window "
+             f"(limit {STALL_S * 1000:.0f})")
+        if late > STALL_S:
+            w["void"] = ran_late(late)
+    stop.set()
+    beat.cancel()
+    await asyncio.gather(beat, return_exceptions=True)
+    for task in list(load.waiting):  # a void window's arrivals that are not sent yet
+        task.cancel()
+    for r in load.log:
+        r["measured"] = not w["void"] and t0 <= r["due"] < t1
+    # requests due in the window are awaited; nothing new is sent
+    pending = list(load.tasks)
+    if pending:
+        done, late_tasks = await asyncio.wait(pending, timeout=REQUEST_TIMEOUT_S)
+        for task in late_tasks:
+            task.cancel()
+        for task in done:
+            if not task.cancelled() and task.exception():
+                raise task.exception()
+    if w["stalls"]:  # now that what lay in the sockets beside the last stall is read
+        note(f"{len(w['stalls'])} heartbeat stalls over 50 ms beside this window\n"
+             + stall_table(w["stalls"], t0, load.log))
+    return w
+
+
 async def run_cell(args, cell_name: str, base_dir: str, allow_platform: str = "tpu") -> dict:
     """Runs the cell and returns the result object. `base_dir` is the
     perfbench directory the data files are read from, and `allow_platform`
     is "tpu" on every path the command line reaches: only perfbench/tests pass
-    another (a CPU rehearsal, whose numbers are printed under no metric's name)."""
+    another (a CPU rehearsal, whose numbers are printed under no metric's name).
+
+    A window that comes back void is measured again on the same engine and
+    router, MAX_WINDOWS in all at the most and only while `room_for_a_window`:
+    the traffic of window k is the cell's own under `window_seed(--seed, k)`,
+    after the set-up phases its plan marks `every_window` (state that belongs
+    to the plan's own text: the sessions' cached histories) and its own
+    lead-in, with no ramp and no drained warm-up, since every shape exists by
+    then. `setup_s` is the FIRST window's opening whatever happens after; every
+    other number comes from the window that held."""
     cell, doc, generator, out_dir = load_cell(cell_name, base_dir, args)
     traced = bool(args.trace)
     seconds = float(args.seconds)
     params, tok = cell["traffic"]["params"], doc["perfbench"]["tokenizer"]
     plan = generator.generate(params, args.seed, seconds, tok)
-    warm = plan["warm_seconds"]
     async with Serving(args, cell_name, cell, doc, base_dir, allow_platform, out_dir) as sv:
         session, load, device = sv.session, sv.load, sv.device
         ebase, cbase, rbase = sv.ebase, sv.cbase, sv.rbase
-        await sv.setup_phases(plan)
+        phase_s = await sv.setup_phases(plan["setup"])
+        every_window = [p["name"] for p in plan["setup"] if p.get("every_window")]
+        # what a further window costs: its phases (at what they cost the first), lead-in, seconds
+        window_s = sum(phase_s[n] for n in every_window) + plan["warm_seconds"] + seconds
 
         # the load generator is one thread: no collector pause may fall on it
         gc.collect()
         gc.freeze()
         gc.disable()
-        stop, stalls = asyncio.Event(), []
-        beat = asyncio.ensure_future(load.heartbeat(stalls))
-        # warm-up traffic runs from now; the window opens `warm` seconds later
-        t0 = time.monotonic() + warm + 0.05
-        t1 = t0 + seconds
-        setup_s = t0 - T_START
-        for c in plan["clients"]:
-            load.spawn(load.client(c, t0 + c["first_due_s"], stop))
-        for req in plan["open"]:
-            load.spawn(load.open_arrival(req, t0))
-        await asyncio.sleep(max(0.0, t0 - time.monotonic()))
-        if traced:
-            await post_json(session, ebase + "/metrics/reset", {})
-        snap0 = await sv.snapshot()
+        voided, taken, setup_s = [], [], None
+
+        def another_window() -> bool:
+            return (len(voided) + 1 < MAX_WINDOWS
+                    and room_for_a_window(time.monotonic() - T_START, window_s))
+
+        while True:
+            w = await measure_window(sv, plan, seconds, traced, out_dir, taken, another_window)
+            if setup_s is None:
+                setup_s = w["t0"] - T_START
+            if not w["void"]:
+                break
+            note(f"window {len(voided) + 1} voided: {w['void']}")
+            if not another_window():
+                raise BenchFailure(
+                    f"{w['void']}, and no room to measure again: window {len(voided) + 1} of "
+                    f"{MAX_WINDOWS}, {time.monotonic() - T_START:.0f} s gone, another takes "
+                    f"{window_s:.0f} s and the {TAIL_S:.0f} s after a window, of "
+                    f"{RUN_LIMIT_S - RUN_MARGIN_S:.0f}; the run gives no result")
+            voided.append(w["void"])
+            plan = generator.generate(params, window_seed(args.seed, len(voided)), seconds, tok)
+            await sv.setup_phases([p for p in plan["setup"] if p["name"] in every_window])
+        t0, t1, snap0, snap1, sub = w["t0"], w["t1"], w["snap0"], w["snap1"], w["sub"]
         m0 = snap0["metrics"]
-        note(f"window opens: setup_s={setup_s:.2f} (engine healthy at "
-             f"{sv.load_s:.1f}s; {prom(m0, 'vllm:compile_events_total'):.0f} compile events, "
-             f"{prom(m0, 'vllm:compile_seconds_total'):.1f}s of compile or cache load)")
-        sub = None
-        if traced:
-            trace_dir = os.path.join(out_dir, "trace")
-            await asyncio.sleep(max(0.0, t0 + 0.4 * seconds - time.monotonic()))
-            ta = time.monotonic()
-            await post_json(session, cbase + "/profile/start", {"dir": trace_dir})
-            tb = time.monotonic()
-            await asyncio.sleep(min(TRACE_SECONDS, max(0.5, 0.2 * seconds)))
-            tc = time.monotonic()
-            await post_json(session, cbase + "/profile/stop", {}, timeout=600)
-            sub = {"start_lo": ta, "start_hi": tb, "stop_lo": tc,
-                   "stop_hi": time.monotonic(), "dir": trace_dir}
-        await asyncio.sleep(max(0.0, t1 - time.monotonic()))
-        snap1 = await sv.snapshot()
-        # TTFT counts from the time a request was DUE, so lateness up to the
-        # limit is in the numbers. Past it the window measured the host, not
-        # the system: the run fails, and says so, rather than measure again.
-        stall = max([over for t, over in stalls if t0 - 1.0 <= t < t1] + [
-            r["sent"] - r["due"] for r in load.log if t0 <= r["due"] < t1], default=0.0)
-        note(f"the load generator ran at most {stall * 1000:.0f} ms late in the window "
-             f"(limit {STALL_S * 1000:.0f})")
-        if stall > STALL_S:
-            raise BenchFailure(f"the load generator ran {stall * 1000:.0f} ms late in the "
-                               f"window (limit {STALL_S * 1000:.0f}): the host was frozen or "
-                               "starved, and the run gives no result")
-        stop.set()
-        beat.cancel()
-        await asyncio.gather(beat, return_exceptions=True)
-        for r in load.log:
-            r["measured"] = t0 <= r["due"] < t1
-        # requests due in the window are awaited; nothing new is sent
-        pending = list(load.tasks)
-        if pending:
-            done, late = await asyncio.wait(pending, timeout=REQUEST_TIMEOUT_S)
-            for task in late:
-                task.cancel()
-            for task in done:
-                if not task.cancelled() and task.exception():
-                    raise task.exception()
+        if voided:
+            note(f"window {len(voided) + 1} held after {len(voided)} voided; setup_s stays the "
+                 f"first window's opening, {setup_s:.2f}")
         gc.enable()
         device1 = await get_json(session, cbase + "/device")
         spans = {}
@@ -487,6 +688,7 @@ async def run_cell(args, cell_name: str, base_dir: str, allow_platform: str = "t
         "cell": cell, "config": doc, "requests": requests, "window": (t0, t1),
         "snap0": snap0, "snap1": snap1, "spans": spans, "trace": None, "sub": sub,
         "device_kind": device["kind"], "base_dir": base_dir, "worst_ms": worst_ms,
+        "windows_voided": len(voided),
     }
     result_device = {
         "platform": device["platform"], "kind": device["kind"], "count": device["count"],
@@ -509,14 +711,14 @@ async def run_cell(args, cell_name: str, base_dir: str, allow_platform: str = "t
                 value, n = pstats.end_to_end(spec, requests, (t0, t1), worst_ms)
             report(name, value, spec["unit"], f" over {n} samples")
     else:
-        try:
-            context["trace"] = reduce_trace(sub, out_dir)
-        except BenchFailure:
-            if allow_platform == "tpu":
-                raise
-            # a CPU rehearsal has no device plane: the trace readers return nothing
-            context["trace"] = {"busy_s": 0.0, "window_s": 0.0, "top_ops": [], "top_gaps": [],
-                                "ops": {}, "modules": {}, "devices": 1}
+        if sub["bad"]:
+            note(f"the trace is kept as it is: {sub['bad']}")
+        if sub["trace"] is None and allow_platform == "tpu":
+            raise BenchFailure(f"{sub['bad']}, taken {len(taken)} times")
+        # a CPU rehearsal has no device plane: the trace readers return nothing
+        context["trace"] = sub["trace"] or {
+            "busy_s": 0.0, "window_s": 0.0, "top_ops": [], "top_gaps": [], "ops": {},
+            "modules": {}, "devices": 1}
         if allow_platform == "tpu":  # an unknown device kind is an error, not a default
             context["peaks"] = manifest.peaks(device["kind"], base_dir)
         tr = context["trace"]
@@ -528,7 +730,9 @@ async def run_cell(args, cell_name: str, base_dir: str, allow_platform: str = "t
             reader = manifest.load_module("readers", spec["reader"], base_dir)
             report(name, reader.read(context, spec.get("params", {})), spec["unit"])
     with open(os.path.join(out_dir, "requests.json"), "w") as f:
-        json.dump({"window": [t0, t1], "sub": sub, "requests": [
+        json.dump({"cell": cell_name, "window": [t0, t1], "setup_s": setup_s, "voided": voided,
+                   "sub": sub and {k: v for k, v in sub.items() if k != "trace"},
+                   "snap0": snap0, "snap1": snap1, "requests": [
             {k: v for k, v in r.items() if k != "logprobs"} for r in requests]}, f)
     for name, c in checks.items():
         note(f"check {name}: {'ok' if c['ok'] else 'FAILED'} {c.get('detail', '')}")
@@ -560,7 +764,7 @@ async def run_sweep(args, cell_name: str, base_dir: str, allow_platform: str = "
     rows = []
     async with Serving(args, cell_name, cell, doc, base_dir, allow_platform, out_dir) as sv:
         warm = generator.generate(cell["traffic"]["params"], args.seed, 1.0, tok)
-        await sv.setup_phases(warm)
+        await sv.setup_phases(warm["setup"])
         for i, rate in enumerate(rates):
             params = copy.deepcopy(cell["traffic"]["params"])
             stream = next(s for s in params["streams"] if s["kind"] == "open")
@@ -622,7 +826,7 @@ def idle_gaps(tr: dict, d0: dict, d1: dict) -> list:
         list(h) for h in host]
 
 
-def reduce_trace(sub: dict, out_dir: str) -> dict:
+def reduce_trace(sub: dict, out: str) -> dict:
     """`tracereduce.py` in a child of its own with JAX held to the CPU: it
     reads the profile with JAX's reader and this parent stays off JAX."""
     found = []
@@ -630,7 +834,6 @@ def reduce_trace(sub: dict, out_dir: str) -> dict:
         found += [os.path.join(d, f) for f in files if f.endswith(".xplane.pb")]
     if not found:
         raise BenchFailure(f"the profiler wrote no .xplane.pb under {sub['dir']}")
-    out = os.path.join(out_dir, "trace_reduced.json")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, os.path.join(HERE, "tracereduce.py"), found[0], "--out", out],

@@ -55,12 +55,69 @@ def test_rehearsal_on_the_cpu(tree, tmp_path, trace):
     assert "jax" not in sys.modules or os.environ.get("JAX_PLATFORMS") == "cpu"
 
 
-def test_a_run_whose_generator_ran_late_fails_and_is_not_measured_again(tree, tmp_path, monkeypatch):
-    monkeypatch.setattr(run, "STALL_S", 1e-9)  # any lateness is past the limit
+def _windows(monkeypatch, limits):
+    """`run.STALL_S` set anew before each window of a run: limits[k] for window
+    k, the last for all further ones. Returns the list of windows as they came back."""
+    real, opened = run.measure_window, []
+
+    async def measure_window(*a, **k):
+        monkeypatch.setattr(run, "STALL_S", limits[min(len(opened), len(limits) - 1)])
+        opened.append(None)
+        opened[-1] = await real(*a, **k)
+        return opened[-1]
+
+    monkeypatch.setattr(run, "measure_window", measure_window)
+    return opened
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_window_whose_generator_ran_late_is_measured_again(tree, tmp_path, monkeypatch, capfd, trace):
+    opened = _windows(monkeypatch, [1e-9, 1.0])  # any lateness voids the first window
+    args = argparse.Namespace(workload="tiny-llama.rehearsal", seed=5, seconds=3.0, trace=trace,
+                              out=str(tmp_path / "out"))
+    res = asyncio.run(run.run_cell(args, args.workload, str(tree), allow_platform="cpu"))
+    err = capfd.readouterr().err
+    first, second = opened
+    assert "window 1 voided" in err and "window 2 held after 1 voided" in err
+    assert "ms late in the window" in first["void"] and second["void"] is None
+    assert res["correct"] and res["failed"] == 0 and res["attempted"] > 5
+    log = json.load(open(tmp_path / "out" / "requests.json"))
+    assert log["voided"] == [first["void"]] and log["window"] == [second["t0"], second["t1"]]
+    # the void window was abandoned at its first late beat (in the last second of its
+    # lead-in, so it never took a snapshot), not at its t1
+    assert first["snap0"] is None and first["snap1"] is None
+    # its requests stay in the log, unmeasured, and the second window's are other text
+    assert sum(1 for r in log["requests"] if r["measured"]) == res["attempted"]
+    assert all(second["t0"] <= r["due"] < second["t1"] for r in log["requests"] if r["measured"])
+    assert json.dumps(first["plan"]["open"]) != json.dumps(second["plan"]["open"])
+    assert [p["name"] for p in second["plan"]["setup"] if p.get("every_window")] == ["qa.histories"]
+    assert err.count("set-up phase qa.histories") == 2 and err.count("set-up phase chat.ramp") == 1
+    # setup_s is the FIRST window's opening, whatever happens after
+    assert log["setup_s"] == first["t0"] - run.T_START < second["t0"] - run.T_START - 1.0
+    if trace:
+        assert res["metrics"]["cpu_rehearsal.bench.windows_voided"]["value"] == 1
+        assert res["metrics"]["cpu_rehearsal.kv.prefix_hit_share"]["value"] > 10
+    else:
+        assert res["metrics"]["cpu_rehearsal.setup_s"]["value"] == log["setup_s"]
+
+
+def test_a_run_whose_every_window_is_void_fails_after_its_windows(tree, tmp_path, monkeypatch, capfd):
+    opened = _windows(monkeypatch, [1e-9])
     args = argparse.Namespace(workload="tiny-llama.rehearsal", seed=5, seconds=2.0, trace=0,
                               out=str(tmp_path / "out"))
-    with pytest.raises(run.BenchFailure, match="ms late in the window"):
+    with pytest.raises(run.BenchFailure, match="ms late in the window.*no room to measure again"):
         asyncio.run(run.run_cell(args, args.workload, str(tree), allow_platform="cpu"))
+    assert len(opened) == run.MAX_WINDOWS
+
+
+def test_a_run_with_no_room_left_fails_at_its_first_void_window(tree, tmp_path, monkeypatch):
+    opened = _windows(monkeypatch, [1e-9])
+    monkeypatch.setattr(run, "RUN_LIMIT_S", 1.0)  # the budget rule, not the count, ends it
+    args = argparse.Namespace(workload="tiny-llama.rehearsal", seed=5, seconds=2.0, trace=0,
+                              out=str(tmp_path / "out"))
+    with pytest.raises(run.BenchFailure, match="no room to measure again: window 1 of 3"):
+        asyncio.run(run.run_cell(args, args.workload, str(tree), allow_platform="cpu"))
+    assert len(opened) == 1
 
 
 def test_the_command_refuses_to_run_off_the_tpu(tmp_path):

@@ -1,5 +1,6 @@
 """The generator: deterministic in (params, seed, seconds), no clock; sizes and
-gaps are draws, and every seed deals the same draws in another order."""
+gaps are draws, the same under every seed: an open stream keeps their order
+too (the seed writes the text), sessions are dealt in another order."""
 
 import copy
 import time
@@ -57,17 +58,26 @@ def window_of(seed, seconds=30):
     return [r for r in plan["open"] if r["due_s"] >= 0], [r for r in plan["open"] if r["due_s"] < 0]
 
 
-def test_open_loop_deals_the_same_draws_under_every_seed_in_another_order():
+@pytest.mark.parametrize("seed", [2, 2**31 + 5, 3450002201])
+def test_open_loop_offers_every_seed_the_same_schedule_in_other_words(seed):
+    """The order of the draws moved the median time per token more than the
+    system's own noise did (PERF.md section 6), so the seed no longer deals it."""
     a, warm_a = window_of(1)
-    b, _ = window_of(2**31 + 5)
+    b, warm_b = window_of(seed)
     assert len(a) == len(b) == 180  # rate x seconds, exactly
     assert all(0 <= r["due_s"] < 30 for r in a)
-    for key in ("prompt_tokens", "max_tokens"):
-        assert sorted(r[key] for r in a) == sorted(r[key] for r in b)
-        assert [r[key] for r in a] != [r[key] for r in b]
-    gaps = lambda rs: sorted(round(y["due_s"] - x["due_s"], 9) for x, y in zip(rs, rs[1:]))  # noqa: E731
-    assert len(set(gaps(a)) & set(gaps(b))) > 170  # the same gaps, dealt differently
-    assert [r["due_s"] for r in a] != [r["due_s"] for r in b]
+    schedule = lambda rs: [(r["due_s"], r["prompt_tokens"], r["max_tokens"]) for r in rs]  # noqa: E731
+    assert schedule(a) == schedule(b) and schedule(warm_a) == schedule(warm_b)
+    assert all(x["messages"] != y["messages"] for x, y in zip(a, b))
+    phases = lambda s: mix.generate({"streams": [OPEN]}, s, 30, TOK)["setup"]  # noqa: E731
+    for x, y in zip(phases(1), phases(seed)):  # the ramp and the warm-up too
+        xs, ys = x.get("requests") or x["open"], y.get("requests") or y["open"]
+        assert [(r.get("due_s"), r["prompt_tokens"], r["max_tokens"]) for r in xs] == \
+               [(r.get("due_s"), r["prompt_tokens"], r["max_tokens"]) for r in ys]
+
+
+def test_open_loop_draws_its_sizes_and_its_window():
+    a, warm_a = window_of(1)
     assert set(r["prompt_tokens"] for r in a) <= set(range(256, 2049, 256))
     assert len(set(r["prompt_tokens"] for r in a)) >= 6 and len(set(r["max_tokens"] for r in a)) > 60
     # the lead-in is the same mix under draws of its own, never the window's

@@ -9,7 +9,10 @@ the same `(params, seed, seconds)` gives the same plan, byte for byte.
                to build, a ramp of concurrent requests), or {"name", "open":
                [request with "due_s" >= 0, ...]} sent when due (warm-up traffic,
                then drained: whatever queue its first meeting with the system
-               builds is gone before the window);
+               builds is gone before the window). A phase marked
+               "every_window" builds state that belongs to this plan's own text
+               (a cache of its histories): a run that measures a further window
+               under another seed's plan runs that plan's marked phases again;
     "open":    [request with "due_s", ...]  open loop: sent when due, whatever
                the system does. due_s < 0 is the lead-in flowing into the window;
     "clients": [{"first_due_s", "think_s", "turns": [request, ...]}, ...]
@@ -22,10 +25,15 @@ the same `(params, seed, seconds)` gives the same plan, byte for byte.
 
 Sizes and gaps are DRAWN: exponential gaps (Poisson arrivals), log-uniform
 lengths, uniform think times. A drawn set has its bursts and its runs of long
-requests. The draws come from the mix's own `population_seed`, and `--seed`
-deals them in another order (a plain shuffle) and writes other text: every seed
-then offers the same amount of work, arranged differently, so runs differ by
-what the system makes of the arrangement and not by how much was asked.
+requests. The draws come from the mix's own `population_seed`: every seed
+offers the same amount of work. An OPEN stream offers it on the same schedule
+too, request for request as drawn, and `--seed` writes the text (and the
+weights): until PR 45 it also dealt the draws in another order (a plain
+shuffle), which makes other bursts of the same draws, and at 0.7-0.8 x the
+knee the median time per token followed the arrangement and not the system
+(PERF.md section 6: two runs of one seed lay 0.15-0.19 ms apart, four seeds
+0.53-1.06). SESSIONS, whose users wait for their replies and so pace
+themselves, are still dealt by `--seed` in another order.
 
 Stream kinds (`params["streams"]`, any number, merged into one plan):
   open      independent users: rate_rps, prompt_tokens [lo, hi], output_tokens
@@ -78,13 +86,12 @@ def log_uniform(rng: random.Random, lo: float, hi: float, n: int, quantum: int =
     return out
 
 
-def poisson_times(rng: random.Random, order: random.Random, n: int, span: float) -> list[float]:
+def poisson_times(rng: random.Random, n: int, span: float) -> list[float]:
     """n arrival times in [0, span): n + 1 exponential gaps drawn by `rng`,
-    dealt by `order`, scaled to fill the span. That is a Poisson process given
-    that n arrivals fell into the span, so the offered rate is exact and the
-    bunching is a Poisson process's own."""
+    scaled to fill the span. That is a Poisson process given that n arrivals
+    fell into the span, so the offered rate is exact and the bunching is a
+    Poisson process's own."""
     gaps = [rng.expovariate(1.0) for _ in range(n + 1)]
-    order.shuffle(gaps)
     scale = span / sum(gaps)
     times, t = [], 0.0
     for g in gaps[:n]:
@@ -121,7 +128,7 @@ def ramp(name: str, requests: list[dict], spec: dict) -> dict:
 
 
 def open_stream(s, name, seed, seconds, tok):
-    order = random.Random(f"{seed}/{name}/open")
+    words = random.Random(f"{seed}/{name}/open")  # the seed writes the text, the mix its sizes and times
     warm, lead = float(s.get("warm_seconds", 0)), float(s.get("lead_seconds", 0))
     rate, q = float(s["rate_rps"]), int(s.get("quantum", 1))
     plan = {"setup": [], "open": [], "clients": [], "warm_seconds": lead}
@@ -129,10 +136,10 @@ def open_stream(s, name, seed, seconds, tok):
         pop = random.Random(f"{s.get('population_seed', 0)}/{name}/ramp")
         n = int(s["ramp"]["requests"])
         # the stream's own lengths; the longest the mix can send outlasts them all
-        prompts = shuffled(log_uniform(pop, *s["prompt_tokens"], n - 1, q), order)
+        prompts = log_uniform(pop, *s["prompt_tokens"], n - 1, q)
         prompts.append(log_uniform(pop, s["prompt_tokens"][1], s["prompt_tokens"][1], 1, q)[0])
         plan["setup"].append(ramp(f"{name}.ramp", [
-            one_shot(order, f"r{seed:x}.{i} ", p, 0, tok, name) for i, p in enumerate(prompts)
+            one_shot(words, f"r{seed:x}.{i} ", p, 0, tok, name) for i, p in enumerate(prompts)
         ], s["ramp"]))
     for part, span, offset in (("warm", warm, None), ("lead", lead, -lead),
                                ("window", float(seconds), 0.0)):
@@ -141,12 +148,12 @@ def open_stream(s, name, seed, seconds, tok):
         # warm-up and lead-in are the same mix under draws of their own, never the window's
         pop = random.Random(f"{s.get('population_seed', 0)}/{name}/{part}/{span}")
         n = max(1, int(round(rate * span)))
-        times = poisson_times(pop, order, n, span)
-        prompts = shuffled(log_uniform(pop, *s["prompt_tokens"], n, q), order)
-        outputs = shuffled(log_uniform(pop, *s["output_tokens"], n), order)
+        times = poisson_times(pop, n, span)
+        prompts = log_uniform(pop, *s["prompt_tokens"], n, q)
+        outputs = log_uniform(pop, *s["output_tokens"], n)
         reqs = []
         for i, t in enumerate(times):
-            r = one_shot(order, f"{part[:2]}{seed:x}.{i} ", prompts[i], outputs[i], tok, name)
+            r = one_shot(words, f"{part[:2]}{seed:x}.{i} ", prompts[i], outputs[i], tok, name)
             r["due_s"] = (offset or 0.0) + t
             reqs.append(r)
         if offset is None:
@@ -203,7 +210,9 @@ def sessions_stream(s, name, seed, seconds, tok):
         # spread the users over one think time, so they do not ask in step
         clients.append({"turns": turns, "think_s": think,
                         "first_due_s": -warm + think * order.random()})
-    setup = [{"name": f"{name}.histories", "requests": cache_phase}]
+    # the cached histories are this plan's own text: a further window of a run,
+    # which takes a plan of another seed, builds them again (`every_window`)
+    setup = [{"name": f"{name}.histories", "requests": cache_phase, "every_window": True}]
     if s.get("ramp"):
         n = min(users, int(s["ramp"]["requests"]))
         setup.append(ramp(f"{name}.ramp", [c["turns"].pop(0) for c in clients[:n]], s["ramp"]))
