@@ -920,6 +920,16 @@ class EngineServer:
             emit("ssm_decode_tokens_total", "counter",
                  s["ssm_decode_tokens_total"],
                  "output tokens the selective scan stepped in decode bursts")
+            emit("conv_state_bytes", "gauge", s["conv_state_bytes"],
+                 "bytes of the convolution tails in the state pool")
+        if "moe_routed_rows_total" in s:
+            # sparse experts (ops/moe.py), counted by the device
+            emit("moe_routed_rows_total", "counter", s["moe_routed_rows_total"],
+                 "token-to-expert assignments the expert layers computed")
+            emit("moe_expert_reads_total", "counter", s["moe_expert_reads_total"],
+                 "experts with at least one row, over expert layers and steps")
+            emit("moe_expert_slots_total", "counter", s["moe_expert_slots_total"],
+                 "experts held, over expert layers and steps")
         emit("first_dispatches_total", "counter",
              s.get("first_dispatches_total", 0),
              "step-program shapes dispatched for the first time in this process")

@@ -577,6 +577,14 @@ class LLMEngine:
             batch_multiple=cfg.data_parallel_size,
             decode_steps=cfg.decode_steps,
             decode_pipeline=cfg.decode_pipeline,
+            # a family whose decode attention reads a row's gathered pages
+            # once says so: every decode dispatch then has max_model_len's
+            # own page-table width, and the decode programs differ by batch
+            # bucket alone (7 shapes to compile cold, not 21: PERF.md PR 46)
+            decode_page_bucket_floor=(
+                -(-cfg.max_model_len // cfg.page_size)
+                if getattr(model_cfg, "decode_one_page_width", False) else 0
+            ),
             spec_k=cfg.speculative_k,
             spec_ngram=cfg.speculative_ngram,
             max_waiting_seqs=cfg.max_waiting_seqs,
@@ -635,6 +643,14 @@ class LLMEngine:
         # decode bursts; each crosses every state-space layer once
         self.ssm_prefill_tokens_total = 0
         self.ssm_decode_tokens_total = 0
+        # what the device counted in its dispatches (a configuration with
+        # ``step_counters``, models/lfm2.py: rows the expert layers routed by
+        # expert, experts read, experts held), summed as the counters arrive
+        # with the tokens; the family's ``counter_stats`` names them
+        self.step_counter_totals = np.zeros(self.runner.num_counters, np.int64)
+        # the device thread adds after every dispatch, stats() on its own
+        # thread takes what the last dispatch before an idle spell left
+        self._step_counter_lock = threading.Lock()
         # engine steps that raised (device thread is the only writer), and
         # the first step program that failed to BUILD (runner.
         # ProgramBuildError): every later batch of that shape fails the same
@@ -1304,6 +1320,7 @@ class LLMEngine:
                         self._note_first_dispatch(batch)
                     work = self._count_work(batch)
                     tokens, lp_data, fetched = self._dispatch_batch(batch)
+                    self._count_device_work()
             except Exception as step_err:
                 logger.exception("engine step failed; aborting batch")
                 self.step_errors_total += 1
@@ -1424,6 +1441,15 @@ class LLMEngine:
                 np.sum(np.maximum(np.broadcast_to(steps, (n,)), 0))
             )
         return {"kv_tokens_read": read}
+
+    def _count_device_work(self) -> None:
+        """Add the counters of the dispatches that have ended (they came with
+        their tokens; one that still runs is counted at a later call)."""
+        if self.runner.num_counters:
+            with self._step_counter_lock:
+                done = self.runner.take_counters()
+                if done is not None:
+                    self.step_counter_totals += done
 
     def _dispatch_batch(self, batch):
         """Stage and dispatch one scheduled batch and fetch what the host
@@ -2577,12 +2603,21 @@ class LLMEngine:
             out["ssm_state_slots"] = self.kv.state_slots
             out["ssm_state_slots_in_use"] = self.kv.slots_in_use()
             out["ssm_state_bytes"] = self.runner.state_pool_bytes()
+            out["conv_state_bytes"] = self.runner.conv_state_bytes
             out["ssm_prefill_tokens_total"] = self.ssm_prefill_tokens_total
             out["ssm_decode_tokens_total"] = self.ssm_decode_tokens_total
             out["ssm_kernel"] = self.runner.ssm_impl
             out["ssm_kernel_reason"] = self.runner.ssm_reason
             out["state_family_off"] = dict(self.state_family_off)
             out["state_family_refusals"] = dict(self.state_family_refusals)
+        if self.runner.num_counters:
+            # counted by the device, named by the family (models/lfm2.py:
+            # moe_routed_rows_total, moe_expert_reads_total,
+            # moe_expert_slots_total, moe_expert_rows)
+            self._count_device_work()
+            out.update(self.runner.module.counter_stats(
+                self.model_cfg, self.step_counter_totals
+            ))
         # first dispatches of step-program shapes (runner._dispatch): how
         # many, their wall seconds, and the split trace / lower / compile
         # (or cache load) / run (first execution and the rest)

@@ -351,21 +351,32 @@ class ModelRunner:
                     f"model family {family!r} advances its recurrent state "
                     "inside the deferred decode burst: kv_write_mode='post'"
                 )
-            from production_stack_tpu.ops.pallas.ssm_scan import resolve_ssm_impl
+            if hasattr(cfg, "ssm_impl"):
+                # a family whose state is a selective scan's (models/jamba.py);
+                # one whose only state is a convolution's tail has no scan
+                # (models/lfm2.py) and ``ssm_impl`` stays ""
+                from production_stack_tpu.ops.pallas.ssm_scan import (
+                    resolve_ssm_impl,
+                )
 
-            self.ssm_impl, self.ssm_reason = (
-                resolve_ssm_impl(jax.default_backend())
-                if cfg.ssm_impl == "auto" else (cfg.ssm_impl, "requested")
-            )
-            cfg = dataclasses.replace(cfg, ssm_impl=self.ssm_impl)
-            self.cfg = cfg
+                self.ssm_impl, self.ssm_reason = (
+                    resolve_ssm_impl(jax.default_backend())
+                    if cfg.ssm_impl == "auto" else (cfg.ssm_impl, "requested")
+                )
+                cfg = dataclasses.replace(cfg, ssm_impl=self.ssm_impl)
+                self.cfg = cfg
             self.state_slots = int(state_slots or max_batch or 8)
             logger.info(
-                "selective scan: %s%s; %d state slots of %d bytes",
-                self.ssm_impl,
-                f" ({self.ssm_reason})" if self.ssm_reason else "",
+                "recurrent state: %d slots of %d bytes; selective scan: %s%s",
                 self.state_slots, cfg.state_bytes_per_slot,
+                self.ssm_impl or "none",
+                f" ({self.ssm_reason})" if self.ssm_reason else "",
             )
+        # int32 counters of what the device did in a dispatch (models/lfm2.py:
+        # what the expert layers routed): a last output of every step program,
+        # its host copy started at the dispatch and read by take_counters()
+        self.num_counters = int(getattr(cfg, "step_counters", 0))
+        self._counters: list = []
 
         if self.kv_quant:
             fwd_params = inspect.signature(self.module.forward).parameters
@@ -540,6 +551,26 @@ class ModelRunner:
             self.state, s["state_slots"],
         )
 
+    def _keep_counters(self, out: tuple) -> tuple:
+        """Take a dispatch's counters off the end of its result and start
+        their copy to the host: they arrive with the tokens."""
+        if not self.num_counters:
+            return out
+        *out, counters = out
+        counters.copy_to_host_async()
+        self._counters.append(counters)
+        return tuple(out)
+
+    def take_counters(self):
+        """Sum (int64 [num_counters]) of the counters of the dispatches that
+        have ended since the last call, in order; those still running stay
+        for the next call. None: nothing ended, or the family counts nothing."""
+        total = None
+        while self._counters and self._counters[0].is_ready():
+            c = np.asarray(self._counters.pop(0), np.int64)
+            total = c if total is None else total + c
+        return total
+
     def _jit(self, program, donate: tuple, outs: tuple):
         """``jax.jit`` a step program and remember how: what comes back from
         the store is jitted the same way (_step_program)."""
@@ -699,6 +730,8 @@ class ModelRunner:
             if self.has_state:
                 outs = outs + (n,)    # the state pool, updated in place
                 donate = (1, 2, 16)   # it rides at arg 16, its slots at 17
+            if self.num_counters:
+                outs = outs + (rep,)
             self._steps[sig] = self._jit(
                 _named_program(
                     "pstpu_step" + _flags(want_lp, want_pen),
@@ -727,6 +760,7 @@ class ModelRunner:
             self._get_step(want_logprobs, want_pen), "step",
             (want_logprobs, want_pen), s, args,
         )
+        out = self._keep_counters(out)
         if self.has_state:
             *out, self.state = out
         if self.kv_quant:
@@ -785,6 +819,8 @@ class ModelRunner:
                 # pool is the burst scan's carry
                 outs = outs + (n,)
                 donate = (1, 2, 17)
+            if self.num_counters:
+                outs = outs + (rep,)
             self._multi_steps[sig] = self._jit(
                 _named_program(
                     f"pstpu_multi_step_k{k}" + _flags(want_logprobs, want_pen),
@@ -801,7 +837,9 @@ class ModelRunner:
         if self.kv_quant:
             args = args + ((self.k_scales, self.v_scales),)
         args = self._with_state(args, s, 17)
-        out = self._dispatch(self._multi_steps[sig], "multi_step", sig, s, args)
+        out = self._keep_counters(
+            self._dispatch(self._multi_steps[sig], "multi_step", sig, s, args)
+        )
         if self.has_state:
             *out, self.state = out
         if self.kv_quant:
@@ -1426,6 +1464,13 @@ class ModelRunner:
             return 0
         return (self.state_slots + 1) * self.cfg.state_bytes_per_slot
 
+    @property
+    def conv_state_bytes(self) -> int:
+        """Bytes of the convolution tails in the state pool, the null slot
+        included (0: the family keeps none)."""
+        conv = (self.state or {}).get("conv")
+        return 0 if conv is None else int(conv.nbytes)
+
     def drop_kv_pools(self) -> None:
         """Release the KV pools' device memory (sleep level 1+)."""
         self.k_pages = None
@@ -1559,6 +1604,17 @@ def _scales_pools(num_layers: int, num_pages: int, num_kv_heads: int):
     )
 
 
+def _split_counters(cfg, out: tuple):
+    """A family whose configuration names ``step_counters`` returns that many
+    int32 (what THIS call did, not a running sum) as the last element of its
+    ``forward``'s result, whatever else the result holds: (the rest, the
+    counters or None)."""
+    if not getattr(cfg, "step_counters", 0):
+        return out, None
+    *out, counters = out
+    return tuple(out), counters
+
+
 def _multi_step_fn(forward, cfg, k, want_lp, want_pen, params, k_pages,
                    v_pages, input_ids, positions, page_table, kv_lens,
                    kv_limits, temperature, top_k, top_p, key, lora=None,
@@ -1669,7 +1725,8 @@ def _multi_step_deferred_fn(forward, cfg, k, want_lp, want_pen, params,
 
     A family with recurrent state (``state``: its pool, ``state_slots``: the
     rows' slots) advances it inside the burst: the pool is a carry of the
-    scan, updated in place by every step, and comes back as the last output."""
+    scan, updated in place by every step, and comes back behind the page
+    pools (with the burst's counters last, where the family counts)."""
     B = input_ids.shape[0]
     L, _, page_size, KH, D = k_pages.shape
     C = k
@@ -1693,19 +1750,19 @@ def _multi_step_deferred_fn(forward, cfg, k, want_lp, want_pen, params,
         hist0 = jnp.zeros((B, 1), jnp.int32)  # inert carry
 
     def body(carry, key_i):
-        ids, pos, lens, counts, ka, va, hist, st = carry
+        ids, pos, lens, counts, ka, va, hist, st, work = carry
+        # rows that ran out of budget (pos -1) leave their state as it is
+        more = {} if st is None else {"state": st, "state_slots": state_slots}
+        out, did = _split_counters(cfg, forward(
+            params, cfg, ids, pos, k_pages, v_pages, page_table, lens,
+            kv_burst=(ka, va, counts), **more, **kw
+        ))
         if st is None:
-            logits, ka_new, va_new = forward(
-                params, cfg, ids, pos, k_pages, v_pages, page_table, lens,
-                kv_burst=(ka, va, counts), **kw
-            )
+            logits, ka_new, va_new = out
         else:
-            # rows that ran out of budget (pos -1) leave their state as it is
-            logits, ka_new, va_new, st = forward(
-                params, cfg, ids, pos, k_pages, v_pages, page_table, lens,
-                kv_burst=(ka, va, counts), state=st, state_slots=state_slots,
-                **kw
-            )
+            logits, ka_new, va_new, st = out
+        if did is not None:
+            work = work + did
         with jax.named_scope("sample"):
             sample_from = logits
             if want_pen:
@@ -1739,14 +1796,16 @@ def _multi_step_deferred_fn(forward, cfg, k, want_lp, want_pen, params,
         pos = jnp.where(active, pos[:, 0] + 1, -1)[:, None]
         lens = lens + active.astype(lens.dtype)
         ids = jnp.where(active, nxt, 0)[:, None]
-        return (ids, pos, lens, counts, ka, va, hist, st), emit
+        return (ids, pos, lens, counts, ka, va, hist, st, work), emit
 
-    (_, _, _, counts_f, k_acc, v_acc, hist_f, state), emitted = jax.lax.scan(
+    n_work = getattr(cfg, "step_counters", 0)
+    (_, _, _, counts_f, k_acc, v_acc, hist_f, state, work), emitted = jax.lax.scan(
         body,
-        (input_ids, positions, kv_lens, counts, k_acc, v_acc, hist0, state),
+        (input_ids, positions, kv_lens, counts, k_acc, v_acc, hist0, state,
+         jnp.zeros((n_work,), jnp.int32) if n_work else None),
         keys,
     )
-    tail = () if state is None else (state,)
+    tail = (() if state is None else (state,)) + (() if work is None else (work,))
     toks = emitted[0] if want_lp else emitted
     # one commit for the whole burst: window entry j of row b holds the
     # token at absolute position pos0 + j (valid for j < counts_f)
@@ -1899,26 +1958,19 @@ def _step_fn(forward, cfg, want_lp, want_pen, params, k_pages, v_pages,
              kv_scales=None, state=None, state_slots=None):
     kw = {} if lora is None else {"lora": lora, "lora_ids": lora_ids}
     key = jax.random.wrap_key_data(key)
-    quant = kv_scales is not None
-    tail = ()  # what follows the page pools in the result
-    if quant:
+    if kv_scales is not None:
         kw["kv_scales"] = kv_scales
-        logits, k_pages, v_pages, k_sc, v_sc = forward(
-            params, cfg, input_ids, positions, k_pages, v_pages, page_table,
-            kv_lens, **kw,
-        )
-        tail = (k_sc, v_sc)
-    elif state is not None:
-        logits, k_pages, v_pages, state = forward(
-            params, cfg, input_ids, positions, k_pages, v_pages, page_table,
-            kv_lens, state=state, state_slots=state_slots, **kw,
-        )
-        tail = (state,)
-    else:
-        logits, k_pages, v_pages = forward(
-            params, cfg, input_ids, positions, k_pages, v_pages, page_table,
-            kv_lens, **kw,
-        )
+    if state is not None:
+        kw.update(state=state, state_slots=state_slots)
+    out, did = _split_counters(cfg, forward(
+        params, cfg, input_ids, positions, k_pages, v_pages, page_table,
+        kv_lens, **kw,
+    ))
+    # what follows the page pools in the result: the scales of int8 pools or
+    # the state pool, then the counters
+    logits, k_pages, v_pages, *tail = out
+    if did is not None:
+        tail.append(did)
     with jax.named_scope("sample"):
         sample_from = logits
         if want_pen:

@@ -195,6 +195,7 @@ class Scheduler:
         batch_multiple: int = 1,
         decode_steps: int = 1,
         decode_pipeline: int = 1,
+        decode_page_bucket_floor: int = 0,
         spec_k: int = 0,
         spec_ngram: int = 3,
         max_waiting_seqs: int = 0,
@@ -222,6 +223,15 @@ class Scheduler:
         # waiting work): m bursts cost m*compute + 1 fetch round trip instead
         # of m of each (runner.step_multi_pipelined)
         self.decode_pipeline = max(1, decode_pipeline)
+        # narrowest page table a decode dispatch is padded to, as one of the
+        # buckets and never wider than max_model_len's own; 0 = none. The
+        # engine gives max_model_len's pages for a family whose decode reads
+        # the padding for next to nothing (``decode_one_page_width``): ONE
+        # width, so the decode programs differ by batch bucket alone
+        self.decode_page_bucket_floor = min(
+            _bucket(decode_page_bucket_floor, self.PAGE_BUCKETS),
+            _bucket(self._pages_needed(max_model_len + 1), self.PAGE_BUCKETS),
+        ) if decode_page_bucket_floor > 0 else 0
         self.spec_k = max(0, spec_k)
         self.spec_ngram = max(1, spec_ngram)
         # admission control (overload survival, docs/failure-handling.md):
@@ -850,10 +860,10 @@ class Scheduler:
         if not ready:
             return None
         B = self._batch_bucket(len(ready))
-        max_pages = _bucket(
+        max_pages = max(self.decode_page_bucket_floor, _bucket(
             max(self._pages_needed(self._decode_target_len(s, bursts)) for s in ready),
             self.PAGE_BUCKETS,
-        )
+        ))
         input_ids = np.zeros((B, 1), np.int32)
         positions = np.full((B, 1), -1, np.int32)
         page_table = np.zeros((B, max_pages), np.int32)

@@ -350,3 +350,41 @@ def paged_attention_decode(
         kv_positions=kv_positions,
     )
     return out[:, 0]
+
+
+def burst_attention(q, kc, vc, k_win, v_win, kv_pos, q_pos, KH: int):
+    """Decode attention of one token a row, XLA path, for pools that store a
+    token's kv heads SIDE BY SIDE in one row of KH * D lanes (a head_dim
+    under 128 lanes, which ``runner.kernel_refusal`` keeps off the kernels:
+    models/lfm2.py). ``q`` [B, 1, NH, D]; ``kc`` / ``vc`` [B, S, KH * D] the
+    row's gathered pages; ``k_win`` / ``v_win`` [B, C, KH * D] the burst's
+    window; ``kv_pos`` [B, S + C] is ``burst_kv_positions``; ``q_pos`` [B,
+    1]. Softmax over pages and window together, each read ONCE and never
+    relaid out: a query head meets the whole row through a copy of itself
+    that is zero outside its own kv head's D lanes (KH times the products,
+    which are nothing beside the bytes). ``flash_attention`` concatenates,
+    pads, blocks and transposes its keys and values: seven passes over a
+    context that a [64, 4096] bucket makes 0.27 GB a layer (PERF.md section
+    6, PR 46). Returns [B, 1, NH, D]."""
+    B, _, NH, D = q.shape
+    S = kc.shape[1]
+    f32 = jnp.float32
+    own = (jnp.arange(NH)[:, None] // (NH // KH) == jnp.arange(KH)[None, :])
+    own = own[None, :, :, None]                                  # [1, NH, KH, 1]
+    wide = jnp.where(own, (q[:, 0].astype(f32) * D**-0.5)[:, :, None, :], 0.0)
+    wide = wide.reshape(B, NH, KH * D).astype(kc.dtype)
+    seen = ((kv_pos >= 0) & (kv_pos <= q_pos[:, :1]))[:, None, :]
+    scores = jnp.concatenate([
+        jnp.einsum("bhc,bsc->bhs", wide, kc, preferred_element_type=f32),
+        jnp.einsum("bhc,bsc->bhs", wide, k_win, preferred_element_type=f32),
+    ], axis=-1)
+    scores = jnp.where(seen, scores, NEG_INF)
+    p = jnp.where(seen, jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True)), 0.0)
+    out = (
+        jnp.einsum("bhs,bsc->bhc", p[..., :S].astype(vc.dtype), vc,
+                   preferred_element_type=f32)
+        + jnp.einsum("bhs,bsc->bhc", p[..., S:].astype(vc.dtype), v_win,
+                     preferred_element_type=f32)
+    ) / jnp.maximum(jnp.sum(p, axis=-1), 1e-30)[..., None]
+    out = jnp.sum(jnp.where(own, out.reshape(B, NH, KH, D), 0.0), axis=2)
+    return out[:, None].astype(q.dtype)

@@ -75,6 +75,14 @@ _LAYER_SPECS = {
     "a_log": P(None, None),
     "d_skip": P(None),
     "out_proj": P(None, None),
+    # LFM2-MoE (models/lfm2.py): replicated too, one chip; the experts lie
+    # [E, ...] under their layer (experts spread over chips: ROADMAP M1)
+    "q_norm": P(None),
+    "k_norm": P(None),
+    "router": P(None, None),
+    "expert_bias": P(None),
+    "w13": P(None, None, None),
+    "w2": P(None, None, None),
 }
 
 # [L, P, page_size, KH, D] pools: shard kv heads over tp.

@@ -159,10 +159,11 @@ def _step_program(cfg, *, tp, B, T, max_pages, num_pages, page_size,
         lambda s, spec: on_mesh(s.shape, s.dtype, spec),
         shapes, shardings.param_specs_for(shapes),
     )
-    pool = on_mesh(
-        (cfg.num_kv_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim),
-        cfg.dtype, shardings.KV_PAGES_SPEC,
-    )
+    # the pool in the family's own shape (models/lfm2.py merges the kv heads)
+    pool = jax.eval_shape(
+        lambda: module.init_kv_pages(cfg, num_pages, page_size)
+    )[0]
+    pool = on_mesh(pool.shape, pool.dtype, shardings.KV_PAGES_SPEC)
     forward = (
         functools.partial(module.forward, mesh=mesh) if tp > 1 else module.forward
     )
@@ -198,6 +199,8 @@ def _step_program(cfg, *, tp, B, T, max_pages, num_pages, page_size,
     if state:
         args = args + (None,) * (scales_at - len(args)) + state
         outs, donate = outs + (None,), (1, 2, scales_at)
+    if getattr(cfg, "step_counters", 0):
+        outs = outs + (rep,)  # the dispatch's counters, fetched with the tokens
     kw = {"donate_argnums": donate, "out_shardings": outs}
     return jax.jit(program, **kw), kw, args
 
@@ -303,6 +306,13 @@ STEP_PROGRAMS = {
         preset="jamba2-3b", B=64, max_pages=64, num_pages=4096, decode_steps=8),
     "jamba2-3b.chat/prefill-b4xt512": dict(
         preset="jamba2-3b", B=4, T=512, max_pages=64, num_pages=4096),
+    # sparse experts + convolution tails, all 16 layers (two scans): the
+    # 10.8 GB of weights and the 2 GB pool have to fit beside the temporaries
+    "lfm2-8b-a1b-d16.chat/burst-b32xp64": dict(
+        preset="lfm2-8b-a1b-d16", B=32, max_pages=64, num_pages=4096,
+        decode_steps=8),
+    "lfm2-8b-a1b-d16.chat/prefill-b4xt512": dict(
+        preset="lfm2-8b-a1b-d16", B=4, T=512, max_pages=64, num_pages=4096),
 }
 
 # the selective scan at the shapes the jamba2-3b.chat cell dispatches
@@ -358,10 +368,9 @@ def _attempt(fn, **kw) -> dict:
 
 
 def run_matrix(slow: bool = False) -> dict:
+    from production_stack_tpu import models
     from production_stack_tpu.engine import runner
-    from production_stack_tpu.models import llama
-
-    from production_stack_tpu.models import jamba
+    from production_stack_tpu.models import jamba, lfm2
 
     out: dict = {"decode": {}, "prefill": {}, "prefill_refused": {},
                  "smem": {}, "step_programs": {}, "ssm_scan": {}}
@@ -399,8 +408,8 @@ def run_matrix(slow: bool = False) -> dict:
             kw = dict(kw)
             tp = kw.get("tp", 1)
             name = kw.pop("preset")
-            recurrent = name in jamba.PRESETS
-            preset = (jamba if recurrent else llama).PRESETS[name]
+            module, preset = models.find_preset(name)
+            recurrent = hasattr(module, "init_state")
             # depth 2 (the layer scan compiles one layer), the attention
             # path resolved as the engine resolves it on the chip
             attn = runner.resolve_attn_impl(
@@ -410,8 +419,13 @@ def run_matrix(slow: bool = False) -> dict:
                 head_dim=preset.head_dim, tp=tp, pool_itemsize=2,
                 max_batch=kw["B"], max_pages=kw["max_pages"],
             )
-            cfg = dataclasses.replace(preset, num_layers=2, attn_impl=attn.impl)
-            if recurrent:
+            if module is lfm2:
+                # its whole depth: the scans compile each body once
+                cfg = dataclasses.replace(
+                    preset, attn_impl=attn.impl, moe_impl="pallas")
+            else:
+                cfg = dataclasses.replace(preset, num_layers=2, attn_impl=attn.impl)
+            if module is jamba:
                 # one period S S A S: both kinds of layer, two scanned runs
                 cfg = dataclasses.replace(
                     cfg, num_layers=4, attn_layer_period=4, attn_layer_offset=2,

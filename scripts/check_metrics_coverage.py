@@ -100,6 +100,10 @@ DASHBOARD_ALLOWLIST = {
     "vllm:ssm_state_bytes",                  # these; GET /stats shows them, no
     "vllm:ssm_prefill_tokens_total",         # dashboard and no benchmark
     "vllm:ssm_decode_tokens_total",          # reader reads them yet
+    "vllm:conv_state_bytes",                 # the same families' conv tails
+    "vllm:moe_routed_rows_total",            # sparse experts (models/lfm2.py):
+    "vllm:moe_expert_reads_total",           # the benchmark reads them from
+    "vllm:moe_expert_slots_total",           # GET /stats; no dashboard yet
     "vllm:decode_dispatches_total",          # dispatch-shape bench telemetry
     "vllm:decode_chained_dispatches_total",
     "vllm:runahead_prefill_dispatches_total",

@@ -125,7 +125,7 @@ def test_step_program_compiles_through_the_store_with_its_pools_donated(tier1, p
     donated = r["pool_bytes"] + r["state_bytes"]
     assert 0 < donated <= r["alias_bytes"] <= 1.01 * donated
     assert r["alias_bytes"] == donated or r["state_bytes"]
-    assert (r["state_bytes"] > 0) == pid.startswith("jamba")
+    assert (r["state_bytes"] > 0) == pid.startswith(("jamba", "lfm2"))
     assert r["blob_bytes"] < 256 << 10
 
 

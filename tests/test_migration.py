@@ -568,6 +568,12 @@ def test_greedy_continuation_bit_identical_across_cpu_engines(tmp_path):
     from production_stack_tpu.engine.config import EngineConfig
     from production_stack_tpu.engine.engine import LLMEngine
     from production_stack_tpu.engine.scheduler import SamplingParams
+    from production_stack_tpu.tracing.flightrecorder import (
+        configure_flightrecorder,
+        get_flightrecorder,
+    )
+
+    was_enabled = get_flightrecorder().enabled
 
     def mk():
         cfg = EngineConfig(
@@ -658,6 +664,10 @@ def test_greedy_continuation_bit_identical_across_cpu_engines(tmp_path):
     finally:
         A.stop()
         B.stop()
+        # ``flight_recorder=False`` switched the PROCESS-GLOBAL recorder off:
+        # leave it as the next test of this worker expects it (tests/
+        # test_step_program_store.py reads its first_dispatch events)
+        configure_flightrecorder(enabled=was_enabled)
 
 
 @pytest.mark.slow  # ~30 s: 2 subprocess engines + router SSE splice;

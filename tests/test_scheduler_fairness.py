@@ -5,6 +5,7 @@ multi-round-qa workload measured 64-token answers taking ~40 s). Chunked
 prefill exists precisely so decode latency survives long prompts."""
 
 import numpy as np
+import pytest
 
 from production_stack_tpu.engine.kv_manager import KVPageManager
 from production_stack_tpu.engine.scheduler import (
@@ -297,3 +298,32 @@ def test_small_decode_batch_short_backlog_keeps_strict_priority():
                        params=SamplingParams(max_tokens=4, ignore_eos=True)))
     batch = sched.schedule()
     assert batch.kind == "prefill"  # 24 < 2*16 backlog, demand 1 < 2
+
+
+@pytest.mark.parametrize("floor, widths", [
+    (0, {2, 4}),      # the scheduler's own buckets follow the contexts
+    (16, {16}),       # one width whatever the contexts
+    (24, {32}),       # rounded up to a bucket
+    (4096, {128}),    # never wider than max_model_len's own bucket
+], ids=["none", "floor-16", "rounds-up", "capped"])
+def test_decode_page_bucket_floor_leaves_one_width(floor, widths):
+    """--decode-page-bucket-floor pads every decode dispatch's page table to
+    one width, so the decode programs differ by batch bucket alone; prefill
+    dispatches keep the scheduler's buckets (their attention pays for the
+    width), and 0 changes nothing."""
+    sched = _mk_scheduler(decode_pipeline=1, decode_page_bucket_floor=floor)
+    # a long row that ends with the first burst, a short one that goes on
+    for i, (n, out) in enumerate(((24, 3), (4, 8))):
+        sched.add(Sequence(f"s{i}", prompt_ids=[1] * n,
+                           params=SamplingParams(max_tokens=out, ignore_eos=True)))
+    seen = {"prefill": set(), "decode": set()}
+    for _ in range(16):
+        batch = sched.schedule()
+        if batch is None:
+            break
+        seen[batch.kind].add(batch.page_table.shape[1])
+        shape = ((len(batch.kv_lens),) if batch.kind == "prefill"
+                 else (len(batch.kv_lens), sched.decode_steps * batch.bursts))
+        sched.apply_step(batch, np.full(shape, 7, np.int32), eos_token_id=-1)
+    assert seen["decode"] == widths
+    assert seen["prefill"] <= {2, 4}
