@@ -906,6 +906,20 @@ class EngineServer:
         emit("decode_kv_tokens_read_total", "counter",
              s.get("decode_kv_tokens_read_total", 0),
              "KV tokens the decoded tokens attended (min(context, window) each)")
+        # one dispatch queued behind the one that runs (engine._turn): how
+        # many went out that way, and what had emptied the loop for the rest
+        for name, label, help_ in (
+            ("queued_ahead_dispatches_total", "kind",
+             "dispatches enqueued while the one before them still ran"),
+            ("queue_ahead_drains_total", "reason",
+             "dispatches that found the device idle, by what emptied the loop"),
+        ):
+            lines.append(f"# HELP vllm:{name} {help_}")
+            lines.append(f"# TYPE vllm:{name} counter")
+            for key, n in sorted(s.get(name, {}).items()):
+                lines.append(
+                    f'vllm:{name}{{model_name="{m}",{label}="{key}"}} {n}'
+                )
         if "ssm_state_slots" in s:
             # a family with recurrent state beside its pages (models/jamba.py)
             emit("ssm_state_slots", "gauge", s["ssm_state_slots"],

@@ -10,13 +10,16 @@ the reference stack treats as a black box (SURVEY.md §1 L4 contract).
 from __future__ import annotations
 
 import asyncio
+import functools
 import os
 import dataclasses
 import queue as queue_mod
 import threading
 import time
-from typing import AsyncIterator, Optional
+from typing import Any, AsyncIterator, Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from production_stack_tpu import tracing
@@ -48,9 +51,9 @@ class _LoopSection:
     each fetched group while later bursts still compute): what they book while
     another section is open is taken off that section, so ``wait``,
     ``schedule``, ``step``, ``apply`` and ``emit`` are disjoint and sum to the
-    loop's wall. ``stage``, ``runahead``, ``chain_dispatch`` and
-    ``chain_fetch`` are parts of ``step``: they nest inside it and it keeps
-    their seconds."""
+    loop's wall. ``stage``, ``call``, ``fetch``, ``hold``, ``runahead``,
+    ``chain_dispatch`` and ``chain_fetch`` are parts of ``step``: they nest
+    inside it and it keeps their seconds."""
 
     __slots__ = ("_secs", "_name", "_span", "_t0", "_inner0", "seconds")
 
@@ -88,6 +91,51 @@ def _kv_tokens_read(kv_len, steps, window) -> int:
         n_over = np.maximum(last - over_first + 1, 0)
         total = total - n_over * ((over_first - window) + (last - window)) // 2
     return int(total.sum())
+
+
+@dataclasses.dataclass
+class _Dispatched:
+    """A dispatch the device has and the host has not read yet."""
+
+    batch: ScheduledBatch
+    result: Any    # device tokens: [B, k] of a decode burst, [B] of a prefill step
+    work: dict     # what it computes, for the flight recorder's ``step`` event
+    step: int      # its step index
+    t0: float      # when it was enqueued (perf_counter)
+    queued: bool   # behind one that still ran
+    first: bool    # its shape's first dispatch: built and timed to its result
+    pinned: bool = True  # its rows still hold what the manager lent them for it
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _last_tokens(result, width: int):
+    """[width] int32: each row's last token of a dispatch's result, padded
+    with zeros (one program a result shape, whatever is fed from it)."""
+    last = result[:, -1] if result.ndim == 2 else result
+    return jnp.pad(last.astype(jnp.int32), (0, width - last.shape[0]))
+
+
+@jax.jit
+def _fed_ids(host_ids, fed_from, last):
+    """The input tokens of a decode queued behind a running dispatch:
+    ``last[fed_from]`` where a row is fed from it, the host's elsewhere."""
+    fed = last[jnp.maximum(fed_from, 0)][:, None]
+    return jnp.where(fed_from[:, None] >= 0, fed, host_ids)
+
+
+def _placed_like_numpy(x):
+    """``x``'s buffer as an array with no placement of its own: a jitted
+    program takes it under the executable it built for the host's numpy input.
+    (A committed array is another in-sharding to ``jax.jit``: every step
+    program would compile a second time the first time it is fed from the
+    device.) One device only; jax 0.9.0 has no public constructor for it."""
+    from jax._src.array import ArrayImpl
+
+    (device,) = x.sharding.device_set
+    return ArrayImpl(
+        jax.core.ShapedArray(x.shape, x.dtype),  # no mesh in its type either
+        jax.sharding.SingleDeviceSharding(device), x._arrays, committed=False,
+    )
 
 
 @dataclasses.dataclass
@@ -611,6 +659,27 @@ class LLMEngine:
         # prefill dispatches whose results were never fetched (skip-fetch
         # optimization); a deferred device error taints these sequences
         self._unfetched: list = []
+        # the dispatch the device has and the loop has not read yet: the loop
+        # plans and enqueues the next one behind it, THEN reads it (_turn)
+        self._inflight: Optional[_Dispatched] = None
+        # device commands that arrived while a dispatch was in flight: they
+        # run once the loop has drained (and nothing after them is read from
+        # the inbox before they do)
+        self._held_cmds: list = []
+        # why nothing was running when the next dispatch goes out
+        self._drain_reason = "idle"
+        self._last_retire = 0.0
+        # what the loop has measured of itself, to enqueue the next dispatch
+        # as LATE as is safe (_hold_back): the device's seconds for a step
+        # program's shape, and the host's from planning a dispatch to having
+        # enqueued it
+        self._device_secs: dict[tuple, float] = {}
+        self._turn_secs = 0.004
+        self._turn_t0 = 0.0
+        # result shapes whose joining helpers are built (_enqueue)
+        self._seams_built: set = set()
+        # widest batch a step program returns: what _last_tokens pads to
+        self._feed_width = self.scheduler._batch_bucket(cfg.max_num_seqs)
         # two-writer maps (event-loop generate() registers/pops, device
         # thread _emit/_process_token reads/writes): every touch goes
         # through _lock — graftcheck GC004 enforces the discipline
@@ -633,6 +702,15 @@ class LLMEngine:
         # (run-ahead): the device queued them behind the chain instead of
         # idling through its fetch + scheduling turnaround
         self.runahead_prefill_dispatches_total = 0
+        # dispatches enqueued while the one before them still ran, by kind,
+        # and those that found the device idle, by what had emptied the loop
+        # (docs/observability.md lists the reasons)
+        self.queued_ahead_dispatches = {"decode": 0, "prefill": 0}
+        self.queue_ahead_drains = dict.fromkeys((
+            "idle", "late", "first_dispatch", "device_cmd", "host_staged_rows",
+            "no_pages", "chained", "speculative", "multi_process",
+            "kv_transfer", "step_error",
+        ), 0)
         # work per decode dispatch, counted from the batch the scheduler
         # built (host numpy, no device read): KV tokens the decoded tokens
         # attend (min(context, sliding window) each — what a decode-attention
@@ -696,7 +774,8 @@ class LLMEngine:
         self.loop_seconds = {
             "wait": 0.0, "schedule": 0.0, "step": 0.0, "apply": 0.0,
             "emit": 0.0, "chain_dispatch": 0.0, "chain_fetch": 0.0,
-            "stage": 0.0, "runahead": 0.0,
+            "stage": 0.0, "runahead": 0.0, "fetch": 0.0, "hold": 0.0,
+            "call": 0.0,
         }
         # the runner books its host->device staging into the same accounting
         self.runner.section = self._section
@@ -1207,17 +1286,24 @@ class LLMEngine:
 
     # -- engine loop (device thread) ----------------------------------------
 
-    def _drain_inbox(self, block: bool, defer_aborts: bool = False) -> list:
+    def _drain_inbox(self, block: bool, defer_aborts: bool = False,
+                     timeout: float = 0.5) -> list:
         """Drain queued arrivals/aborts/device commands. With
         ``defer_aborts`` (mid-chain run-ahead), aborts are RETURNED instead
         of applied: an abort frees the sequence's pages, and a page freed
         while a dispatched-but-unfetched chain still writes to it must not
         be reallocated to a run-ahead admission. The caller re-queues them
         once the chain has been applied (aborts are idempotent and
-        order-independent — abort of an already-finished seq is a no-op)."""
+        order-independent — abort of an already-finished seq is a no-op).
+
+        With a dispatch in flight (the loop's ordinary state, _turn) an abort
+        is applied at once: the row is finished and answered, and what the
+        manager lent it goes back when the last dispatch that names it has
+        retired (Scheduler.pin / retire). A device command is HELD there, and
+        nothing behind it is read, until the loop has drained."""
         deferred: list = []
-        timeout = 0.5 if block else None
-        while True:
+        timeout = timeout if block else None
+        while not self._held_cmds:
             try:
                 item = self._inbox.get(block=block, timeout=timeout)
             except queue_mod.Empty:
@@ -1226,6 +1312,9 @@ class LLMEngine:
             if item is None:
                 return deferred
             if isinstance(item, tuple) and item[0] == "device_cmd":
+                if self._inflight is not None:
+                    self._held_cmds.append(item[1])
+                    continue
                 item[1]()  # LoRA update / embed forward, serialized with steps
             elif isinstance(item, tuple) and item[0] == "abort":
                 if defer_aborts:
@@ -1248,6 +1337,7 @@ class LLMEngine:
                         self._emit(s, "")
             else:
                 self._inbox_accept(item)
+        return deferred
 
     def _inbox_accept(self, seq: Sequence) -> None:
         self._arrival_times.append(time.monotonic())
@@ -1279,6 +1369,12 @@ class LLMEngine:
         sched.add(seq)
 
     def _run_loop(self) -> None:
+        """One dispatch is queued on the device behind the one that runs: each
+        pass plans the next dispatch from the state the running one WILL
+        leave, enqueues it, and only then reads the running one's tokens,
+        applies and streams them (_turn) — the host's turn for dispatch N
+        happens while N+1 computes. A batch that needs the host between two
+        steps runs with nothing in flight, as it always did."""
         logger.info("engine loop started (model=%s)", self.cfg.name)
         while not self._stop.is_set():
             if self._sleeping:
@@ -1286,7 +1382,12 @@ class LLMEngine:
                 self._drain_inbox(block=False)
                 continue
             with self._section("wait"):
-                self._drain_inbox(block=not self.scheduler.has_work())
+                if self._inflight is None:
+                    while self._held_cmds:  # the loop has drained: run them
+                        self._held_cmds.pop(0)()
+                self._drain_inbox(
+                    block=self._inflight is None and not self.scheduler.has_work()
+                )
                 self._shed_expired()  # queue-deadline load shedding
                 if self.warm is not None:
                     # periodic warm-start manifest (crash protection): prefers
@@ -1302,98 +1403,321 @@ class LLMEngine:
                     time.monotonic() - self._arrival_times[-1]
                     if self._arrival_times else float("inf")
                 )
+            if self._inflight is not None:
+                with self._section("step"), self._section("hold"):
+                    self._hold_back()
+            self._turn_t0 = time.perf_counter()
             with self._section("schedule"):  # the scheduler's decision alone
-                batch = self.scheduler.schedule()
-            if batch is None:
-                continue
-            # apply/emit seconds booked inside the dispatch (incremental
-            # chained fetch) are taken off the enclosing sections, so wait +
-            # schedule + step + apply + emit stay disjoint and sum to the
-            # loop's wall (_LoopSection)
-            step = self._section(
-                "step", **(self._dispatch_attrs(batch) if profiler.active() else {})
-            )
-            try:
-                with step:
-                    self._record_sched_event(batch)
-                    if batch.kind == "prefill":
-                        self._note_first_dispatch(batch)
-                    work = self._count_work(batch)
-                    tokens, lp_data, fetched = self._dispatch_batch(batch)
-                    self._count_device_work()
-            except Exception as step_err:
-                logger.exception("engine step failed; aborting batch")
-                self.step_errors_total += 1
-                if (
-                    isinstance(step_err, ProgramBuildError)
-                    and self.program_fault is None
-                ):
-                    self.program_fault = str(step_err)[:2000]
-                    logger.critical(
-                        "a step program failed to build; /health now "
-                        "answers 503: %s", self.program_fault,
-                    )
-                # postmortem: the window of scheduler/KV/compile events that
-                # led INTO this failure, while it is still in the ring
-                self._fr.record(
-                    "error", step=self.step_idx, batch_kind=batch.kind,
-                    error=repr(step_err)[:500],
-                )
-                self._fr.dump("engine_step_error", force=True)
-                if self.cfg.distributed_num_processes > 1:
-                    # multi-host: catch-and-continue would leave the leader
-                    # serving while followers are dead or desynced (a broadcast
-                    # happens before local execution). Exit so K8s restarts the
-                    # StatefulSet and the set re-rendezvouses — this enforces
-                    # the documented failure model (distributed.py).
-                    logger.critical(
-                        "fatal in multi-host mode: exiting so the pod set "
-                        "restarts in sync"
-                    )
-                    os._exit(13)
-                # deferred errors from skipped-fetch prefill dispatches
-                # surface here: those sequences' KV is suspect, abort them too
-                suspect = list(batch.seqs)
-                for b in self._unfetched:
-                    suspect.extend(b.seqs)
-                self._unfetched.clear()
-                for s in suspect:
-                    if not s.finished:
-                        self.scheduler._finish(s, "error")
-                        self._emit(s, "", error=True)
-                continue
-            step_wall = step.seconds
-            if self._fr.enabled:
-                # runner step timing, dispatch-granular: a fetched step's
-                # wall is real device time; a skip-fetch dispatch's wall is
-                # enqueue-only (the trailing fetched step absorbs its compute)
-                self._fr.record(
-                    "step", step=self.step_idx, batch_kind=batch.kind,
-                    wall_ms=round(step_wall * 1000, 3), bursts=batch.bursts,
-                    fetched=fetched, **work,
-                )
-            if fetched:
-                self._unfetched.clear()  # a real fetch retires prior dispatches
-                # dispatch-granular prefill-phase observability (the
-                # Grafana prefill panel): chunk latency for FETCHED prefill
-                # dispatches (a skip-fetch dispatch's wall is just enqueue
-                # time — the final fetched chunk absorbs the queued
-                # compute), and decode per-token time while a prefill is
-                # resident (the interleave the demand gate schedules)
-                if batch.kind == "prefill":
-                    tracing.prefill_chunk_hist.observe(step_wall)
-                elif batch.kind == "decode" and any(
-                    s.in_prefill for s in self.scheduler.running
-                ):
-                    toks_n = max(
-                        1, self.scheduler.decode_steps * batch.bursts
-                    )
-                    tracing.interleaved_decode_hist.observe(
-                        step_wall / toks_n
-                    )
-            if tokens is not None:
-                self._apply_and_emit(batch, tokens, lp_data)
+                batch = self._plan()
+            if batch is not None or self._inflight is not None:
+                self._turn(batch)
         logger.info("engine loop exited")
+
+    @staticmethod
+    def _shape(batch) -> tuple:
+        return batch.kind, batch.input_ids.shape, batch.page_table.shape
+
+    def _hold_back(self) -> None:
+        """Wait, reading the inbox, until the running dispatch is a margin
+        from its end: the dispatch queued behind it is then planned with the
+        arrivals of nearly the whole of it, and a request waits for one
+        dispatch, not two, before its prefill. The end is reckoned from the
+        device's seconds for the same shape the last time (nothing is held
+        back behind a shape not timed yet); the margin is the loop's own
+        measured turn four times over, a fifth of the dispatch, 10 ms at the
+        least. Too long a hold costs idle time (``late``), never tokens."""
+        running = self._inflight
+        secs = self._device_secs.get(self._shape(running.batch))
+        if secs is None:
+            return
+        margin = max(4 * self._turn_secs, 0.2 * secs, 0.010)
+        enqueue_at = max(running.t0, self._last_retire) + secs - margin
+        while not self._held_cmds and not self._stop.is_set():
+            left = enqueue_at - time.perf_counter()
+            if left <= 0:
+                return
+            self._drain_inbox(block=True, timeout=left)
+
+    def _plan(self) -> Optional[ScheduledBatch]:
+        """The next dispatch: behind the one in flight where there is one
+        (planned from the state it will leave), else from the state as it
+        is. Where nothing can be queued, ``_drain_reason`` keeps why."""
+        sched = self.scheduler
+        if self._inflight is None:
+            batch = sched.schedule()
+            if batch is None:
+                self._drain_reason = "idle"
+            return batch
+        if self._held_cmds:
+            self._drain_reason = "device_cmd"
+            return None
+        batch = sched.schedule(
+            ahead_of=self._inflight.batch, allow=self._runahead_allowed
+        )
+        if batch is None:
+            self._drain_reason = sched.ahead_refusal or "idle"
+        return batch
+
+    def _synchronous(self, batch) -> Optional[str]:
+        """Why ``batch`` runs with nothing queued behind or before it (None:
+        it may be queued). Read from the engine's roles and the batch itself:
+        a mesh whose leader broadcasts before it runs, a producer that ships
+        KV at apply, speculative rounds, a chain, rows whose dispatch is
+        staged from the host's copy of their tokens."""
+        if self.cfg.distributed_num_processes > 1:
+            return "multi_process"
+        if self._kv_sender is not None:
+            return "kv_transfer"
+        if self.scheduler.spec_k:
+            return "speculative"
+        if batch.bursts > 1:
+            return "chained"
+        if batch.want_logprobs or batch.want_penalties or not all(
+            self._runahead_allowed(s) for s in batch.seqs
+        ):
+            return "host_staged_rows"
+        return None
+
+    def _shape_known(self, batch) -> bool:
+        """Whether ``batch``'s step program has been dispatched before (a
+        shape's first dispatch is timed to its result, runner._dispatch: it
+        goes out with nothing in flight)."""
+        k = self.scheduler.decode_steps
+        kind, ids, pages = self._shape(batch)
+        want = (
+            ("multi_step", (k, False, False), ids, pages)
+            if kind == "decode" and k > 1
+            else ("step", (False, False), ids, pages)
+        )
+        return any(key[:4] == want for key in self.runner._programs)
+
+    def _turn(self, batch) -> None:
+        """Enqueue ``batch`` (None: nothing to queue) and retire the dispatch
+        that was in flight, in that order; a failure anywhere makes the rows
+        of both suspect."""
+        running = feeds = self._inflight
+        queued: Optional[_Dispatched] = None
+        step = self._section(
+            "step",
+            **(self._dispatch_attrs(batch)
+               if batch is not None and profiler.active() else {}),
+        )
+        try:
+            with step:
+                why, first = None, False
+                if batch is not None:
+                    why = self._synchronous(batch) if running is None else None
+                    first = why is None and not self._shape_known(batch)
+                    if first and running is not None:
+                        # timed to its result: nothing runs beside it
+                        self._retire(running)
+                        running = None
+                        self._drain_reason = "first_dispatch"
+                    if why is None:
+                        queued = self._enqueue(batch, feeds, running, first)
+                    else:
+                        self._dispatch_now(batch, why)
+                if running is not None:
+                    self._retire(running)
+            self._inflight = queued
+        except Exception as step_err:
+            self._step_failed(step_err, batch, feeds, queued)
+
+    def _step_failed(self, step_err, batch, feeds, queued) -> None:
+        """A turn raised: what failed, what ran before it and what was queued
+        behind it share the pools, so the rows of all of them are finished
+        with ``error``, and so are those of the prefill dispatches nobody
+        fetched."""
+        logger.exception("engine step failed; aborting batch")
+        self.step_errors_total += 1
+        if (
+            isinstance(step_err, ProgramBuildError)
+            and self.program_fault is None
+        ):
+            self.program_fault = str(step_err)[:2000]
+            logger.critical(
+                "a step program failed to build; /health now "
+                "answers 503: %s", self.program_fault,
+            )
+        # postmortem: the window of scheduler/KV/compile events that
+        # led INTO this failure, while it is still in the ring
+        self._fr.record(
+            "error", step=self.step_idx,
+            batch_kind=(batch or feeds.batch).kind,
+            error=repr(step_err)[:500],
+        )
+        self._fr.dump("engine_step_error", force=True)
+        if self.cfg.distributed_num_processes > 1:
+            # multi-host: catch-and-continue would leave the leader
+            # serving while followers are dead or desynced (a broadcast
+            # happens before local execution). Exit so K8s restarts the
+            # StatefulSet and the set re-rendezvouses — this enforces
+            # the documented failure model (distributed.py).
+            logger.critical(
+                "fatal in multi-host mode: exiting so the pod set "
+                "restarts in sync"
+            )
+            os._exit(13)
+        flying = [d for d in (feeds, queued) if d is not None and d.pinned]
+        suspect = list(batch.seqs) if batch is not None else []
+        for b in [d.batch for d in flying] + self._unfetched:
+            suspect.extend(b.seqs)
+        self._unfetched.clear()
+        for s in suspect:
+            if not s.finished:
+                self.scheduler._finish(s, "error")
+                self._emit(s, "", error=True)
+        for d in flying:
+            d.pinned = False
+            self.scheduler.retire(d.batch)
+        self._inflight = None
+        self._drain_reason = "step_error"
+
+    def _count_dispatch(self, batch, reason: Optional[str]) -> None:
+        """One count a dispatch: queued ahead (``reason`` None), or what had
+        emptied the loop before it; the flight recorder's ``sched`` event
+        carries the same."""
+        if reason is None:
+            self.queued_ahead_dispatches[batch.kind] += 1
+        else:
+            self.queue_ahead_drains[reason] += 1
+        self._record_sched_event(
+            batch, queued_ahead=reason is None, drain=reason
+        )
+        if batch.kind == "prefill":
+            self._note_first_dispatch(batch)
+
+    def _enqueue(
+        self, batch, feeds: Optional[_Dispatched],
+        running: Optional[_Dispatched], first: bool,
+    ) -> _Dispatched:
+        """Hand ``batch`` to the device without reading anything back. The
+        rows that ``feeds`` (the dispatch it was planned behind) feeds take
+        their input token from its device-resident result; ``running`` is
+        that dispatch while it has not been retired. The step programs are
+        the ones every other dispatch runs. The first result of a shape has
+        the two helpers that join dispatches built for its batch size, fed or
+        not: a run's set-up meets them all, as it meets the step programs."""
+        input_ids = batch.input_ids
+        if batch.fed_from is not None and (batch.fed_from >= 0).any():
+            input_ids = _fed_ids(
+                input_ids, batch.fed_from,
+                _last_tokens(feeds.result, self._feed_width),
+            )
+            if self.mesh_devices == 1:
+                # the runner hands a one-chip program its inputs as they
+                # come; over a mesh it places every input itself
+                input_ids = _placed_like_numpy(input_ids)
+        # the device ended before the next was enqueued: it stood idle
+        queued = running is not None and not running.result.is_ready()
+        self._count_dispatch(
+            batch,
+            None if queued else "late" if running is not None
+            else self._drain_reason,
+        )
+        work = self._count_work(batch)
+        inp = StepInput(
+            input_ids, batch.positions, batch.page_table, batch.kv_lens,
+            batch.temperature, batch.top_k, batch.top_p,
+            lora_ids=batch.lora_ids, kv_limits=batch.kv_limits,
+            state_slots=batch.state_slots,
+        )
+        self.scheduler.pin(batch)
+        try:
+            with self._section("call"):  # staging included
+                if batch.kind == "decode":
+                    self.decode_dispatches_total += 1
+                    result = self.runner.step_multi(
+                        inp, self.scheduler.decode_steps
+                    )
+                else:
+                    result, _ = self.runner.step(inp)
+                result.copy_to_host_async()
+            if running is not None:
+                # 50 ms is no turn: something compiled, or the host froze
+                turn = min(time.perf_counter() - self._turn_t0, 0.05)
+                self._turn_secs += 0.2 * (turn - self._turn_secs)
+            if result.shape not in self._seams_built:
+                self._seams_built.add(result.shape)
+                rows = len(batch.kv_lens)
+                _fed_ids(
+                    np.zeros((rows, 1), np.int32), np.full((rows,), -1, np.int32),
+                    _last_tokens(result, self._feed_width),
+                )
+        except Exception:
+            self.scheduler.retire(batch)
+            raise
+        return _Dispatched(
+            batch, result, work, self.step_idx, time.perf_counter(), queued, first
+        )
+
+    def _retire(self, done: _Dispatched) -> None:
+        """Wait for a dispatch's tokens (the loop's one blocking wait), apply
+        and stream them, and let go of what its rows held for it."""
+        batch = done.batch
+        with self._section("fetch"):
+            tokens = np.asarray(done.result)
+        now = time.perf_counter()
+        # the device ran it once it had it AND what ran before it had ended
+        wall = now - max(done.t0, self._last_retire)
+        self._last_retire = now
+        if not done.first:
+            # the least seen lately: a wall the host was late for reads too long
+            timed = self._device_secs.get(self._shape(batch), wall)
+            self._device_secs[self._shape(batch)] = min(wall, 0.9 * timed + 0.1 * wall)
+        self._count_device_work()
+        self._unfetched.clear()  # what was dispatched before it has ended too
+        if self._fr.enabled:
+            self._fr.record(
+                "step", step=done.step, batch_kind=batch.kind,
+                wall_ms=round(wall * 1000, 3), bursts=batch.bursts,
+                fetched=True, queued_ahead=done.queued, **done.work,
+            )
+        self._observe_dispatch(batch, wall)
+        self._apply_and_emit(batch, tokens)
+        done.pinned = False
+        self.scheduler.retire(batch)
+
+    def _observe_dispatch(self, batch, wall: float) -> None:
+        """Dispatch-granular prefill-phase observability (the Grafana prefill
+        panel): chunk latency of prefill dispatches whose result was waited
+        for, and decode per-token time while a prefill is resident (the
+        interleave the demand gate schedules)."""
+        if batch.kind == "prefill":
+            tracing.prefill_chunk_hist.observe(wall)
+        elif batch.kind == "decode" and any(
+            s.in_prefill for s in self.scheduler.running
+        ):
+            toks_n = max(1, self.scheduler.decode_steps * batch.bursts)
+            tracing.interleaved_decode_hist.observe(wall / toks_n)
+
+    def _dispatch_now(self, batch, why: str) -> None:
+        """The synchronous path: dispatch ``batch`` with nothing in flight,
+        fetch what the host needs of it, apply and stream it."""
+        self._count_dispatch(batch, why)
+        self._drain_reason = why
+        secs = self.loop_seconds
+        t0 = time.perf_counter() + secs["apply"] + secs["emit"]
+        work = self._count_work(batch)
+        tokens, lp_data, fetched = self._dispatch_batch(batch)
+        self._count_device_work()
+        self._last_retire = time.perf_counter()
+        # less what a chained decode applied and streamed inside it
+        step_wall = self._last_retire + secs["apply"] + secs["emit"] - t0
+        if self._fr.enabled:
+            # runner step timing, dispatch-granular: a fetched step's
+            # wall is real device time; a skip-fetch dispatch's wall is
+            # enqueue-only (the trailing fetched step absorbs its compute)
+            self._fr.record(
+                "step", step=self.step_idx, batch_kind=batch.kind,
+                wall_ms=round(step_wall * 1000, 3), bursts=batch.bursts,
+                fetched=fetched, queued_ahead=False, **work,
+            )
+        if fetched:
+            self._unfetched.clear()  # a real fetch retires prior dispatches
+            self._observe_dispatch(batch, step_wall)
+        if tokens is not None:
+            self._apply_and_emit(batch, tokens, lp_data)
 
     def _section(self, name: str, **attrs) -> _LoopSection:
         return _LoopSection(self.loop_seconds, name, attrs)
@@ -1655,11 +1979,13 @@ class LLMEngine:
             tokens = np.asarray(ids)
         return tokens, lp_data, fetched
 
-    def _record_sched_event(self, batch) -> None:
+    def _record_sched_event(self, batch, **queue_ahead) -> None:
         """Flight-recorder "sched" event: the batch composition and the
         interleave-gate inputs that produced it, stamped with the step index
         and the members' trace ids so a slow request's spans cross-link to
-        the exact dispatches that served (or starved) it."""
+        the exact dispatches that served (or starved) it. ``queue_ahead``:
+        whether it was enqueued behind a running dispatch, else what had
+        emptied the loop (_count_dispatch)."""
         self.step_idx += 1
         fr = self._fr
         if not fr.enabled:
@@ -1681,6 +2007,7 @@ class LLMEngine:
             waiting=self.scheduler.num_waiting(),
             kv_usage=round(self.kv.usage(), 4),
             trace_id=trace_ids[0] if trace_ids else None,
+            **queue_ahead,
         )
 
     def _note_first_dispatch(self, batch) -> None:
@@ -2594,6 +2921,10 @@ class LLMEngine:
                 self.runahead_prefill_dispatches_total
             ),
             "decode_kv_tokens_read_total": self.decode_kv_tokens_read_total,
+            # dispatches enqueued behind a running one, by kind, and those
+            # that found the device idle, by what had emptied the loop
+            "queued_ahead_dispatches_total": dict(self.queued_ahead_dispatches),
+            "queue_ahead_drains_total": dict(self.queue_ahead_drains),
         }
         if self.state_family:
             # the second kind of state (models/jamba.py): slots of the
@@ -2635,10 +2966,14 @@ class LLMEngine:
         # which names its keys, leaves it to GET /stats)
         out["decode_kernel_blocks"] = dict(self.runner.decode_blocks)
         for section, secs in self.loop_seconds.items():
-            # stage and runahead are parts of step that the loop did not
-            # separate before: under a prefix of their own, so that a reader
-            # summing engine_loop_* reads what it always did
-            prefix = "engine_dispatch" if section in ("stage", "runahead") else "engine_loop"
+            # stage, call, fetch, hold and runahead are parts of step that the
+            # loop did not separate before: under a prefix of their own, so that a
+            # reader summing engine_loop_* reads what it always did
+            prefix = (
+                "engine_dispatch"
+                if section in ("stage", "call", "fetch", "hold", "runahead")
+                else "engine_loop"
+            )
             out[f"{prefix}_{section}_seconds_total"] = round(secs, 3)
         # interactive-SLO degradation signal for the fleet controller's
         # latency-protection policy (migration/controller.py): p99 over the

@@ -126,6 +126,11 @@ class Sequence:
     queue_span: Optional[object] = None
     prefill_span: Optional[object] = None
     decode_span: Optional[object] = None
+    # dispatches that name this row and have not retired (Scheduler.pin /
+    # retire): while any does, nothing the manager lent the row goes back to
+    # it; ``release_pending`` says that a finish is waiting for the last one
+    inflight: int = 0
+    release_pending: bool = False
 
     @property
     def num_tokens(self) -> int:
@@ -168,6 +173,10 @@ class ScheduledBatch:
     # [B] int32 slot of each row in the recurrent-state pool, the null slot
     # for padded rows (None: the family keeps pages only)
     state_slots: np.ndarray = None
+    # a decode planned behind a dispatch that still runs (schedule(ahead_of=)):
+    # [B] int32, the row of THAT dispatch whose last token is this row's
+    # input (``input_ids`` holds -1 there), -1 where the host knows the token
+    fed_from: np.ndarray = None
 
 
 def _bucket(n: int, buckets: tuple[int, ...]) -> int:
@@ -297,6 +306,9 @@ class Scheduler:
         # cannot serve (logprobs dispatches fetch whole-chain) falls back
         # to bursts=1 when a single burst already exceeds the budget.
         self.runahead_available = False
+        # why the last ``schedule(ahead_of=...)`` planned nothing, where that
+        # was not for lack of work
+        self.ahead_refusal: Optional[str] = None
 
     # -- api ----------------------------------------------------------------
 
@@ -511,24 +523,127 @@ class Scheduler:
 
     def _release(self, seq: Sequence) -> None:
         """Give back everything the manager lent ``seq``: its pages and, where
-        the family keeps recurrent state, its slot."""
+        the family keeps recurrent state, its slot. While a dispatch that
+        names the row is running or queued, nothing goes back: ``retire``
+        releases when the last of them has ended."""
+        if seq.inflight:
+            seq.release_pending = True
+            return
         self.kv.free(seq.pages)
         seq.pages = []
         if seq.state_slot is not None:
             self.kv.free_slot(seq.state_slot)
             seq.state_slot = None
 
+    def pin(self, batch: ScheduledBatch) -> None:
+        """``batch`` goes to the device: its rows keep their pages and state
+        slots until ``retire``, whatever finishes them meanwhile."""
+        for s in batch.seqs:
+            s.inflight += 1
+
+    def retire(self, batch: ScheduledBatch) -> None:
+        """``batch``'s dispatch has ended (or failed): rows that finished
+        while it named them give back what they held."""
+        for s in batch.seqs:
+            s.inflight -= 1
+            if not s.inflight and s.release_pending:
+                s.release_pending = False
+                self._release(s)
+
     # -- step planning ------------------------------------------------------
 
-    def schedule(self) -> Optional[ScheduledBatch]:
+    def schedule(self, ahead_of: Optional[ScheduledBatch] = None,
+                 allow=None) -> Optional[ScheduledBatch]:
+        """The next dispatch. With ``ahead_of`` (a dispatch that still runs,
+        one burst or one prefill step), the one to QUEUE BEHIND it, planned
+        from the state it will leave: its rows' lengths are advanced by the
+        device's own rule, a row that certainly ends in it (by length) is left
+        out, one that may end for a reason only its tokens tell (EOS, a stop
+        string) stays in, and a decode row it feeds takes its input token
+        from the device (``fed_from``). Planned without preempting, and only
+        where every resident row passes ``allow`` (needs nothing from the host
+        between two steps). None: nothing to queue; ``ahead_refusal`` says
+        why where it is not for lack of work."""
+        self.ahead_refusal = None
+        if ahead_of is None:
+            return self._schedule()
+        kept = self._last_kind, self._chain_streak
+        undo, fed, ending = self._project(ahead_of)
+        try:
+            batch = self._schedule(ending, allow, ahead=True)
+        finally:
+            for s, computed, recompute, n_out in undo:
+                s.num_computed, s.recompute_len = computed, recompute
+                del s.output_ids[n_out:]
+        if batch is None:
+            self._last_kind, self._chain_streak = kept
+        elif batch.kind == "decode":
+            batch.fed_from = np.full((len(batch.kv_lens),), -1, np.int32)
+            batch.fed_from[: len(batch.seqs)] = [
+                fed.get(id(s), -1) for s in batch.seqs
+            ]
+        return batch
+
+    def _project(self, batch: ScheduledBatch):
+        """Advance ``batch``'s rows to what its dispatch will leave (what
+        ``apply_step`` does, with -1 where a token is not known yet). Returns
+        (what to put back, {id(seq): the row whose last token is the seq's
+        next input}, the ids of the rows that end in it by length)."""
+        undo, fed, ending = [], {}, set()
+        if batch.kind == "decode":
+            if self.decode_steps == 1:
+                made = np.ones((len(batch.seqs),), np.int64)
+            else:
+                # the burst's rule: a row emits while its KV length is under
+                # its limit (runner._multi_step_deferred_fn)
+                n = len(batch.seqs)
+                made = np.clip(
+                    batch.kv_limits[:n].astype(np.int64) - batch.kv_lens[:n] + 1,
+                    0, self.decode_steps,
+                )
+        for i, s in enumerate(batch.seqs):
+            if s.finished:
+                continue
+            undo.append((s, s.num_computed, s.recompute_len, len(s.output_ids)))
+            if batch.kind == "decode":
+                if made[i]:
+                    s.output_ids.extend([-1] * int(made[i]))
+                    fed[id(s)] = i
+            else:
+                s.num_computed += batch.chunk_sizes[i]
+                if s.in_prefill:
+                    continue
+                if s.recompute_len:  # its sampled token is discarded
+                    s.recompute_len = 0
+                    continue
+                s.output_ids.append(-1)
+                fed[id(s)] = i
+            if (
+                len(s.output_ids) >= s.params.max_tokens
+                or s.num_tokens >= self.max_model_len
+            ):
+                ending.add(id(s))
+        return undo, fed, ending
+
+    def _schedule(self, ending=frozenset(), allow=None,
+                  ahead: bool = False) -> Optional[ScheduledBatch]:
+        """``schedule``'s plan on the state as the sequences show it; ``ahead``:
+        behind a running dispatch whose rows in ``ending`` end in it."""
         # high-watermark proactive spill: while the pool is nearly full, copy
         # the coldest evictable pages to the offload tier BEFORE an admission
         # or decode-growth allocation forces an eviction — the eviction then
         # frees slots with zero device I/O (cheap no-op below the watermark)
         self.kv.proactive_spill()
         self._try_admit()
+        if ahead and allow is not None and not all(
+            allow(s) for s in self.running if id(s) not in ending
+        ):
+            self.ahead_refusal = "host_staged_rows"
+            return None
         prefilling = [s for s in self.running if s.in_prefill]
-        decoding = [s for s in self.running if not s.in_prefill]
+        decoding = [
+            s for s in self.running if not s.in_prefill and id(s) not in ending
+        ]
         # Alternate prefill chunks with decode bursts when prefill work
         # coexists with RESIDENT DECODE DEMAND: strict prefill priority
         # starves decodes under a steady long-prompt arrival stream
@@ -678,12 +793,18 @@ class Scheduler:
                     rem = s.params.min_tokens - len(s.output_ids)
                     if rem > 0:
                         bursts = min(bursts, max(1, -(-rem // self.decode_steps)))
-            batch = self._plan_decode(decoding, bursts)
+            if ahead and bursts > 1:
+                # a chain fetches group by group: it runs with nothing queued
+                self.ahead_refusal = "chained"
+                return None
+            batch = self._plan_decode(decoding, bursts, may_preempt=not ahead)
             self._chain_streak = (
                 self._chain_streak + 1
                 if batch is not None and batch.bursts > 1
                 else 0
             )
+            if batch is None and self.ahead_refusal:
+                return None
             if batch is None:
                 # nothing decodable this pass — fall back to prefill work.
                 # RE-DERIVE the prefill set: _plan_decode's page-pressure
@@ -821,7 +942,7 @@ class Scheduler:
         return slots
 
     def _plan_decode(
-        self, seqs: list[Sequence], bursts: int = 1
+        self, seqs: list[Sequence], bursts: int = 1, may_preempt: bool = True
     ) -> Optional[ScheduledBatch]:
         ready = []
         # decode-dispatch priority: interactive rows claim their KV growth
@@ -836,6 +957,11 @@ class Scheduler:
             if s not in self.running or s.finished:
                 continue  # preempted or finished earlier in this pass
             ok = self._ensure_decode_page(s, bursts)
+            if not ok and not may_preempt:
+                # planned behind a running dispatch: no page it reads or
+                # writes changes hands before it retires
+                self.ahead_refusal = "no_pages"
+                return None
             while not ok:
                 # out of KV pages: preempt the newest other running sequence,
                 # preferring batch victims over interactive ones; if there is

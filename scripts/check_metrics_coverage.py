@@ -59,8 +59,12 @@ GENERATED = [
         "wait", "schedule", "step", "apply", "emit", "chain_dispatch",
         "chain_fetch",
     )),
-    # ... and the two parts of step under a prefix of their own
-    *(f"vllm:engine_dispatch_{sec}_seconds_total" for sec in ("stage", "runahead")),
+    # ... and the parts of step under a prefix of their own
+    *(f"vllm:engine_dispatch_{sec}_seconds_total"
+      for sec in ("stage", "call", "fetch", "hold", "runahead")),
+    # engine/api_server.py: labelled by kind / by reason (engine._turn)
+    "vllm:queued_ahead_dispatches_total",
+    "vllm:queue_ahead_drains_total",
     # engine/api_server.py: first-dispatch wall by phase (runner._dispatch)
     *(f"vllm:first_dispatch_{phase}_seconds_total" for phase in (
         "trace", "lower", "compile", "run",
@@ -85,6 +89,11 @@ DASHBOARD_ALLOWLIST = {
     "vllm:engine_loop_chain_fetch_seconds_total",
     "vllm:engine_dispatch_stage_seconds_total",
     "vllm:engine_dispatch_runahead_seconds_total",
+    "vllm:engine_dispatch_fetch_seconds_total",
+    "vllm:engine_dispatch_hold_seconds_total",
+    "vllm:engine_dispatch_call_seconds_total",
+    "vllm:queued_ahead_dispatches_total",    # how often the loop kept one
+    "vllm:queue_ahead_drains_total",         # dispatch queued: bench/debug
     "vllm:decode_kv_tokens_read_total",      # the benchmark's counted roofline reads it
     "vllm:first_dispatches_total",           # first-dispatch stalls: a start-up
     "vllm:first_dispatch_seconds_total",     # and bench surface; the dashboard

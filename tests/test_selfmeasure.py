@@ -216,7 +216,11 @@ def test_loop_sections_are_disjoint_sum_to_the_wall_and_keep_their_keys(engine):
     def snap():
         return time.perf_counter(), dict(engine.loop_seconds)
     (t0, a) = snap()
+    queued = sum(engine.stats()["queued_ahead_dispatches_total"].values())
+    _generate(engine, "sections of the loop, summed", 24)  # warm: every shape met
     _generate(engine, "sections of the loop, summed", 24)
+    # the second pass ran with a dispatch queued behind the one that ran
+    assert sum(engine.stats()["queued_ahead_dispatches_total"].values()) > queued
     time.sleep(0.3)  # an idle stretch: the loop waits on its inbox
     (t1, b) = snap()
     delta = {k: b[k] - a[k] for k in b}
@@ -226,9 +230,9 @@ def test_loop_sections_are_disjoint_sum_to_the_wall_and_keep_their_keys(engine):
     assert top == pytest.approx(t1 - t0, abs=0.6)
     assert delta["step"] > 0 and delta["apply"] > 0 and delta["emit"] > 0
     # the parts of a dispatch lie inside `step`
-    for part in ("stage", "chain_dispatch", "chain_fetch", "runahead"):
+    for part in ("stage", "call", "fetch", "hold", "chain_dispatch", "chain_fetch", "runahead"):
         assert 0 <= delta[part] <= delta["step"] + 1e-9
-    assert delta["stage"] > 0
+    assert delta["call"] >= delta["stage"] > 0 and delta["fetch"] > 0
     stats = engine.stats()
     # /stats keeps every engine_loop_* key it had, and no more: the two parts
     # of step that are new stand under a prefix of their own
@@ -236,7 +240,9 @@ def test_loop_sections_are_disjoint_sum_to_the_wall_and_keep_their_keys(engine):
         f"engine_loop_{k}_seconds_total" for k in (
             "wait", "schedule", "step", "apply", "emit", "chain_dispatch", "chain_fetch")}
     assert {k for k in stats if k.startswith("engine_dispatch_")} == {
-        "engine_dispatch_stage_seconds_total", "engine_dispatch_runahead_seconds_total"}
+        "engine_dispatch_stage_seconds_total", "engine_dispatch_runahead_seconds_total",
+        "engine_dispatch_fetch_seconds_total", "engine_dispatch_hold_seconds_total",
+        "engine_dispatch_call_seconds_total"}
 
 
 def test_apply_and_emit_inside_a_dispatch_are_taken_off_it(engine):
@@ -277,9 +283,9 @@ def test_under_a_profile_the_trace_holds_the_loop_spans_and_the_program_names(en
         for line in plane.lines:
             for ev in line.events:
                 names.add(ev.name)
-                if ev.name == "pstpu.loop.step":
-                    attrs = dict(ev.stats)
-    for section in ("wait", "schedule", "step", "stage", "apply", "emit"):
+                if ev.name == "pstpu.loop.step" and dict(ev.stats):
+                    attrs = dict(ev.stats)  # a turn that enqueues a dispatch says which
+    for section in ("wait", "schedule", "step", "stage", "fetch", "apply", "emit"):
         assert "pstpu.loop." + section in names
     assert {"kind", "family", "rows", "chunk", "pages", "bursts"} <= set(attrs)
     # the program's name, as JAX's own dispatch span shows it on the host plane
@@ -313,6 +319,34 @@ def test_the_store_counters_stand_in_stats_and_in_metrics(engine, what):
     text = asyncio.run(scrape())
     assert f"# TYPE vllm:{name} counter" in text
     assert f'vllm:{name}{{model_name="mistral-debug"}} {stats[name]}' in text
+
+
+@pytest.mark.parametrize("name,label", [("queued_ahead_dispatches_total", "kind"),
+                                        ("queue_ahead_drains_total", "reason")])
+def test_the_queue_ahead_counters_stand_in_stats_and_in_metrics(engine, name, label):
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from production_stack_tpu.engine.api_server import EngineServer
+
+    _generate(engine, "one dispatch queued behind the one that runs", 24)
+    _generate(engine, "one dispatch queued behind the one that runs", 24)
+    counts = engine.stats()[name]
+    assert sum(counts.values()) > 0
+    if label == "kind":
+        assert set(counts) == {"decode", "prefill"} and counts["decode"] > 0
+    else:
+        assert {"idle", "late", "first_dispatch", "device_cmd", "host_staged_rows"} <= set(counts)
+        assert counts["idle"] > 0  # the dispatch after the loop waited for work
+
+    async def scrape():
+        cfg = EngineConfig(model="mistral-debug")
+        async with TestClient(TestServer(EngineServer(cfg, engine).build_app())) as client:
+            return await (await client.get("/metrics")).text()
+
+    text = asyncio.run(scrape())
+    assert f"# TYPE vllm:{name} counter" in text
+    for key, n in counts.items():
+        assert f'vllm:{name}{{model_name="mistral-debug",{label}="{key}"}} {n}' in text
 
 
 def test_profile_endpoints_ride_the_debug_gate(engine, tmp_path):
