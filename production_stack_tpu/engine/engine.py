@@ -40,6 +40,8 @@ from production_stack_tpu.utils.logging import init_logger
 
 logger = init_logger(__name__)
 
+_NO_ATTRS: dict = {}  # a span's attributes while no profile runs
+
 
 class _LoopSection:
     """``with engine._section(name, **attrs):`` — one section of the engine
@@ -53,7 +55,12 @@ class _LoopSection:
     ``schedule``, ``step``, ``apply`` and ``emit`` are disjoint and sum to the
     loop's wall. ``stage``, ``call``, ``fetch``, ``hold``, ``runahead``,
     ``chain_dispatch`` and ``chain_fetch`` are parts of ``step``: they nest
-    inside it and it keeps their seconds."""
+    inside it and it keeps their seconds.
+
+    What a section learns only once it is open (which dispatch its turn
+    enqueued, and why nothing was queued ahead of it) goes into its span
+    through ``annotate``; callers build such attributes only while
+    ``profiler.active()``."""
 
     __slots__ = ("_secs", "_name", "_span", "_t0", "_inner0", "seconds")
 
@@ -67,6 +74,9 @@ class _LoopSection:
         self._inner0 = self._secs["apply"] + self._secs["emit"]
         self._t0 = time.perf_counter()
         return self
+
+    def annotate(self, **attrs) -> None:
+        self._span.set_metadata(**attrs)
 
     def __exit__(self, *exc):
         secs = self._secs
@@ -102,7 +112,7 @@ class _Dispatched:
     work: dict     # what it computes, for the flight recorder's ``step`` event
     step: int      # its step index
     t0: float      # when it was enqueued (perf_counter)
-    queued: bool   # behind one that still ran
+    drain: Optional[str]  # what had emptied the loop (None: behind one that still ran)
     first: bool    # its shape's first dispatch: built and timed to its result
     pinned: bool = True  # its rows still hold what the manager lent them for it
 
@@ -1404,7 +1414,10 @@ class LLMEngine:
                     if self._arrival_times else float("inf")
                 )
             if self._inflight is not None:
-                with self._section("step"), self._section("hold"):
+                # held back: the dispatch that the next turn may enqueue
+                with self._section("step"), self._section(
+                    "hold", **self._seq_attr(self.step_idx + 1)
+                ):
                     self._hold_back()
             self._turn_t0 = time.perf_counter()
             with self._section("schedule"):  # the scheduler's decision alone
@@ -1506,6 +1519,7 @@ class LLMEngine:
             with step:
                 why, first = None, False
                 if batch is not None:
+                    seq = self.step_idx + 1  # the dispatch this turn hands over
                     why = self._synchronous(batch) if running is None else None
                     first = why is None and not self._shape_known(batch)
                     if first and running is not None:
@@ -1515,8 +1529,18 @@ class LLMEngine:
                         self._drain_reason = "first_dispatch"
                     if why is None:
                         queued = self._enqueue(batch, feeds, running, first)
+                        drain = queued.drain
                     else:
                         self._dispatch_now(batch, why)
+                        drain = why
+                    if profiler.active():
+                        # WHICH dispatch the turn enqueued and, where the device
+                        # had nothing queued behind the one before, why (the words
+                        # of the ``sched`` event and ``queue_ahead_drains_total``;
+                        # a trace keeps no empty value, so none when queued ahead)
+                        step.annotate(
+                            seq=seq, **({"drain": drain} if drain else _NO_ATTRS)
+                        )
                 if running is not None:
                     self._retire(running)
             self._inflight = queued
@@ -1609,12 +1633,11 @@ class LLMEngine:
                 # come; over a mesh it places every input itself
                 input_ids = _placed_like_numpy(input_ids)
         # the device ended before the next was enqueued: it stood idle
-        queued = running is not None and not running.result.is_ready()
-        self._count_dispatch(
-            batch,
-            None if queued else "late" if running is not None
-            else self._drain_reason,
+        drain = (
+            self._drain_reason if running is None
+            else "late" if running.result.is_ready() else None
         )
+        self._count_dispatch(batch, drain)
         work = self._count_work(batch)
         inp = StepInput(
             input_ids, batch.positions, batch.page_table, batch.kv_lens,
@@ -1624,7 +1647,7 @@ class LLMEngine:
         )
         self.scheduler.pin(batch)
         try:
-            with self._section("call"):  # staging included
+            with self._section("call", **self._seq_attr(self.step_idx)):  # staging included
                 if batch.kind == "decode":
                     self.decode_dispatches_total += 1
                     result = self.runner.step_multi(
@@ -1648,14 +1671,14 @@ class LLMEngine:
             self.scheduler.retire(batch)
             raise
         return _Dispatched(
-            batch, result, work, self.step_idx, time.perf_counter(), queued, first
+            batch, result, work, self.step_idx, time.perf_counter(), drain, first
         )
 
     def _retire(self, done: _Dispatched) -> None:
         """Wait for a dispatch's tokens (the loop's one blocking wait), apply
         and stream them, and let go of what its rows held for it."""
         batch = done.batch
-        with self._section("fetch"):
+        with self._section("fetch", **self._seq_attr(done.step)):
             tokens = np.asarray(done.result)
         now = time.perf_counter()
         # the device ran it once it had it AND what ran before it had ended
@@ -1671,7 +1694,7 @@ class LLMEngine:
             self._fr.record(
                 "step", step=done.step, batch_kind=batch.kind,
                 wall_ms=round(wall * 1000, 3), bursts=batch.bursts,
-                fetched=True, queued_ahead=done.queued, **done.work,
+                fetched=True, queued_ahead=done.drain is None, **done.work,
             )
         self._observe_dispatch(batch, wall)
         self._apply_and_emit(batch, tokens)
@@ -1722,6 +1745,13 @@ class LLMEngine:
     def _section(self, name: str, **attrs) -> _LoopSection:
         return _LoopSection(self.loop_seconds, name, attrs)
 
+    @staticmethod
+    def _seq_attr(seq: int) -> dict:
+        """The dispatch a ``call`` / ``fetch`` / ``hold`` span hands over,
+        waits for or holds back (the ``sched`` event's ``step``), built only
+        while a profile runs."""
+        return {"seq": seq} if profiler.active() else _NO_ATTRS
+
     def _dispatch_attrs(self, batch) -> dict:
         """What the scheduler knows of a dispatch, for its span in the
         profiler's trace. A shape's FIRST dispatch shows as the span
@@ -1739,7 +1769,6 @@ class LLMEngine:
             "chunk": int(batch.input_ids.shape[1]),
             "pages": int(batch.page_table.shape[1]),
             "bursts": batch.bursts,
-            "step": self.step_idx,
         }
 
     def _count_work(self, batch) -> dict:

@@ -9,7 +9,8 @@ test, nothing built. The debug endpoints ``POST /v1/debug/profile/start|stop``
 (engine API server, behind ``--enable-debug-endpoints``) call this module, so
 the spans switch with the profiler; a profile started through ``jax.profiler``
 directly (as the benchmark's engine child does) holds the device plane and the
-named programs, not the spans. See docs/tracing.md for the span names.
+named programs, not the spans. See docs/tracing.md for the span names and
+their attributes.
 
 The Python tracer is off (``python_tracer_level = 0``): with it every Python
 call of every server thread lands in the trace, which slows the threads that
@@ -38,6 +39,9 @@ class _NoSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_metadata(self, **attrs):
+        """What a TraceAnnotation takes once it is open; nothing here."""
+
 
 _NO_SPAN = _NoSpan()
 
@@ -48,7 +52,8 @@ def active() -> bool:
 
 def span(name: str, **attrs):
     """A context manager: a TraceAnnotation while a profile runs, else the
-    shared no-op. Attribute values must be str, int or float."""
+    shared no-op. Attribute values must be str, int or float; what is known
+    only once the span is open goes in through its ``set_metadata(**attrs)``."""
     if not _active:
         return _NO_SPAN
     import jax

@@ -11,19 +11,35 @@ runs (`production_stack_tpu/tracing/profiler.py`): `pstpu.loop.<section>` for
 each section of the engine loop and `pstpu.first_dispatch` around the first
 call of a step-program shape. They lie on the host plane, on the thread that
 drives the device. Spans nest (a dispatch holds its staging); at any instant
-the INNERMOST open span is what the host was doing.
+the INNERMOST open span is what the host was doing. Since PR 47 the host is
+one dispatch AHEAD of the device, so what it did during a gap need not be why
+the device waited: the turn that enqueued the dispatch AFTER the gap says why
+nothing was queued behind the one before (`pstpu.loop.step`'s `drain`).
+
+A gap is named, in this order (`name_gap`):
+  `(under clock skew)`  it is shorter than twice the estimated skew between
+                        the two planes' clocks: not guessed
+  `drain:<reason>`      the `pstpu.loop.step` span open when the next device
+                        program started carries a `drain` (`late`,
+                        `first_dispatch`, `no_pages`, `idle`, ...: the words of
+                        `/stats` `queue_ahead_drains_total`)
+  `pstpu.<span>`        the innermost span covering most of it, `(no span)`
+                        where none does (the turn was opened before the
+                        profile started, or the profile was started around
+                        the program's control: its spans are the no-op)
 
 Output, seconds throughout:
   spans         {name: [count, total_s]} of the `pstpu.*` spans found
   clock_skew_s  what was added to the device plane's times: the device
                 plane's clock runs ahead of the host plane's (by 1.0-1.7 ms in
-                the probe of PR 25); the least "host saw the program complete"
+                the probe of PR 25, 0.29-0.37 ms in the engine's traces of PR
+                48); the least "host saw the program complete"
                 minus "program ended on the device" over the traced programs
                 bounds it from above and is taken as the estimate. 0.0 where
                 the host plane shows no completions
-  gaps          [[innermost span covering most of the gap or "(no span)",
-                gap seconds, {name: seconds} of all that cover it], ...]
-                the longest device idle gaps first
+  gaps          [[the gap's name by the rule above, gap seconds, {name:
+                seconds} of the innermost spans that cover it, "(no span)" for
+                the rest], ...] the longest device idle gaps first
   idle_by_span  {name: idle seconds} over ALL gaps, "(no span)" for the rest
   idle_s        the sum
   scopes        {scope: device seconds} of the leaf operations by the first
@@ -46,6 +62,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
 import tracereduce  # noqa: E402
 
 SPAN_PREFIX = "pstpu."
+STEP_SPAN = "pstpu.loop.step"
+UNDER_SKEW = "(under clock skew)"
+NO_SPAN = "(no span)"
 HOST_PLANE = "/host:CPU"
 COMPLETE = "CompleteCallbacks"  # libtpu's host event when a program's run ends
 STRUCTURAL = ("while", "body", "cond", "closed_call", "checkpoint", "pjit")
@@ -82,6 +101,19 @@ def gap_intervals(events):
             gaps.append((hi, s))
         hi = e if hi is None else max(hi, e)
     return gaps
+
+
+def name_gap(a, b, cover, drains, skew) -> str:
+    """The name of the device's idle gap [a, b) (host clock, ns); the rule is
+    in the module's docstring. `cover` {innermost span: ns} as `attribute`
+    gives it with NO_SPAN for the rest, `drains` sorted [(start, end, drain)]
+    of the step spans that carry one, `skew` the estimated clock skew in ns."""
+    if b - a < 2 * skew:
+        return UNDER_SKEW
+    i = bisect.bisect_right(drains, (b, float("inf"), "")) - 1
+    if i >= 0 and drains[i][1] >= b:  # the turn open when the next program started
+        return "drain:" + drains[i][2]
+    return max(cover, key=cover.get)
 
 
 def attribute(gaps, segments):
@@ -187,7 +219,7 @@ def reduce(path: str) -> dict:
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
-    device_ops, ends_by_run, completes, threads = [], {}, {}, []
+    device_ops, ends_by_run, completes, threads, drains = [], {}, {}, [], []
     for plane in data.planes:
         if tracereduce.is_device(plane.name):
             for line in plane.lines:
@@ -205,6 +237,10 @@ def reduce(path: str) -> dict:
                 for ev in line.events:
                     if ev.name.startswith(SPAN_PREFIX):
                         spans.append((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name))
+                        if ev.name == STEP_SPAN:
+                            drain = dict(ev.stats).get("drain")
+                            if drain:
+                                drains.append((ev.start_ns, ev.start_ns + ev.duration_ns, str(drain)))
                     elif ev.name == COMPLETE:
                         run = dict(ev.stats).get("run_id")
                         if run is not None:
@@ -216,6 +252,7 @@ def reduce(path: str) -> dict:
     # the engine loop is one thread; a span of another thread names no gap
     spans = max(threads, key=len) if threads else []
     segments = innermost(spans)
+    drains.sort()
     found: dict = {}
     for s, e, name in spans:
         row = found.setdefault(name, [0, 0.0])
@@ -227,10 +264,10 @@ def reduce(path: str) -> dict:
         for (a, b), cover in zip(intervals, attribute(intervals, segments)):
             rest = (b - a) - sum(cover.values())
             if rest > 0:
-                cover["(no span)"] = rest
+                cover[NO_SPAN] = rest
             for name, ns in cover.items():
                 idle[name] = idle.get(name, 0.0) + ns / 1e9
-            gaps.append([max(cover, key=cover.get), (b - a) / 1e9,
+            gaps.append([name_gap(a, b, cover, drains, skew), (b - a) / 1e9,
                          {n: v / 1e9 for n, v in cover.items()}])
     gaps.sort(key=lambda g: -g[1])
     n_dev = max(1, len(device_ops))

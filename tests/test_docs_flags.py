@@ -78,6 +78,27 @@ def test_doc_flags_exist():
     assert not missing, f"flags documented but not implemented: {missing}"
 
 
+def test_the_benchmarks_trace_modes_on_the_pages_are_the_parsers():
+    """`--trace <0|1>`: every value a page that tells how to run the benchmark
+    gives is one `perfbench/run.py` takes (`--trace 2`, measure and then trace in
+    one run, was built twice and left out: PERF.md section 7)."""
+    src = REPO.joinpath("perfbench", "run.py").read_text()
+    choices = re.search(r'"--trace", type=int, choices=\(([\d, ]+)\)', src)
+    modes = {m.strip() for m in choices[1].split(",")}
+    for rel in ("docs/benchmarking.md", "perfbench/README.md"):
+        text = REPO.joinpath(rel).read_text()
+        given = set(re.findall(r"--trace (\d)\b", text))
+        for alt in re.findall(r"--trace <([\d|]+)>", text):
+            given |= set(alt.split("|"))
+        assert given and given <= modes, (rel, given)
+
+
+def test_the_hostspans_command_keeps_its_flags():
+    """`scripts/hostspans.py <file> --out <json>` as the pages give it."""
+    src = REPO.joinpath("scripts", "hostspans.py").read_text()
+    assert re.findall(r'add_argument\(\s*"([a-z-]+)"', src) == ["trace", "--out"]
+
+
 # -- cited files exist ----------------------------------------------------------
 
 CITING_PAGES = (

@@ -69,3 +69,33 @@ def test_scope_of_a_tf_op_path():
     assert hostspans.scope_of("jit(pstpu_step)/attention/dot_general") == "attention"
     assert hostspans.scope_of("jit(pstpu_multi_step_k8)/while/body/jit(_take)/kv_gather/gather") == "kv_gather"
     assert hostspans.scope_of("jit(pstpu_step)/while/body/add") == "(unscoped)"
+
+
+def test_a_gap_under_twice_the_clock_skew_is_not_guessed():
+    r = hostspans.reduce(SMALL)
+    floor = 2 * r["clock_skew_s"]
+    short = [g for g in r["gaps"] if g[1] < floor]
+    assert short and all(g[0] == "(under clock skew)" for g in short)
+    assert all(g[0] != "(under clock skew)" for g in r["gaps"] if g[1] >= floor)
+    # its seconds still go to the spans that cover it: the sums are what they were
+    assert sum(r["idle_by_span"].values()) == pytest.approx(r["idle_s"])
+    assert "(under clock skew)" not in r["idle_by_span"]
+
+
+@pytest.mark.parametrize("gap, cover, want", [
+    # the turn open when the next program started says what had emptied the loop
+    ((1000, 9000), {"pstpu.loop.schedule": 5000, "pstpu.loop.hold": 3000}, "drain:late"),
+    # ... whatever the host did for most of the gap (it waited on its inbox: nothing to run)
+    ((20000, 90000), {"pstpu.loop.wait": 69000, "pstpu.loop.step": 1000}, "drain:idle"),
+    # a turn that was queued ahead carries no drain: the innermost span covering most of it
+    ((100000, 104000), {"pstpu.loop.fetch": 3000, "pstpu.loop.apply": 1000}, "pstpu.loop.fetch"),
+    # the turn with a drain ended before the next program started: not its gap
+    ((200000, 205000), {"pstpu.loop.emit": 4000, "(no span)": 1000}, "pstpu.loop.emit"),
+    # shorter than twice the skew: not guessed, drain or no drain
+    ((8500, 9000), {"pstpu.loop.call": 500}, "(under clock skew)"),
+    # no span over it (a turn opened before the profile started, a profile around the control)
+    ((300000, 310000), {"(no span)": 10000}, "(no span)"),
+])
+def test_the_rule_by_which_a_gap_is_named(gap, cover, want):
+    drains = [(8000, 12000, "late"), (88000, 95000, "idle"), (190000, 199000, "first_dispatch")]
+    assert hostspans.name_gap(*gap, cover, drains, 400) == want

@@ -77,6 +77,60 @@ def test_trace_roofline_counted():
     assert read(dict(ctx, trace=None), params) is None
 
 
+def _metrics(queued, drains):
+    lines = ["# TYPE vllm:queued_ahead_dispatches_total counter"]
+    lines += [f'vllm:queued_ahead_dispatches_total{{model_name="m",kind="{k}"}} {v}'
+              for k, v in queued.items()]
+    lines += [f'vllm:queue_ahead_drains_total{{model_name="m",reason="{k}"}} {v}'
+              for k, v in drains.items()]
+    return "\n".join(lines) + "\n"
+
+
+def test_queued_ahead_share_reads_the_labelled_series_of_metrics():
+    read = reader("metrics_ratio").read
+    params = proposed_params("sched.queued_ahead_share")
+    ctx = {"snap0": {"metrics": _metrics({"decode": 100, "prefill": 20}, {"idle": 30, "late": 1})},
+           "snap1": {"metrics": _metrics({"decode": 500, "prefill": 153}, {"idle": 31, "late": 9,
+                                                                           "first_dispatch": 2})}}
+    # 533 of 544 dispatches of the window went out behind a running one (PERF.md, PR 47's sessions run)
+    assert read(ctx, params) == pytest.approx(100.0 * 533 / 544)
+    # a program without the counters (the parent of PR 47), and a window that dispatched nothing
+    assert read({"snap0": {"metrics": ""}, "snap1": {"metrics": "vllm:num_requests_running 1\n"}},
+                params) is None
+    assert read(dict(ctx, snap0=ctx["snap1"]), params) is None
+
+
+def test_dispatch_hold_share_through_the_accepted_reader():
+    read = manifest.load_module("readers", "counter_ratio", PERFBENCH).read
+    params = proposed_params("sched.dispatch_hold_share")
+    sections = {"wait": 0.5, "schedule": 0.4, "step": 47.0, "apply": 1.6, "emit": 1.5}
+    s0 = {f"engine_loop_{k}_seconds_total": 10.0 for k in sections}
+    s1 = {k: v + sections[k.split("_")[2]] for k, v in s0.items()}
+    s0["engine_dispatch_hold_seconds_total"], s1["engine_dispatch_hold_seconds_total"] = 3.0, 40.74
+    ctx = {"snap0": {"stats": s0}, "snap1": {"stats": s1}}
+    assert read(ctx, params) == pytest.approx(100.0 * 37.74 / 51.0)
+    # the parts of `step` are not in the denominator: the five sections are the loop's wall
+    assert not any("dispatch" in n or "chain" in n for n in params["den"])
+    # a program without the hold (before PR 47): nothing of it counted
+    del s1["engine_dispatch_hold_seconds_total"]
+    assert read(ctx, params) == 0.0
+
+
+def test_the_proposed_metrics_keep_the_benchmarks_words():
+    """A proposed file becomes a file of the benchmark by being moved: its layer
+    is one BENCHMARK.json names, its reader exists, and the README lists it."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        layers = {m["layer"] for m in json.load(f)["per_layer"]}
+    with open(os.path.join(PROPOSED, "README.md")) as f:
+        readme = f.read()
+    for name in manifest.names("layer_metrics", PROPOSED):
+        spec = manifest.load_json("layer_metrics", name + ".json", base=PROPOSED)
+        assert spec["layer"] in layers and spec["moves"] == "tpot_p50_ms" or name.endswith("setup_s"), name
+        assert any(os.path.exists(os.path.join(d, "readers", spec["reader"] + ".py"))
+                   for d in (PROPOSED, PERFBENCH)), name
+        assert f"`{name}`" in readme, name
+
+
 @pytest.mark.slow  # ~1 min: an engine and a router child on the CPU
 def test_the_four_are_read_through_perfbenchs_own_command(tmp_path):
     """The toy cell of perfbench/tests/test_rehearsal.py in a copy of perfbench/
