@@ -963,6 +963,26 @@ class EngineServer:
         emit("step_program_store_errors_total", "counter",
              s.get("step_program_store_errors_total", 0),
              "blobs deleted and rebuilt + programs jax.export refused")
+        # what the loader built of the store's listing as the process started
+        emit("step_program_preload_listed", "gauge",
+             s.get("step_program_preload_listed", 0),
+             "step programs the store listed for this process's identity")
+        emit("step_program_preloaded_total", "counter",
+             s.get("step_program_preloaded_total", 0),
+             "listed step programs built off the loop's thread")
+        emit("step_program_preload_failed_total", "counter",
+             s.get("step_program_preload_failed_total", 0),
+             "listed step programs not found, built or callable: unlisted")
+        emit("step_program_preload_served_total", "counter",
+             s.get("step_program_preload_served_total", 0),
+             "first dispatches that ran a preloaded executable")
+        emit("step_program_preload_seconds", "gauge",
+             s.get("step_program_preload_seconds", 0.0),
+             "wall seconds the loader has worked")
+        if s.get("step_program_preload_pending_at_first_dispatch") is not None:
+            emit("step_program_preload_pending_at_first_dispatch", "gauge",
+                 s["step_program_preload_pending_at_first_dispatch"],
+                 "listed programs still to build when the first dispatch came")
         for k in sorted(s):  # kv offload / transfer / spec / warm-start / loop
             if k.startswith(("kv_", "spec_decode_", "engine_loop_", "engine_dispatch_",
                              "warm_start_")):

@@ -2990,6 +2990,10 @@ class LLMEngine:
         # beside the compile cache, wrote it, or could not use the store
         # (step_program_store_bypassed: program -> why it runs its plain jit)
         out.update(store_stats(self.runner.step_store))
+        # and what the loader built of the store's listing as the process
+        # started (step_programs.Preloader): a first dispatch that takes one
+        # of those reads store "preloaded" and costs its run alone
+        out.update(self.runner.preloaded.stats())
         # per decode (batch x pages) bucket dispatched: the block of pages and
         # the ring depth the kernel's derivation chose (a dict, so /metrics,
         # which names its keys, leaves it to GET /stats)

@@ -25,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from production_stack_tpu import models
 from production_stack_tpu.engine import devicemon
 from production_stack_tpu.engine.step_programs import (
+    Preloader,
     StepProgramStore,
     abstract_args,
     program_key,
@@ -399,6 +400,41 @@ class ModelRunner:
                     "kv_cache_dtype=int8 does not compose with sp/pp meshes"
                 )
 
+        # sampled tokens come back fully replicated so the leader process can
+        # fetch the whole batch in multi-host serving (each process can only
+        # address its own shards); logits/pools keep their compiler-chosen or
+        # donated layouts.
+        self._rep = NamedSharding(self.mesh, P())
+        self._steps: dict[bool, Any] = {}  # want_logprobs -> jitted step
+        self._multi_steps: dict[tuple, Any] = {}  # (k, want_lp) -> jitted decode
+        self._spec_fns: dict[tuple, Any] = {}   # (steps, k, n) -> jitted spec decode
+        # exported step programs beside the compile cache (None: no cache
+        # directory, so no store), how each jitted step was jitted (the
+        # store's wrapper repeats it), and the program each (family, sig,
+        # shapes) runs through once it has dispatched (None: the plain jit)
+        self.step_store = StepProgramStore.beside_compile_cache()
+        self._jit_kw: dict[Any, dict] = {}
+        self._programs: dict[tuple, Any] = {}
+        # every field of a step program's key that does not name the batch's
+        # shape: the identity the store lists this process's programs by. The
+        # sizes the runner was built with are in it (they decide the shapes
+        # of the arguments it owns: another pool's programs are not ours)
+        self._key_fields = {
+            "module": self.module.__name__, "cfg": repr(self.cfg),
+            "page_size": page_size,
+            "pool_dtype": str(np.dtype(self.kv_pool_dtype)),
+            "runner": [self._kv_burst_ok, self.kv_quant],
+            "mesh": list(self.mesh.shape.items()),
+            "processes": jax.process_count(),
+            "sizes": [num_pages, self.state_slots, enable_lora and [
+                max_loras, max_lora_rank, list(lora_targets)]],
+        }
+        # the executables of what the store lists for that identity are built
+        # from here on, on threads of their own, while the weights are drawn
+        # and the pools built below: a listed shape's first dispatch takes one
+        self.preloaded = Preloader(
+            self.step_store, self._key_fields, self.mesh, self._wrapper)
+
         if params is None:
             # seeded random weights, built under jit straight into their
             # shards (no device ever holds the whole tree)
@@ -437,19 +473,6 @@ class ModelRunner:
 
         self._row_sh = NamedSharding(self.mesh, shardings.BATCH_SPECS["input_ids"])
         self._vec_sh = NamedSharding(self.mesh, shardings.BATCH_SPECS["kv_lens"])
-        # sampled tokens come back fully replicated so the leader process can
-        # fetch the whole batch in multi-host serving (each process can only
-        # address its own shards); logits/pools keep their compiler-chosen or
-        # donated layouts.
-        self._rep = NamedSharding(self.mesh, P())
-        self._steps: dict[bool, Any] = {}  # want_logprobs -> jitted step
-        # exported step programs beside the compile cache (None: no cache
-        # directory, so no store), how each jitted step was jitted (the
-        # store's wrapper repeats it), and the wrapper each (family, sig,
-        # shapes) runs through once it has dispatched (None: the plain jit)
-        self.step_store = StepProgramStore.beside_compile_cache()
-        self._jit_kw: dict[Any, dict] = {}
-        self._programs: dict[tuple, Any] = {}
         # what first dispatches cost, by phase (engine stats() exports it)
         self.first_dispatch = {
             "count": 0, "seconds": 0.0,
@@ -471,8 +494,6 @@ class ModelRunner:
         self._last_hist = None    # device history after a burst (chaining)
         self._params_host = None  # host copy during sleep level 2
         self._encode = None       # built lazily in encode (pooled embeddings)
-        self._multi_steps: dict[tuple, Any] = {}  # (k, want_lp) -> jitted decode
-        self._spec_fns: dict[tuple, Any] = {}   # (steps, k, n) -> jitted spec decode
 
     def _stage(self, inp: StepInput, with_limits: bool = False) -> dict:
         """Host→device staging shared by step/step_multi: split the RNG and
@@ -579,48 +600,68 @@ class ModelRunner:
         self._jit_kw[fn] = kw
         return fn
 
-    def _step_program(self, fn, family: str, sig, args: tuple, refused=None):
-        """What the first dispatch of a shape runs, and how the store took
-        part: ``jit(exported.call)`` over the module the store holds ("hit")
-        or holds from now on ("write"; "error" where the file that was there
-        had to be deleted: ``refused`` is what its call raised), the plain jit
-        where there is no store ("off") or ``jax.export`` refuses the program
-        ("error"; ``/stats`` names it). Cold or warm, XLA compiles the same
-        module, so the persistent compile cache's key is the same too."""
-        store, kw = self.step_store, self._jit_kw.get(fn)
-        if store is None or kw is None:
+    def _wrapper(self, family: str, sig: tuple):
+        """(name, what jits an exported call as this runner jits it) of the
+        step program that ``family`` runs for ``sig``."""
+        fn = {"step": self._get_step, "multi_step": self._get_multi_step,
+              "spec_step": self._get_spec}[family](*sig)
+        return fn.__name__, functools.partial(self._wrap, fn)
+
+    def _wrap(self, fn, call):
+        return jax.jit(_named_program(fn.__name__, call), **self._jit_kw[fn])
+
+    def _program_key(self, fn, family: str, sig, args: tuple) -> str:
+        kw = self._jit_kw[fn]
+        return program_key(
+            dict(
+                self._key_fields,
+                program=fn.__name__, family=family, sig=repr(sig),
+                args=abstract_args(args, self.mesh.devices.size),
+                donate=kw["donate_argnums"],
+                outs=[o and f"{tuple(o.mesh.shape.items())}{o.spec}"
+                      for o in kw["out_shardings"]],
+            ),
+            self.mesh.devices.flat[0],
+        )
+
+    def _step_program(self, fn, key, listed, args: tuple, refused=None):
+        """What the first dispatch of a shape runs where nothing was
+        preloaded for it, and how the store took part:
+        ``jit(exported.call)`` over the module the store holds ("hit") or
+        holds from now on ("write"; "error" where the file that was there had
+        to be deleted: ``refused`` is what its call raised), the plain jit
+        where there is no store ("off": no ``key``) or ``jax.export`` refuses
+        the program ("error"; ``/stats`` names it). Cold or warm, XLA compiles
+        the same module, so the persistent compile cache's key is the same
+        too. ``listed``: what the store keeps for a later process's loader."""
+        store = self.step_store
+        if key is None:
             return fn, "off"
-        name = fn.__name__
         try:
-            key = program_key(
-                {
-                    "program": name, "family": family, "sig": repr(sig),
-                    "module": self.module.__name__, "cfg": repr(self.cfg),
-                    "page_size": self.page_size,
-                    "pool_dtype": str(np.dtype(self.kv_pool_dtype)),
-                    "runner": [self._kv_burst_ok, self.kv_quant],
-                    "mesh": list(self.mesh.shape.items()),
-                    "processes": jax.process_count(),
-                    "args": abstract_args(args, self.mesh.devices.size),
-                    "donate": kw["donate_argnums"],
-                    "outs": [o and f"{tuple(o.mesh.shape.items())}{o.spec}"
-                             for o in kw["out_shardings"]],
-                },
-                self.mesh.devices.flat[0],
-            )
             if refused is not None:
                 store.discard(key, f"{type(refused).__name__}: {refused}")
-            exported, status = store.exported(key, fn, args)
+            exported, status = store.exported(key, fn, args, listed)
         except Exception as e:  # noqa: BLE001 - whatever jax.export refuses
-            store.bypass(name, f"{type(e).__name__}: {e}")
+            store.bypass(fn.__name__, f"{type(e).__name__}: {e}")
             return fn, "error"
-        program = jax.jit(_named_program(name, exported.call), **kw)
-        return program, "error" if refused is not None else status
+        return self._wrap(fn, exported.call), "error" if refused is not None else status
 
     def _first_call(self, fn, family: str, sig, args: tuple):
         """(program, how the store took part, the batch's result) of a
         shape's first dispatch."""
-        program, store = self._step_program(fn, family, sig, args)
+        key = listed = None
+        if self.step_store is not None and fn in self._jit_kw:
+            key = self._program_key(fn, family, sig, args)
+            listed = (self.preloaded.identity, {
+                "program": fn.__name__, "family": family, "sig": list(sig),
+                "order": self.first_dispatch["count"],
+            })
+            # an executable built at start-up from the same blob; one that
+            # is not there, or refuses these arguments, is built below
+            served = self.preloaded.call(key, args)
+            if served is not None:
+                return served[0], "preloaded", served[1]
+        program, store = self._step_program(fn, key, listed, args)
         try:
             return program, store, jax.block_until_ready(program(*args))
         except Exception as e:  # noqa: BLE001 - judged by `store`
@@ -630,9 +671,7 @@ class ModelRunner:
         # the blob deserialised, but its call does not trace or lower
         # (nothing is donated before it does): once more from the step
         # function itself
-        program, store = self._step_program(
-            fn, family, sig, args, refused=refused
-        )
+        program, store = self._step_program(fn, key, listed, args, refused=refused)
         return program, store, jax.block_until_ready(program(*args))
 
     def _dispatch(self, fn, family: str, sig, s: dict, args: tuple):
@@ -799,6 +838,32 @@ class ModelRunner:
         s = self._stage(inp, with_limits=True)
         want_pen = "pen" in s
         sig = (k, want_logprobs, want_pen)
+        args = (
+            self.params, self.k_pages, self.v_pages,
+            s["input_ids"], s["positions"], s["page_table"], s["kv_lens"],
+            s["kv_limits"], s["temperature"], s["top_k"], s["top_p"], s["key"],
+            self.lora, s["lora_ids"], s.get("pen"), s.get("bias"),
+        )
+        if self.kv_quant:
+            args = args + ((self.k_scales, self.v_scales),)
+        args = self._with_state(args, s, 17)
+        out = self._keep_counters(
+            self._dispatch(self._get_multi_step(*sig), "multi_step", sig, s, args)
+        )
+        if self.has_state:
+            *out, self.state = out
+        if self.kv_quant:
+            *out, self.k_scales, self.v_scales = out
+        if want_logprobs:
+            toks, lp, tids, tlp, hist_f, self.k_pages, self.v_pages = out
+            self._last_hist = hist_f if want_pen else None
+            return toks, (lp, tids, tlp)
+        toks, hist_f, self.k_pages, self.v_pages = out
+        self._last_hist = hist_f if want_pen else None
+        return toks
+
+    def _get_multi_step(self, k: int, want_logprobs: bool, want_pen: bool):
+        sig = (k, want_logprobs, want_pen)
         if sig not in self._multi_steps:
             rep, n = self._rep, None
             outs = (
@@ -828,29 +893,7 @@ class ModelRunner:
                 ),
                 donate, outs,
             )
-        args = (
-            self.params, self.k_pages, self.v_pages,
-            s["input_ids"], s["positions"], s["page_table"], s["kv_lens"],
-            s["kv_limits"], s["temperature"], s["top_k"], s["top_p"], s["key"],
-            self.lora, s["lora_ids"], s.get("pen"), s.get("bias"),
-        )
-        if self.kv_quant:
-            args = args + ((self.k_scales, self.v_scales),)
-        args = self._with_state(args, s, 17)
-        out = self._keep_counters(
-            self._dispatch(self._multi_steps[sig], "multi_step", sig, s, args)
-        )
-        if self.has_state:
-            *out, self.state = out
-        if self.kv_quant:
-            *out, self.k_scales, self.v_scales = out
-        if want_logprobs:
-            toks, lp, tids, tlp, hist_f, self.k_pages, self.v_pages = out
-            self._last_hist = hist_f if want_pen else None
-            return toks, (lp, tids, tlp)
-        toks, hist_f, self.k_pages, self.v_pages = out
-        self._last_hist = hist_f if want_pen else None
-        return toks
+        return self._multi_steps[sig]
 
     def step_multi_pipelined(
         self,
@@ -966,19 +1009,11 @@ class ModelRunner:
                 "kv_cache_dtype=int8 (the spec scan carries raw pool blocks)"
             )
         sig = (steps, spec_k, ngram)
-        if sig not in self._spec_fns:
-            self._spec_fns[sig] = self._jit(
-                _named_program(
-                    f"pstpu_spec_s{steps}_k{spec_k}_n{ngram}",
-                    _spec_fn, self._forward, self.cfg, steps, spec_k, ngram,
-                ),
-                (1, 2), (self._rep, None, None),
-            )
         s = self._stage(inp, with_limits=True)
         hist = jax.device_put(jnp.asarray(history, jnp.int32), self._row_sh) \
             if self.mesh.devices.size > 1 else np.asarray(history, np.int32)
         toks, self.k_pages, self.v_pages = self._dispatch(
-            self._spec_fns[sig], "spec_step", sig, s,
+            self._get_spec(*sig), "spec_step", sig, s,
             (
                 self.params, self.k_pages, self.v_pages, hist,
                 s["input_ids"], s["positions"], s["page_table"],
@@ -987,6 +1022,18 @@ class ModelRunner:
             ),
         )
         return toks
+
+    def _get_spec(self, steps: int, spec_k: int, ngram: int):
+        sig = (steps, spec_k, ngram)
+        if sig not in self._spec_fns:
+            self._spec_fns[sig] = self._jit(
+                _named_program(
+                    f"pstpu_spec_s{steps}_k{spec_k}_n{ngram}",
+                    _spec_fn, self._forward, self.cfg, steps, spec_k, ngram,
+                ),
+                (1, 2), (self._rep, None, None),
+            )
+        return self._spec_fns[sig]
 
     def encode(self, input_ids, positions) -> jnp.ndarray:
         """Pooled-embedding forward ([B, T] -> [B, H] unit vectors). Shapes

@@ -104,6 +104,12 @@ DASHBOARD_ALLOWLIST = {
     "vllm:step_program_store_hits_total",    # how often a first dispatch found
     "vllm:step_program_store_writes_total",  # its exported program: the same
     "vllm:step_program_store_errors_total",  # start-up and bench surface
+    "vllm:step_program_preload_listed",      # what the loader built of the
+    "vllm:step_program_preloaded_total",     # store's listing at start-up,
+    "vllm:step_program_preload_failed_total",    # and how many first
+    "vllm:step_program_preload_served_total",    # dispatches took one: the
+    "vllm:step_program_preload_seconds",     # same start-up and bench surface
+    "vllm:step_program_preload_pending_at_first_dispatch",
     "vllm:ssm_state_slots",                  # a family with recurrent state
     "vllm:ssm_state_slots_in_use",           # (models/jamba.py) alone emits
     "vllm:ssm_state_bytes",                  # these; GET /stats shows them, no

@@ -53,6 +53,20 @@ enable_persistent_cache(
 
 import pytest  # noqa: E402
 
+from production_stack_tpu.engine.step_programs import StepProgramStore  # noqa: E402
+from production_stack_tpu.utils import compile_cache  # noqa: E402
+
+# The suite's runners share the store beside that cache, and a runner builds at
+# start-up every step program the store lists for its identity
+# (step_programs.Preloader): each of the suite's thousand runners would load, on
+# threads of its own, whatever every test before it dispatched. The SHARED
+# store lists nothing; the loader's tests give their runners a store of their
+# own (tests/test_step_program_store.py).
+_listed = StepProgramStore.listed
+StepProgramStore.listed = lambda self, identity: (
+    [] if self.root == compile_cache.step_program_dir() else _listed(self, identity)
+)
+
 
 @pytest.fixture(autouse=True, scope="module")
 def _bounded_memory_maps():

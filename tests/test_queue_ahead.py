@@ -430,3 +430,69 @@ def test_a_resident_row_the_host_stages_refuses_the_plan_and_a_pinned_row_keeps_
     assert kv.num_free() == free and a.pages and a.release_pending
     sched.retire(burst)
     assert not a.pages and kv.num_free() > free and not a.release_pending
+
+
+def _engine_beside(store_dir, name, tp=1):
+    """An engine whose runner finds ``store_dir`` as the step-program store
+    (and so builds what it lists as it starts), the loader finished."""
+    from production_stack_tpu.engine.step_programs import StepProgramStore
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(StepProgramStore, "beside_compile_cache",
+                   classmethod(lambda cls: StepProgramStore(str(store_dir))))
+        eng = LLMEngine(EngineConfig(
+            model=name, max_model_len=256, max_num_seqs=4, num_pages=PAGES, page_size=8,
+            prefill_chunk=32, kv_cache_memory_gb=0.01, enable_prefix_caching=False,
+            tensor_parallel_size=tp))
+    assert eng.runner.preloaded.wait(120)
+    eng.start()
+    return eng
+
+
+@pytest.mark.parametrize("family,tp", [*((f, 1) for f in sorted(FAMILIES)), ("llama", 2)])
+def test_a_preloaded_shape_still_goes_out_drained_and_its_event_says_preloaded(
+        tmp_path, family, tp):
+    """PR 47's invariant beside PR 50's loader: ``_shape_known`` means "has
+    been dispatched", so a shape whose executable was built at start-up still
+    goes out with nothing in flight, timed to its result, and is counted."""
+    module, preset = FAMILIES[family]
+    name = preset + "-preload-f32"
+    module.PRESETS[name] = dataclasses.replace(module.PRESETS[preset], dtype="float32")
+    try:
+        eng = _engine_beside(tmp_path, name, tp)
+        try:
+            want = _serve(eng)
+            assert eng.stats()["step_program_preload_listed"] == 0
+        finally:
+            eng.stop()
+        tracing.get_flightrecorder().reset()
+        eng = _engine_beside(tmp_path, name, tp)
+        try:
+            stats = eng.stats()
+            assert stats["step_program_preload_listed"] == stats["step_program_preloaded_total"] > 0
+            assert stats["step_program_preload_pending_at_first_dispatch"] is None
+            got = _serve(eng)
+            _everything_back(eng)
+            stats = eng.stats()
+        finally:
+            eng.stop()
+    finally:
+        del module.PRESETS[name]
+    assert [r["tokens"] for r in got] == [r["tokens"] for r in want]
+    served = stats["step_program_preload_served_total"]
+    assert served > 0 and stats["step_program_preload_failed_total"] == 0
+    assert stats["step_program_preload_pending_at_first_dispatch"] == 0
+    # every first dispatch, preloaded or not, followed a ``sched`` event that
+    # was NOT queued ahead, and nothing else ran beside it
+    events = [(e["kind"], e["data"]) for e in tracing.get_flightrecorder().events()]
+    firsts, sched = [], None
+    for kind, data in events:
+        if kind == "sched":
+            sched = data
+        elif kind == "compile" and data.get("event") == "first_dispatch":
+            assert sched is not None and sched["queued_ahead"] is False and sched["drain"]
+            firsts.append(data["store"])
+    assert firsts.count("preloaded") == served and set(firsts) <= {"preloaded", "hit", "write"}
+    assert len(firsts) == stats["first_dispatches_total"]
+    if set(firsts) == {"preloaded"}:  # (a batch formed otherwise meets a new shape)
+        assert stats["first_dispatch_compile_seconds_total"] == 0

@@ -355,6 +355,86 @@ def test_the_store_counters_stand_in_stats_and_in_metrics(engine, what):
     assert f'vllm:{name}{{model_name="mistral-debug"}} {stats[name]}' in text
 
 
+@pytest.fixture(scope="module")
+def preloaded(tmp_path_factory):
+    """(``/stats``, ``/metrics``) of an engine that started beside a store a
+    first engine of its identity had filled, after the same request."""
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from production_stack_tpu.engine.api_server import EngineServer
+    from production_stack_tpu.engine.step_programs import StepProgramStore
+
+    store_dir = str(tmp_path_factory.mktemp("store"))
+    cfg = EngineConfig(
+        model="mistral-debug", max_model_len=256, max_num_seqs=8, num_pages=64,
+        page_size=8, prefill_chunk=32, kv_cache_memory_gb=0.01)
+    for _ in range(2):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(StepProgramStore, "beside_compile_cache",
+                       classmethod(lambda cls: StepProgramStore(store_dir)))
+            eng = LLMEngine(cfg)
+        assert eng.runner.preloaded.wait(120)
+        eng.start()
+        try:
+            assert _generate(eng, "a first dispatch or two", 12).finish_reason == "length"
+            stats = eng.stats()
+
+            async def scrape():
+                async with TestClient(TestServer(EngineServer(cfg, eng).build_app())) as client:
+                    return await (await client.get("/metrics")).text()
+
+            text = asyncio.run(scrape())
+        finally:
+            eng.stop()
+    return stats, text
+
+
+@pytest.mark.parametrize("name,kind", [
+    ("step_program_preload_listed", "gauge"), ("step_program_preloaded_total", "counter"),
+    ("step_program_preload_failed_total", "counter"),
+    ("step_program_preload_served_total", "counter"),
+    ("step_program_preload_seconds", "gauge"),
+    ("step_program_preload_pending_at_first_dispatch", "gauge")])
+def test_the_loader_counters_stand_in_stats_and_in_metrics(preloaded, name, kind):
+    stats, text = preloaded
+    firsts = stats["first_dispatches_total"]
+    assert firsts >= 2 and stats["first_dispatch_compile_seconds_total"] == 0
+    assert stats["step_program_store_hits_total"] == stats["step_program_store_writes_total"] == 0
+    want = {"step_program_preload_listed": firsts, "step_program_preloaded_total": firsts,
+            "step_program_preload_failed_total": 0, "step_program_preload_served_total": firsts,
+            "step_program_preload_seconds": stats["step_program_preload_seconds"],
+            "step_program_preload_pending_at_first_dispatch": 0}
+    assert stats[name] == want[name] and 0 < stats["step_program_preload_seconds"] < 120
+    assert f"# TYPE vllm:{name} {kind}" in text
+    assert f'vllm:{name}{{model_name="mistral-debug"}} {stats[name]}' in text
+
+
+def test_before_a_first_dispatch_the_loader_says_nothing_of_it(engine):
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from production_stack_tpu.engine.api_server import EngineServer
+    from production_stack_tpu.engine.runner import ModelRunner
+
+    fresh = ModelRunner(llama.PRESETS["mistral-debug"], num_pages=16, page_size=8)
+    assert fresh.preloaded.stats()["step_program_preload_pending_at_first_dispatch"] is None
+
+    class NotYet:  # the engine's stats before anything was dispatched
+        def __getattr__(self, name):
+            return getattr(engine, name)
+
+        def stats(self):
+            return dict(engine.stats(), **fresh.preloaded.stats())
+
+    async def scrape():
+        cfg = EngineConfig(model="mistral-debug")
+        async with TestClient(TestServer(EngineServer(cfg, NotYet()).build_app())) as client:
+            return await (await client.get("/metrics")).text()
+
+    text = asyncio.run(scrape())
+    assert "vllm:step_program_preload_listed" in text
+    assert "step_program_preload_pending_at_first_dispatch" not in text
+
+
 @pytest.mark.parametrize("name,label", [("queued_ahead_dispatches_total", "kind"),
                                         ("queue_ahead_drains_total", "reason")])
 def test_the_queue_ahead_counters_stand_in_stats_and_in_metrics(engine, name, label):

@@ -8,6 +8,7 @@ import os
 import shutil
 import sys
 import threading
+import time
 
 import jax
 import numpy as np
@@ -307,3 +308,315 @@ def test_without_a_cache_directory_there_is_no_store_and_no_file(tmp_path, monke
     assert r.step_store is None
     r.step(_inp(2, 4))
     assert [e["store"] for e in _events()] == ["off"]
+
+
+# -- the loader: what the store lists for a runner's identity is built at start-up --
+# (ISSUE 50, step_programs.Preloader)
+
+def _beside(store_dir, cfg=CFG, wait=True, **kw):
+    """A runner that FINDS ``store_dir`` as the store beside its compile cache
+    (so its loader reads that store's listing as it is built), the loader
+    finished unless ``wait`` is False."""
+    kw = {"num_pages": 16, "page_size": 8, **kw}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(StepProgramStore, "beside_compile_cache",
+                   classmethod(lambda cls: StepProgramStore(str(store_dir))))
+        r = ModelRunner(cfg, seed=0, **kw)
+    assert r.step_store.root == r.preloaded.store.root == str(store_dir)
+    if wait:
+        assert r.preloaded.wait(120)
+    return r
+
+
+def _listing(store_dir):
+    """The listing's files, whatever identity they stand under."""
+    return sorted(
+        os.path.join(store_dir, d, f)
+        for d in os.listdir(store_dir) if d.endswith(step_programs.LISTING)
+        for f in os.listdir(os.path.join(store_dir, d)))
+
+
+def _listed_keys(store_dir):
+    """The listed programs' keys, in the order in which they were met."""
+    import json
+
+    entries = {os.path.basename(p)[:-len(".json")]: json.load(open(p))
+               for p in _listing(store_dir)}
+    return sorted(entries, key=lambda k: entries[k]["order"])
+
+
+def _state_inp(r, *a, **kw):
+    inp = _inp(*a, **kw)
+    if r.has_state:
+        inp.state_slots = np.arange(inp.kv_lens.shape[0], dtype=np.int32)
+    return inp
+
+
+def _serve_family(r):
+    """``_serve`` for any family: rows with state slots where it keeps state."""
+    ids, logits, lps = r.step(_state_inp(r, 2, 0, T=4), want_logprobs=True)
+    toks, blps = r.step_multi(_state_inp(r, 2, 4, k=4), 4, want_logprobs=True)
+    sampled = r.step_multi(_state_inp(r, 2, 8, k=4, temperature=0.8), 4)
+    one, _ = r.step(_state_inp(r, 2, 12, temperature=0.8))
+    return [np.asarray(x) for x in (ids, logits, *lps, toks, *blps, sampled, one)]
+
+
+def _family(name):
+    from production_stack_tpu.models import jamba, lfm2
+    from production_stack_tpu.parallel.mesh import make_mesh
+
+    return {
+        "llama": lambda: (CFG, {}),
+        "llama-tp2": lambda: (CFG, {"mesh": make_mesh(tp=2, devices=jax.devices()[:2])}),
+        "jamba": lambda: (jamba.PRESETS["jamba-debug"], {"state_slots": 4}),
+        "lfm2": lambda: (lfm2.PRESETS["lfm2-debug"], {"state_slots": 4}),
+    }[name]()
+
+
+@pytest.mark.parametrize("family", ["llama", "llama-tp2", "jamba", "lfm2"])
+def test_a_second_runner_beside_a_filled_store_serves_its_first_dispatches_preloaded(
+        tmp_path, family, traced):
+    cfg, kw = _family(family)
+    first = _beside(tmp_path, cfg, **kw)
+    assert first.preloaded.stats() == {
+        "step_program_preload_listed": 0, "step_program_preloaded_total": 0,
+        "step_program_preload_failed_total": 0, "step_program_preload_served_total": 0,
+        "step_program_preload_seconds": 0.0,
+        "step_program_preload_pending_at_first_dispatch": None}
+    served = _serve_family(first)
+    assert first.step_store.writes == 4 and len(_listing(tmp_path)) == 4
+    assert first.preloaded.pending_at_first_dispatch == 0 and not first.preloaded._threads
+    # the un-preloaded path of a process that finds the blobs: today's "hit"
+    tracing.get_flightrecorder().reset()
+    plain = _runner(tmp_path, cfg, **kw)
+    hit = _serve_family(plain)
+    assert [e["store"] for e in _events()] == ["hit"] * 4
+    # and a runner that found the listing as it started
+    tracing.get_flightrecorder().reset()
+    r = _beside(tmp_path, cfg, **kw)
+    pre = r.preloaded
+    assert (pre.listed, pre.loaded, pre.failed, pre.served) == (4, 4, 0, 0)
+    traced.names.clear()
+    traced.on = True
+    try:
+        again = _serve_family(r)
+    finally:
+        traced.on = False
+    for a, b, c in zip(served, hit, again):
+        assert a.dtype == b.dtype == c.dtype
+        assert np.array_equal(a, b) and np.array_equal(a, c)
+    events = _events()
+    assert [e["store"] for e in events] == ["preloaded"] * 4
+    # nothing was traced, lowered, compiled or read from a cache on the
+    # dispatching thread, and the store was not asked
+    fd = r.first_dispatch
+    assert fd["count"] == 4 and fd["trace"] == fd["lower"] == fd["compile"] == 0.0
+    assert all(e["cache"] == "none" and e["compile_s"] == 0 for e in events)
+    assert not [n for n in traced.names if n and n.startswith("pstpu_")]
+    store = r.step_store
+    assert store.hits == store.writes == store.errors == 0
+    assert pre.stats() == {
+        "step_program_preload_listed": 4, "step_program_preloaded_total": 4,
+        "step_program_preload_failed_total": 0, "step_program_preload_served_total": 4,
+        "step_program_preload_seconds": pre.stats()["step_program_preload_seconds"],
+        "step_program_preload_pending_at_first_dispatch": 0}
+    assert 0 < pre.stats()["step_program_preload_seconds"] < 120
+    # first-met order, as the first runner met them
+    assert [p.name for p in pre._queue] == [
+        "pstpu_step_lp", "pstpu_multi_step_k4_lp", "pstpu_multi_step_k4", "pstpu_step"]
+    # a dispatched shape runs its executable from then on, and a shape nobody
+    # has met is built and listed as before
+    r.step(_state_inp(r, 2, 13, temperature=0.8))
+    r.step(_state_inp(r, 4, 4))
+    assert fd["count"] == 5 and store.writes == 1 and len(_listing(tmp_path)) == 5
+    assert _events()[-1]["store"] == "write"
+
+
+@pytest.mark.parametrize("variant", [
+    dict(cfg=dataclasses.replace(CFG, rope_theta=5e5)),
+    dict(page_size=16),
+    dict(num_pages=32),
+    dict(mesh="tp2"),
+    dict(digest="another package"),
+    dict(processes=2),
+], ids=["config-field", "page-size", "pool-size", "mesh", "package-digest", "processes"])
+def test_another_identity_preloads_nothing(written, monkeypatch, variant):
+    d, served, _ = written
+    _runner(d).step(_inp(2, 4))  # a hit lists what the write of an older tree did not
+    assert _beside(d).preloaded.listed >= 1  # the control: this identity is listed
+    before = _listing(d)
+    variant = dict(variant)
+    if "digest" in variant:
+        monkeypatch.setattr(step_programs, "package_digest",
+                            lambda digest=variant.pop("digest"): digest)
+    if "processes" in variant:
+        monkeypatch.setattr(jax, "process_count", lambda n=variant.pop("processes"): n)
+    if "mesh" in variant:
+        from production_stack_tpu.parallel.mesh import make_mesh
+
+        variant["mesh"] = make_mesh(tp=2, devices=jax.devices()[:2])
+    r = _beside(d, variant.pop("cfg", CFG), **variant)
+    pre = r.preloaded
+    assert (pre.listed, pre.loaded, pre.failed) == (0, 0, 0) and not pre._threads
+    tracing.get_flightrecorder().reset()
+    r.step(_inp(2, 4))
+    assert [e["store"] for e in _events()] == ["write"] and pre.served == 0
+    assert set(before) < set(_listing(d))
+
+
+def test_a_mesh_over_several_processes_lists_and_preloads_nothing(tmp_path, monkeypatch):
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    first, _ = _beside(tmp_path).step(_inp(2, 4))
+    assert len(_listing(tmp_path)) == 1  # listed under its identity all the same
+    tracing.get_flightrecorder().reset()
+    r = _beside(tmp_path)
+    assert r.preloaded.listed == 0 and not r.preloaded._threads
+    again, _ = r.step(_inp(2, 4))
+    assert np.array_equal(np.asarray(first), np.asarray(again))
+    assert [e["store"] for e in _events()] == ["hit"]
+
+
+def test_an_empty_store_and_no_store_preload_nothing(tmp_path, monkeypatch):
+    r = _beside(tmp_path)
+    assert r.preloaded.listed == 0 and not r.preloaded._threads and not os.listdir(tmp_path)
+    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+    r = ModelRunner(CFG, num_pages=16, page_size=8, seed=0)
+    assert r.step_store is None and r.preloaded.store is None
+    assert r.preloaded.listed == 0 and not r.preloaded._threads
+    r.step(_inp(2, 4))
+    assert r.preloaded.stats()["step_program_preload_served_total"] == 0
+
+
+def test_a_truncated_blob_and_a_stale_entry_are_discarded_counted_and_answered(tmp_path):
+    r = _beside(tmp_path)
+    first, _ = r.step(_inp(2, 4))
+    burst = np.asarray(r.step_multi(_inp(2, 5, k=4), 4))
+    r.step(_inp(4, 4))
+    stale, truncated, whole = _listed_keys(tmp_path)
+    os.unlink(tmp_path / (stale + SUFFIX))
+    blob = (tmp_path / (truncated + SUFFIX)).read_bytes()
+    (tmp_path / (truncated + SUFFIX)).write_bytes(blob[: len(blob) // 2])
+    with open(os.path.join(os.path.dirname(_listing(tmp_path)[0]), "0" * 64 + ".json"), "w") as f:
+        f.write('{"program": "pstpu_step", "family": "step"')  # half an entry
+    tracing.get_flightrecorder().reset()
+    r = _beside(tmp_path)
+    pre, store = r.preloaded, r.step_store
+    assert (pre.listed, pre.loaded, pre.failed) == (3, 1, 2)
+    assert store.errors == 2  # the blob that did not deserialise, the half entry
+    assert not (tmp_path / (truncated + SUFFIX)).exists()
+    assert _listed_keys(tmp_path) == [whole]
+    again, _ = r.step(_inp(2, 4))
+    assert np.array_equal(np.asarray(first), np.asarray(again))
+    assert np.array_equal(burst, np.asarray(r.step_multi(_inp(2, 5, k=4), 4)))
+    r.step(_inp(4, 4))
+    assert [e["store"] for e in _events()] == ["write", "write", "preloaded"]
+    assert (pre.served, pre.failed, store.writes) == (1, 2, 2)
+    # what served is listed again, with its blob
+    assert len(_listing(tmp_path)) == len(_files(tmp_path)) == 3
+    r = _beside(tmp_path)
+    assert (r.preloaded.listed, r.preloaded.loaded, r.preloaded.failed) == (3, 3, 0)
+
+
+def test_an_entry_the_runner_does_not_build_and_a_program_refused_at_its_call(tmp_path):
+    r = _beside(tmp_path)
+    first, _ = r.step(_inp(2, 4))
+    r.step(_inp(4, 4))
+    small, large = _listed_keys(tmp_path)
+    # the 4-row program under the 2-row program's key: it builds, and its call
+    # refuses the 2-row batch before anything runs
+    shutil.copyfile(tmp_path / (large + SUFFIX), tmp_path / (small + SUFFIX))
+    for sig, name in (('["x", 3]', "pstpu_step"), ("[false, false]", "pstpu_other"),
+                      ("[1, 2, 3, 4]", "pstpu_step")):
+        other = os.path.join(os.path.dirname(_listing(tmp_path)[0]), f"{len(sig):064d}.json")
+        with open(other, "w") as f:
+            f.write('{"program": "%s", "family": "step", "sig": %s, "order": 9}' % (name, sig))
+    tracing.get_flightrecorder().reset()
+    r = _beside(tmp_path)
+    pre, store = r.preloaded, r.step_store
+    assert (pre.listed, pre.loaded, pre.failed) == (5, 2, 3)
+    again, _ = r.step(_inp(2, 4))
+    assert np.array_equal(np.asarray(first), np.asarray(again))
+    # refused at the call and counted; then the blob itself is judged as
+    # before: hit, its call does not lower, deleted, exported again
+    assert (pre.served, pre.failed, store.hits, store.writes, store.errors) == (0, 4, 1, 1, 1)
+    assert [e["store"] for e in _events()] == ["error"]
+    assert r.first_dispatch["count"] == 1 and not store.bypassed
+    r.step(_inp(4, 4))
+    assert pre.served == 1 and [e["store"] for e in _events()] == ["error", "preloaded"]
+    assert sorted(_listed_keys(tmp_path)) == sorted([small, large])
+
+
+def test_a_dispatch_that_arrives_mid_load_waits_and_nothing_is_built_twice(tmp_path, monkeypatch):
+    r = _beside(tmp_path)
+    want = [np.asarray(r.step(_inp(B, 4))[0]) for B in (1, 2, 4)]
+    monkeypatch.setattr(step_programs.Preloader, "WORKERS", 1)
+    build, built = step_programs.Preloader._build, []
+    entered = [threading.Event() for _ in want]
+    gates = [threading.Event() for _ in want]
+
+    def slow(self, listed):
+        built.append(listed.key)
+        entered[len(built) - 1].set()
+        assert gates[len(built) - 1].wait(60)
+        return build(self, listed)
+
+    monkeypatch.setattr(step_programs.Preloader, "_build", slow)
+    tracing.get_flightrecorder().reset()
+    r = _beside(tmp_path, wait=False)
+    pre = r.preloaded
+    assert entered[0].wait(60)
+    assert [p.state for p in pre._queue] == ["loading", "queued", "queued"]
+    # the first listed program is being built: its dispatch waits for that build
+    threading.Timer(0.5, gates[0].set).start()
+    t0 = time.perf_counter()
+    got = [np.asarray(r.step(_inp(1, 4))[0])]
+    assert time.perf_counter() - t0 > 0.4 and pre.pending_at_first_dispatch == 3
+    # the worker stands in the second; the third is still queued: its dispatch
+    # builds it itself ("hit", as a process that preloaded nothing would), and
+    # no worker will
+    assert entered[1].wait(60)
+    got.append(np.asarray(r.step(_inp(4, 4))[0]))
+    assert [p.state for p in pre._queue] == ["taken", "loading", "taken"]
+    gates[1].set()
+    got.insert(1, np.asarray(r.step(_inp(2, 4))[0]))
+    for a, b in zip(want, got):
+        assert np.array_equal(a, b)
+    assert pre.wait(60) and built == [p.key for p in pre._queue[:2]]
+    assert [e["store"] for e in _events()] == ["preloaded", "hit", "preloaded"]
+    assert (pre.loaded, pre.served, pre.failed) == (2, 2, 0)
+    assert (r.step_store.hits, r.step_store.writes) == (1, 0)
+    assert _events()[0]["run_s"] > 0.4 and _events()[0]["compile_s"] == 0
+
+
+def test_many_dispatches_race_the_workers_and_each_program_is_built_once(tmp_path, monkeypatch):
+    shapes = [(B, ctx) for B in (1, 2, 4, 8) for ctx in (4, 12)]
+
+    def serve(r):
+        return [np.asarray(r.step(_inp(B, ctx))[0]) for B, ctx in shapes]
+
+    serve(_beside(tmp_path))
+    want = serve(_runner(tmp_path))
+    build, built = step_programs.Preloader._build, []
+
+    def counted(self, listed):
+        built.append(listed.key)
+        return build(self, listed)
+
+    monkeypatch.setattr(step_programs.Preloader, "_build", counted)
+    monkeypatch.setattr(step_programs.Preloader, "WORKERS", 16)  # more than the programs
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        r = _beside(tmp_path, wait=False)
+        got = serve(r)
+        assert r.preloaded.wait(120)
+    finally:
+        sys.setswitchinterval(interval)
+    for a, b in zip(want, got):
+        assert np.array_equal(a, b)
+    pre, store = r.preloaded, r.step_store
+    # 4 programs (the context does not name a shape): each was built by a
+    # worker or by its dispatch, never by both, and each dispatch was served
+    assert pre.listed == 4 and len(built) == len(set(built)) == pre.loaded
+    assert pre.loaded + store.hits == 4 and pre.served == pre.loaded and pre.failed == 0
+    assert r.first_dispatch["count"] == 4 and store.writes == 0
