@@ -928,6 +928,9 @@ class EngineServer:
                  "state slots held by admitted sequences")
             emit("ssm_state_bytes", "gauge", s["ssm_state_bytes"],
                  "bytes of the recurrent-state pool, the null slot included")
+            emit("ssm_state_bytes_per_slot", "gauge",
+                 s["ssm_state_bytes_per_slot"],
+                 "bytes one running sequence keeps beside its pages")
             emit("ssm_prefill_tokens_total", "counter",
                  s["ssm_prefill_tokens_total"],
                  "prompt tokens the selective scan walked in prefill chunks")
@@ -944,6 +947,16 @@ class EngineServer:
                  "experts with at least one row, over expert layers and steps")
             emit("moe_expert_slots_total", "counter", s["moe_expert_slots_total"],
                  "experts held, over expert layers and steps")
+        if "ssd_decode_tokens_total" in s:
+            # Mamba-2 layers (models/nemotron_h.py), counted by the device
+            emit("ssd_decode_tokens_total", "counter", s["ssd_decode_tokens_total"],
+                 "output tokens the SSD layers stepped in decode bursts")
+            emit("ssd_prefill_tokens_total", "counter", s["ssd_prefill_tokens_total"],
+                 "prompt tokens the SSD layers walked in chunks")
+            emit("ssd_prefill_chunks_total", "counter", s["ssd_prefill_chunks_total"],
+                 "chunks of chunk_size positions those tokens lay in")
+            emit("ssd_prefill_rows_total", "counter", s["ssd_prefill_rows_total"],
+                 "rows of prefill dispatches: a state in and out once a layer")
         emit("first_dispatches_total", "counter",
              s.get("first_dispatches_total", 0),
              "step-program shapes dispatched for the first time in this process")

@@ -2960,9 +2960,11 @@ class LLMEngine:
             # recurrent-state pool, the scan's work, the implementation the
             # platform resolved to, and what this family switches off or
             # refuses to start with, each with its reason
-            out["ssm_state_slots"] = self.kv.state_slots
+            # seats and bytes from the one place the start-up line reads
+            # (runner.state_report); the page manager deals the same seats
+            out.update(self.runner.state_report())
+            assert out["ssm_state_slots"] == self.kv.state_slots
             out["ssm_state_slots_in_use"] = self.kv.slots_in_use()
-            out["ssm_state_bytes"] = self.runner.state_pool_bytes()
             out["conv_state_bytes"] = self.runner.conv_state_bytes
             out["ssm_prefill_tokens_total"] = self.ssm_prefill_tokens_total
             out["ssm_decode_tokens_total"] = self.ssm_decode_tokens_total

@@ -367,10 +367,12 @@ class ModelRunner:
                 cfg = dataclasses.replace(cfg, ssm_impl=self.ssm_impl)
                 self.cfg = cfg
             self.state_slots = int(state_slots or max_batch or 8)
+            report = self.state_report()
             logger.info(
-                "recurrent state: %d slots of %d bytes; selective scan: %s%s",
-                self.state_slots, cfg.state_bytes_per_slot,
-                self.ssm_impl or "none",
+                "recurrent state: %d seats (+ the null slot) of %d bytes = %d "
+                "bytes; selective scan: %s%s",
+                report["ssm_state_slots"], report["ssm_state_bytes_per_slot"],
+                report["ssm_state_bytes"], self.ssm_impl or "none",
                 f" ({self.ssm_reason})" if self.ssm_reason else "",
             )
         # int32 counters of what the device did in a dispatch (models/lfm2.py:
@@ -1511,6 +1513,19 @@ class ModelRunner:
             return 0
         return (self.state_slots + 1) * self.cfg.state_bytes_per_slot
 
+    def state_report(self) -> dict:
+        """Seats and bytes of the recurrent-state pool, for the start-up log
+        line and ``/stats`` alike, every stateful family from the one number
+        its configuration states (``state_bytes_per_slot``): the seats (one a
+        running sequence: ``--max-num-seqs``, and where a slot is tens of MB
+        it is the state's bytes that bound them), a seat's bytes, the pool's
+        with its null slot. ``reset_kv`` holds the pools it allocates to it."""
+        return {
+            "ssm_state_slots": self.state_slots,
+            "ssm_state_bytes_per_slot": int(self.cfg.state_bytes_per_slot),
+            "ssm_state_bytes": self.state_pool_bytes(),
+        }
+
     @property
     def conv_state_bytes(self) -> int:
         """Bytes of the convolution tails in the state pool, the null slot
@@ -1588,6 +1603,14 @@ class ModelRunner:
                 # whole on the one device the family serves on
                 out_shardings=NamedSharding(self.mesh, P()),
             )()
+            held = sum(int(a.nbytes) for a in jax.tree.leaves(self.state))
+            if held != self.state_pool_bytes():
+                raise ValueError(
+                    f"{self.module.__name__}.init_state allocated {held} "
+                    f"bytes for {self.state_slots} seats + the null slot; its "
+                    f"configuration states {self.cfg.state_bytes_per_slot} a "
+                    "seat (state_bytes_per_slot)"
+                )
         self.k_scales = self.v_scales = None
         if self.kv_quant:
             KH = getattr(self.cfg, "num_kv_heads", 1)

@@ -12,14 +12,16 @@ engine/runner.py and engine/model_loader.py:
 - ``Config.num_kv_layers``: the layers that hold pages (``num_layers`` counts
   the model's; the two differ where not every layer attends)
 - optionally ``init_state(cfg, slots)``: a family that keeps recurrent state
-  beside the pages (models/jamba.py, models/lfm2.py); its ``forward`` takes
+  beside the pages (models/jamba.py, models/lfm2.py, models/nemotron_h.py);
+  its ``forward`` takes
   ``state=`` and ``state_slots=`` and returns the updated state as a fourth
   value
 - optionally ``Config.step_counters`` (a count) with ``counter_stats(cfg,
   totals)``: ``forward`` returns that many int32 as the LAST element of its
   result, whatever else it returns: what this call did on the device, which
   the step programs sum over a burst and hand out beside the tokens
-  (models/lfm2.py: what the expert layers routed)
+  (models/lfm2.py: what the expert layers routed; models/nemotron_h.py: that
+  and the tokens its SSD layers walked)
 
 Sharding specs are name-based (parallel/shardings.py) so new families only
 need to reuse the leaf-name vocabulary or extend the spec tables.
@@ -27,10 +29,10 @@ need to reuse the leaf-name vocabulary or extend the spec tables.
 
 from __future__ import annotations
 
-from production_stack_tpu.models import gemma2, jamba, lfm2, llama, opt
+from production_stack_tpu.models import gemma2, jamba, lfm2, llama, nemotron_h, opt
 
 #: module search order for preset names and HF architectures
-MODULES = (llama, opt, gemma2, jamba, lfm2)
+MODULES = (llama, opt, gemma2, jamba, lfm2, nemotron_h)
 
 _ARCH_TO_MODULE = {
     "LlamaForCausalLM": llama,
@@ -41,6 +43,7 @@ _ARCH_TO_MODULE = {
     "Gemma2ForCausalLM": gemma2,
     "JambaForCausalLM": jamba,
     "Lfm2MoeForCausalLM": lfm2,
+    "NemotronHForCausalLM": nemotron_h,
 }
 
 
@@ -66,6 +69,8 @@ def module_for_config(cfg):
         return jamba
     if isinstance(cfg, lfm2.Lfm2Config):
         return lfm2
+    if isinstance(cfg, nemotron_h.NemotronHConfig):
+        return nemotron_h
     raise ValueError(f"unknown model config type {type(cfg).__name__}")
 
 

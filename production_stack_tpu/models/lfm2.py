@@ -80,8 +80,9 @@ class Lfm2Config:
     norm_topk_prob: bool = True
     use_expert_bias: bool = True
     routed_scaling_factor: float = 1.0
-    # the chip's share of every expert layer: (first, count); None = all. No
-    # published key: a deployment that spreads its experts sets it (ROADMAP M1)
+    # the chip's share of every expert layer: (first, count); None = all; the
+    # parameter stacks hold the experts held and no others. No published key:
+    # a deployment that spreads its experts sets it (ROADMAP M1)
     experts_held: Optional[tuple[int, int]] = None
     num_heads: int = 32
     num_kv_heads: int = 8
@@ -237,6 +238,7 @@ def init_params(cfg: Lfm2Config, key: jax.Array) -> dict:
     NH, KH, D, E = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.num_experts
     Lc, La = cfg.num_conv_layers, cfg.num_kv_layers
     Ld, Lm = cfg.num_dense_layers, cfg.num_moe_layers
+    held = cfg.experts_held[1] if cfg.experts_held else E
 
     def normal(key, shape, scale, dtype=cfg.dtype):
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
@@ -249,7 +251,7 @@ def init_params(cfg: Lfm2Config, key: jax.Array) -> dict:
         return (scale * (
             (1.0 - rho**2) ** 0.5
             * jax.random.normal(shared, (Lm, 1) + shape, jnp.float32)
-            + rho * jax.random.normal(own, (Lm, E) + shape, jnp.float32)
+            + rho * jax.random.normal(own, (Lm, held) + shape, jnp.float32)
         )).astype(cfg.dtype)
 
     kc = jax.random.split(k_conv, 3)

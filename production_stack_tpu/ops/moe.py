@@ -5,13 +5,15 @@ sum back (ROADMAP M1 / D7).
 ``route`` scores every token against every expert (sigmoid scores in float32,
 a selection bias that decides the SELECTION only, top-k, renormalised).
 ``expert_ffn`` sorts the ``tokens x k`` assignments by expert and runs the
-experts' SwiGLU as two grouped products (``[gate | up]`` fused, then ``down``)
-over rows that lie expert by expert: no ``[tokens, experts, width]``
-intermediate, no loop over experts in the program, and an expert with no row
-is never read. ``experts_held = (first, count)`` is the chip's share of a
-layer (model-configs guide, section 4): the router keeps its published width,
-an assignment to an expert not held contributes nothing, and the shares of a
-layer add up to the whole layer.
+experts' feed-forward as two grouped products over rows that lie expert by
+expert: no ``[tokens, experts, width]`` intermediate, no loop over experts in
+the program, and an expert with no row is never read. The expert's FORM is a
+static argument of the one function: ``swiglu`` (``[gate | up]`` fused, then
+``down``: models/lfm2.py) or ``relu2`` (``down(relu(up x)^2)``, no gate, two
+matrices an expert: models/nemotron_h.py). ``experts_held = (first, count)``
+is the chip's share of a layer (model-configs guide, section 4): the router
+keeps its published width, an assignment to an expert not held contributes
+nothing, and the shares of a layer add up to the whole layer.
 
 The grouped product is ``grouped_matmul``: the Pallas kernel ``moe_grouped``
 on a TPU (the name the device trace shows), ``jax.lax.ragged_dot`` elsewhere.
@@ -94,7 +96,11 @@ def _tile_cols(n: int) -> int:
     for tn in range(min(n, TILE_COLS), 0, -128):
         if n % tn == 0 and tn % 128 == 0:
             return tn
-    return n  # a toy width under 128 lanes: one block
+    # no multiple of 128 lanes divides the width (a toy's): ONE block of the
+    # whole width. A real width that is no whole number of lane tiles
+    # (Nemotron-H's experts: 1,856 = 14.5 x 128) is STORED padded to one
+    # (models/nemotron_h.py ``expert_cols``) and never comes here
+    return n
 
 
 def tile_rows(rows: int) -> int:
@@ -203,15 +209,23 @@ def grouped_matmul(lhs, rhs, group_sizes, base, *, out_dtype=None,
 
 
 def expert_ffn(h, experts, weights, w13, w2, layer, *, num_experts: int,
-               experts_held=None, valid=None, impl: str = "xla"):
-    """The routed experts' SwiGLU for tokens ``h`` [N, H].
+               experts_held=None, valid=None, impl: str = "xla",
+               form: str = "swiglu"):
+    """The routed experts' feed-forward for tokens ``h`` [N, H].
 
-    ``experts`` / ``weights`` [N, k] from ``route``; ``w13`` ``[layers * E, H,
-    2 I]`` (gate | up) and ``w2`` ``[layers * E, I, H]`` the layer-stacked
-    expert weights, ``layer`` this layer's index in the stack (a scalar, may
-    be traced); ``experts_held`` (first, count), default all; ``valid`` [N]
-    marks real tokens (padding is routed nowhere). Returns (out [N, H]
-    float32, counters int32 [E + 2])."""
+    ``experts`` / ``weights`` [N, k] from ``route``; ``w13`` and ``w2`` ``[S,
+    ...]`` the layer-stacked expert weights, ``layer`` this layer's index in
+    the stack (a scalar, may be traced): with ``form="swiglu"`` ``w13`` is
+    ``[S, H, 2 I]`` (gate | up) and an expert is ``w2 (silu(gate) * up)``; with
+    ``form="relu2"`` it is ``[S, H, >= I]`` (up alone; columns past ``I`` are
+    padding to whole lane tiles) and an expert is ``w2 relu(up)^2``. ``w2`` is
+    ``[S, I, H]``. ``experts_held`` (first, count), default all
+    ``num_experts``, is the chip's share of the router's experts, and the
+    stacks hold the experts held and no others (``S = layers x count``).
+    ``valid`` [N] marks real tokens (padding is routed nowhere). Returns (out
+    [N, H] float32, counters int32 [E + 2])."""
+    if form not in ("swiglu", "relu2"):
+        raise ValueError(f"unknown expert form {form!r}")
     N, K = experts.shape
     first, count = experts_held or (0, num_experts)
     inter = w2.shape[1]
@@ -230,13 +244,20 @@ def expert_ffn(h, experts, weights, w13, w2, layer, *, num_experts: int,
         sizes = jnp.sum(
             group[:, None] == jnp.arange(count, dtype=jnp.int32)[None, :], axis=0
         ).astype(jnp.int32)
-        base = layer * num_experts + first
+        # where this layer's held experts start in the stacks
+        base = layer * count
         x = jnp.take(h, jnp.minimum(order // K, N - 1), axis=0)
-        gate_up = grouped_matmul(x, w13, sizes, base, impl=impl)
-        act = (
-            jax.nn.silu(gate_up[:, :inter].astype(jnp.float32))
-            * gate_up[:, inter:].astype(jnp.float32)
-        ).astype(h.dtype)
+        up = grouped_matmul(x, w13, sizes, base, impl=impl)
+        if form == "swiglu":
+            act = (
+                jax.nn.silu(up[:, :inter].astype(jnp.float32))
+                * up[:, inter:].astype(jnp.float32)
+            ).astype(h.dtype)
+        else:
+            # (columns past the expert's width are the stack's padding)
+            act = jnp.square(
+                jax.nn.relu(up[:, :inter].astype(jnp.float32))
+            ).astype(h.dtype)
         y = grouped_matmul(act, w2, sizes, base, out_dtype=jnp.float32, impl=impl)
         # back to token order: where assignment a went in the sorted block
         where = jnp.zeros((rows,), jnp.int32).at[order].set(

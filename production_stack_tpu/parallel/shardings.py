@@ -83,6 +83,11 @@ _LAYER_SPECS = {
     "expert_bias": P(None),
     "w13": P(None, None, None),
     "w2": P(None, None, None),
+    # Nemotron-H (models/nemotron_h.py): replicated, one chip; Mamba-2's
+    # per-head scalars, its gated norm, and ungated experts ([E, H, I] up)
+    "a_log_head": P(None),
+    "gate_norm": P(None),
+    "w1": P(None, None, None),
 }
 
 # [L, P, page_size, KH, D] pools: shard kv heads over tp.

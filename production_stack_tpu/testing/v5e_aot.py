@@ -207,7 +207,7 @@ def _step_program(cfg, *, tp, B, T, max_pages, num_pages, page_size,
 
 def compile_step_program(
     cfg, *, tp=1, B=4, T=512, max_pages=64, num_pages=512, page_size=64,
-    decode_steps=0, report=None,
+    decode_steps=0, report=None, state_slots=64,
 ):
     """AOT-compile the serving step ModelRunner would dispatch for ``cfg`` on
     a v5e mesh of ``tp`` chips, the way ``runner._dispatch`` runs it: exported,
@@ -221,7 +221,7 @@ def compile_step_program(
 
     jitted, kw, args = _step_program(
         cfg, tp=tp, B=B, T=T, max_pages=max_pages, num_pages=num_pages,
-        page_size=page_size, decode_steps=decode_steps,
+        page_size=page_size, decode_steps=decode_steps, state_slots=state_slots,
     )
     blob = jax_export.export(jitted, platforms=["tpu"])(*args).serialize()
     exported = jax_export.deserialize(blob)

@@ -115,7 +115,12 @@ DASHBOARD_ALLOWLIST = {
     "vllm:ssm_state_bytes",                  # these; GET /stats shows them, no
     "vllm:ssm_prefill_tokens_total",         # dashboard and no benchmark
     "vllm:ssm_decode_tokens_total",          # reader reads them yet
+    "vllm:ssm_state_bytes_per_slot",         # the same: a seat's bytes
     "vllm:conv_state_bytes",                 # the same families' conv tails
+    "vllm:ssd_decode_tokens_total",          # Mamba-2 layers (models/
+    "vllm:ssd_prefill_tokens_total",         # nemotron_h.py), counted by the
+    "vllm:ssd_prefill_chunks_total",         # device; /stats and the
+    "vllm:ssd_prefill_rows_total",           # benchmark's SSD rooflines
     "vllm:moe_routed_rows_total",            # sparse experts (models/lfm2.py):
     "vllm:moe_expert_reads_total",           # the benchmark reads them from
     "vllm:moe_expert_slots_total",           # GET /stats; no dashboard yet

@@ -354,7 +354,9 @@ class TestPublisherWire:
             while time.time() < deadline:
                 d = _dir_dump(cache_server)
                 eng = d.get("engines", {}).get("http://e:1") or {}
-                if eng.get("resident_chunks") == 3:
+                # the publisher counts a withdrawal once its request has
+                # RETURNED: the server can show it a moment earlier
+                if eng.get("resident_chunks") == 3 and pub.withdrawals == 1:
                     break
                 time.sleep(0.1)
             d = _dir_dump(cache_server)

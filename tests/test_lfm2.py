@@ -127,8 +127,11 @@ def test_forward_in_chunks_then_steps_agrees_with_the_reference(toy, impl):
 def test_a_share_of_the_experts_agrees_with_the_reference_given_the_same_share(toy):
     cfg, params = toy
     held = (2, 4)
+    # the program's stacks hold the experts held and no others
+    share = dict(params, moe_ffn=dict(
+        params["moe_ffn"], **{n: params["moe_ffn"][n][:, 2:6] for n in ("w13", "w2")}))
     served = served_logprobs(
-        dataclasses.replace(cfg, experts_held=held), params, TOKENS, N_PROMPT)
+        dataclasses.replace(cfg, experts_held=held), share, TOKENS, N_PROMPT)
     top, whole = worst_against_reference(
         served, params, TOKENS, N_PROMPT, experts_held=held)
     assert whole < TOLERANCE, (top, whole)
@@ -250,8 +253,12 @@ def test_the_shares_add_up_to_the_uncut_layer(impl):
     h, experts, weights, w13, w2, router = _expert_layer(40, seed=4, bias=bias)
     layer = jnp.int32(2)
     whole, counted = moe.expert_ffn(h, experts, weights, w13, w2, layer, num_experts=E, impl=impl)
-    shares = [moe.expert_ffn(h, experts, weights, w13, w2, layer, num_experts=E,
-                             experts_held=(first, 2), impl=impl)
+    # a share's stacks hold its 2 experts of each of the 3 layers
+    stacked = [w.reshape((3, E) + w.shape[1:]) for w in (w13, w2)]
+    shares = [moe.expert_ffn(
+        h, experts, weights,
+        *(w[:, first:first + 2].reshape((-1,) + w.shape[2:]) for w in stacked),
+        layer, num_experts=E, experts_held=(first, 2), impl=impl)
               for first in range(0, E, 2)]
     np.testing.assert_allclose(sum(s[0] for s in shares), whole, atol=2e-5, rtol=1e-5)
     assert list(sum(s[1] for s in shares)) == list(counted)
