@@ -906,6 +906,14 @@ class EngineServer:
         emit("decode_kv_tokens_read_total", "counter",
              s.get("decode_kv_tokens_read_total", 0),
              "KV tokens the decoded tokens attended (min(context, window) each)")
+        for name, help_ in (
+            ("prefill_dispatches_total", "prefill dispatches planned"),
+            ("prefill_rider_dispatches_total",
+             "prefill dispatches in which the running decode rows took one step"),
+            ("prefill_rider_rows_total",
+             "decode rows that took a step inside a prefill dispatch (a token each)"),
+        ):
+            emit(name, "counter", s.get(name, 0), help_)
         # one dispatch queued behind the one that runs (engine._turn): how
         # many went out that way, and what had emptied the loop for the rest
         for name, label, help_ in (
@@ -913,6 +921,8 @@ class EngineServer:
              "dispatches enqueued while the one before them still ran"),
             ("queue_ahead_drains_total", "reason",
              "dispatches that found the device idle, by what emptied the loop"),
+            ("prefill_riderless_dispatches_total", "reason",
+             "prefill dispatches planned while decode rows ran that carried none"),
         ):
             lines.append(f"# HELP vllm:{name} {help_}")
             lines.append(f"# TYPE vllm:{name} counter")

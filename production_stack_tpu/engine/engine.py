@@ -33,7 +33,9 @@ from production_stack_tpu.engine.runner import (
 )
 from production_stack_tpu.engine.lora import LoRAManager
 from production_stack_tpu.engine.step_programs import store_stats
-from production_stack_tpu.engine.scheduler import SamplingParams, ScheduledBatch, Scheduler, Sequence
+from production_stack_tpu.engine.scheduler import (
+    SamplingParams, ScheduledBatch, Scheduler, Sequence, host_staged,
+)
 from production_stack_tpu.engine.tokenizer import load_tokenizer
 from production_stack_tpu.tracing import profiler
 from production_stack_tpu.utils.logging import init_logger
@@ -650,6 +652,10 @@ class LLMEngine:
             interactive_reserve=cfg.interactive_reserve,
             batch_queue_deadline_s=cfg.batch_queue_deadline_s,
             batch_prefill_share=cfg.batch_prefill_share,
+            # whether running decode rows take one step inside a prefill
+            # dispatch is what the runner reports of the model module and of
+            # its own set-up, and nothing else
+            rider_refusal=self.runner.rider_refusal,
         )
         # this loop dispatches run-ahead prefills behind in-flight chains
         # (_runahead_prefills), which is what licenses the scheduler's
@@ -688,8 +694,13 @@ class LLMEngine:
         self._turn_t0 = 0.0
         # result shapes whose joining helpers are built (_enqueue)
         self._seams_built: set = set()
-        # widest batch a step program returns: what _last_tokens pads to
-        self._feed_width = self.scheduler._batch_bucket(cfg.max_num_seqs)
+        # widest batch a step program returns: what _last_tokens pads to (a
+        # prefill step's result holds its rows and the riders' slot)
+        self._feed_width = max(
+            self.scheduler._batch_bucket(cfg.max_num_seqs),
+            self.scheduler._batch_bucket(cfg.prefill_batch)
+            + self.scheduler.rider_slots,
+        )
         # two-writer maps (event-loop generate() registers/pops, device
         # thread _emit/_process_token reads/writes): every touch goes
         # through _lock — graftcheck GC004 enforces the discipline
@@ -1430,6 +1441,23 @@ class LLMEngine:
     def _shape(batch) -> tuple:
         return batch.kind, batch.input_ids.shape, batch.page_table.shape
 
+    @staticmethod
+    def _step_input(batch, input_ids=None, rider_ids=None) -> StepInput:
+        """``batch`` as the runner takes it; ``input_ids`` / ``rider_ids``
+        where the device holds some of the tokens (_enqueue)."""
+        r = batch.riders
+        return StepInput(
+            batch.input_ids if input_ids is None else input_ids,
+            batch.positions, batch.page_table, batch.kv_lens,
+            batch.temperature, batch.top_k, batch.top_p,
+            lora_ids=batch.lora_ids, kv_limits=batch.kv_limits,
+            state_slots=batch.state_slots,
+            riders=r and (
+                r.input_ids if rider_ids is None else rider_ids, r.positions,
+                r.page_table, r.kv_lens, r.temperature, r.top_k, r.top_p,
+            ),
+        )
+
     def _hold_back(self) -> None:
         """Wait, reading the inbox, until the running dispatch is a margin
         from its end: the dispatch queued behind it is then planned with the
@@ -1583,9 +1611,9 @@ class LLMEngine:
             )
             os._exit(13)
         flying = [d for d in (feeds, queued) if d is not None and d.pinned]
-        suspect = list(batch.seqs) if batch is not None else []
+        suspect = list(batch.rows) if batch is not None else []
         for b in [d.batch for d in flying] + self._unfetched:
-            suspect.extend(b.seqs)
+            suspect.extend(b.rows)
         self._unfetched.clear()
         for s in suspect:
             if not s.finished:
@@ -1622,16 +1650,22 @@ class LLMEngine:
         the ones every other dispatch runs. The first result of a shape has
         the two helpers that join dispatches built for its batch size, fed or
         not: a run's set-up meets them all, as it meets the step programs."""
-        input_ids = batch.input_ids
-        if batch.fed_from is not None and (batch.fed_from >= 0).any():
-            input_ids = _fed_ids(
-                input_ids, batch.fed_from,
-                _last_tokens(feeds.result, self._feed_width),
+        def fed(ids, fed_from):
+            """``ids`` with the tokens ``feeds`` holds on the device."""
+            if fed_from is None or not (fed_from >= 0).any():
+                return ids
+            ids = _fed_ids(
+                ids, fed_from, _last_tokens(feeds.result, self._feed_width)
             )
-            if self.mesh_devices == 1:
-                # the runner hands a one-chip program its inputs as they
-                # come; over a mesh it places every input itself
-                input_ids = _placed_like_numpy(input_ids)
+            # the runner hands a one-chip program its inputs as they come;
+            # over a mesh it places every input itself
+            return _placed_like_numpy(ids) if self.mesh_devices == 1 else ids
+
+        riders = batch.riders
+        inp = self._step_input(
+            batch, fed(batch.input_ids, batch.fed_from),
+            riders and fed(riders.input_ids, riders.fed_from),
+        )
         # the device ended before the next was enqueued: it stood idle
         drain = (
             self._drain_reason if running is None
@@ -1639,12 +1673,6 @@ class LLMEngine:
         )
         self._count_dispatch(batch, drain)
         work = self._count_work(batch)
-        inp = StepInput(
-            input_ids, batch.positions, batch.page_table, batch.kv_lens,
-            batch.temperature, batch.top_k, batch.top_p,
-            lora_ids=batch.lora_ids, kv_limits=batch.kv_limits,
-            state_slots=batch.state_slots,
-        )
         self.scheduler.pin(batch)
         try:
             with self._section("call", **self._seq_attr(self.step_idx)):  # staging included
@@ -1662,11 +1690,13 @@ class LLMEngine:
                 self._turn_secs += 0.2 * (turn - self._turn_secs)
             if result.shape not in self._seams_built:
                 self._seams_built.add(result.shape)
-                rows = len(batch.kv_lens)
-                _fed_ids(
-                    np.zeros((rows, 1), np.int32), np.full((rows,), -1, np.int32),
-                    _last_tokens(result, self._feed_width),
-                )
+                last = _last_tokens(result, self._feed_width)
+                slot = batch.riders and len(batch.riders.kv_lens)
+                for rows in {len(batch.kv_lens), slot or len(batch.kv_lens)}:
+                    _fed_ids(
+                        np.zeros((rows, 1), np.int32),
+                        np.full((rows,), -1, np.int32), last,
+                    )
         except Exception:
             self.scheduler.retire(batch)
             raise
@@ -1775,19 +1805,25 @@ class LLMEngine:
         """The dispatch's work, for the flight recorder's ``step`` event; a
         decode's KV tokens read are also added to the total."""
         n = len(batch.seqs)
+        window = getattr(self.model_cfg, "sliding_window", None)
         if batch.kind == "prefill":
             tokens = int(sum(batch.chunk_sizes))
             if self.state_family:
                 self.ssm_prefill_tokens_total += tokens
-            return {"prefill_tokens": tokens}
+            work = {"prefill_tokens": tokens}
+            if batch.riders is not None and batch.riders.seqs:
+                # the riders' one step each is decode attention's work too
+                r = len(batch.riders.seqs)
+                read = _kv_tokens_read(batch.riders.kv_lens[:r], 1, window)
+                self.decode_kv_tokens_read_total += read
+                work.update(rider_rows=r, kv_tokens_read=read)
+            return work
         steps = max(1, self.scheduler.decode_steps) * batch.bursts
         kv_len = batch.kv_lens[:n]
         if batch.kv_limits is not None:
             # a row decodes while its KV length stays under its limit
             steps = np.minimum(steps, batch.kv_limits[:n] - kv_len + 1)
-        read = _kv_tokens_read(
-            kv_len, steps, getattr(self.model_cfg, "sliding_window", None)
-        )
+        read = _kv_tokens_read(kv_len, steps, window)
         self.decode_kv_tokens_read_total += read
         if self.state_family:
             self.ssm_decode_tokens_total += int(
@@ -1812,12 +1848,7 @@ class LLMEngine:
         ``fetched`` says whether a host fetch retired the earlier dispatches."""
         fetched = True
         lp_data = None
-        inp = StepInput(
-            batch.input_ids, batch.positions, batch.page_table,
-            batch.kv_lens, batch.temperature, batch.top_k, batch.top_p,
-            lora_ids=batch.lora_ids, kv_limits=batch.kv_limits,
-            state_slots=batch.state_slots,
-        )
+        inp = self._step_input(batch)
         if batch.want_penalties:
             inp.history = batch.history
             inp.prompt_lens = batch.prompt_lens
@@ -1982,7 +2013,9 @@ class LLMEngine:
                 tokens = np.asarray(
                     self.runner.step_multi(inp, self.scheduler.decode_steps)
                 )  # [B, k]
-        elif batch.kind == "prefill" and not any(
+        elif batch.kind == "prefill" and not (
+            batch.riders and batch.riders.seqs  # their tokens are needed
+        ) and not any(
             s.num_computed + c >= s.prefill_len
             for s, c in zip(batch.seqs, batch.chunk_sizes)
         ):
@@ -2028,6 +2061,8 @@ class LLMEngine:
             "sched", step=self.step_idx, batch_kind=batch.kind,
             tp=self.tensor_parallel,
             rows=len(batch.seqs), bursts=batch.bursts,
+            # decode rows that take one step inside this prefill dispatch
+            riders=len(batch.riders.seqs) if batch.riders else 0,
             chunk_tokens=sum(batch.chunk_sizes) if batch.chunk_sizes else 0,
             seq_ids=[s.seq_id for s in batch.seqs[:8]],
             trace_ids=trace_ids,
@@ -2053,13 +2088,7 @@ class LLMEngine:
     def _runahead_allowed(s: Sequence) -> bool:
         """Rows whose dispatch needs no bias/penalty/logprob staging — that
         staging lives on the normal path only; others wait for it."""
-        return (
-            not s.params.wants_penalties
-            and s.params.logprobs is None
-            and not s.params.logit_bias
-            and (s.params.ignore_eos
-                 or len(s.output_ids) >= s.params.min_tokens)
-        )
+        return not host_staged(s)
 
     def _runahead_prefills(self, chain_batch):
         """Dispatch prefill work for sequences disjoint from an in-flight
@@ -2085,11 +2114,7 @@ class LLMEngine:
             self._record_sched_event(ra)
             self._note_first_dispatch(ra)
             self.runahead_prefill_dispatches_total += 1
-            inp = StepInput(
-                ra.input_ids, ra.positions, ra.page_table, ra.kv_lens,
-                ra.temperature, ra.top_k, ra.top_p, lora_ids=ra.lora_ids,
-                kv_limits=ra.kv_limits, state_slots=ra.state_slots,
-            )
+            inp = self._step_input(ra)
             if not any(
                 s.num_computed + c >= s.prefill_len
                 for s, c in zip(ra.seqs, ra.chunk_sizes)
@@ -2954,6 +2979,20 @@ class LLMEngine:
             # that found the device idle, by what had emptied the loop
             "queued_ahead_dispatches_total": dict(self.queued_ahead_dispatches),
             "queue_ahead_drains_total": dict(self.queue_ahead_drains),
+            # decode rows that take one step inside a prefill dispatch
+            # (scheduler._plan_riders): engagement is the dispatches that
+            # carried riders over those and the ones that had decode demand
+            # and carried none (by reason); ``rider_refusal``: why this
+            # engine's prefill programs have no slot at all ("": they have)
+            "rider_refusal": self.scheduler.rider_refusal or "",
+            "prefill_dispatches_total": self.scheduler.prefill_dispatches_total,
+            "prefill_rider_dispatches_total": (
+                self.scheduler.prefill_rider_dispatches_total
+            ),
+            "prefill_rider_rows_total": self.scheduler.prefill_rider_rows_total,
+            "prefill_riderless_dispatches_total": dict(
+                self.scheduler.prefill_riderless_dispatches
+            ),
         }
         if self.state_family:
             # the second kind of state (models/jamba.py): slots of the

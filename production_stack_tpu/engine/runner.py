@@ -213,6 +213,11 @@ class StepInput:
     # [B] int32 slot of each row in the recurrent-state pool (a family with
     # ``init_state``; the null slot for padded rows)
     state_slots: Any = None
+    # decode rows that take one step inside this prefill dispatch (a runner
+    # whose ``rider_refusal`` is None): (ids [R, 1], positions [R, 1],
+    # page_table [R, Pr], kv_lens [R], temperature [R], top_k [R], top_p [R]),
+    # a slot of fixed width whose padded rows have position -1 and kv_len 0
+    riders: Any = None
 
 
 class ModelRunner:
@@ -334,6 +339,23 @@ class ModelRunner:
             "kv_burst" in inspect.signature(self.module.forward).parameters
             and getattr(cfg, "kv_write_mode", "pre") == "post"
             and self._pp == 1
+        )
+
+        # whether the family's prefill step takes running decode rows along
+        # for one token each (``forward(riders=)``, models/llama.py), and what
+        # stands in the way here where it does: the scheduler plans riders
+        # only where this is None (engine.py hands it over)
+        self.rider_refusal = (
+            "family" if "riders" not in inspect.signature(
+                self.module.forward).parameters
+            else "mesh" if self.mesh.devices.size > 1
+            else "kv_quant" if self.kv_quant
+            else "lora" if enable_lora
+            else "kv_write_mode" if getattr(cfg, "kv_write_mode", "pre") != "post"
+            # one kind of attention for the chunk and for the riders: both
+            # kernels (they read the stacked pools) or neither
+            else "attn_impl" if self.attn.impl == "pallas"
+            else None
         )
 
         # a family with recurrent state beside the pages (models/jamba.py):
@@ -563,6 +585,13 @@ class ModelRunner:
                     "state_slots (the scheduler fills them in)"
                 )
             staged["state_slots"] = vec(inp.state_slots, jnp.int32)
+        if inp.riders is not None:
+            ids, pos, table, lens, temp, top_k, top_p = inp.riders
+            staged["riders"] = (
+                row(ids, jnp.int32), row(pos, jnp.int32), row(table, jnp.int32),
+                vec(lens, jnp.int32), vec(temp, jnp.float32),
+                vec(top_k, jnp.int32), vec(top_p, jnp.float32),
+            )
         return staged
 
     def _with_state(self, args: tuple, s: dict, scales_at: int) -> tuple:
@@ -797,6 +826,8 @@ class ModelRunner:
         if self.kv_quant:
             args = args + ((self.k_scales, self.v_scales),)
         args = self._with_state(args, s, 16)
+        if "riders" in s:
+            args = args + (None,) * (18 - len(args)) + (s["riders"],)
         out = self._dispatch(
             self._get_step(want_logprobs, want_pen), "step",
             (want_logprobs, want_pen), s, args,
@@ -2025,13 +2056,21 @@ def _spec_fn(forward, cfg, steps, k, n, params, k_pages, v_pages, history,
 def _step_fn(forward, cfg, want_lp, want_pen, params, k_pages, v_pages,
              input_ids, positions, page_table, kv_lens, temperature, top_k,
              top_p, key, lora=None, lora_ids=None, pen=None, bias=None,
-             kv_scales=None, state=None, state_slots=None):
+             kv_scales=None, state=None, state_slots=None, riders=None):
+    """One forward and one sampled token a row. With ``riders`` (StepInput)
+    the prefill chunk's rows are followed by the riding decode rows: in the
+    logits, under the sampler and in the ids that come back ([B + R])."""
     kw = {} if lora is None else {"lora": lora, "lora_ids": lora_ids}
     key = jax.random.wrap_key_data(key)
     if kv_scales is not None:
         kw["kv_scales"] = kv_scales
     if state is not None:
         kw.update(state=state, state_slots=state_slots)
+    if riders is not None:
+        kw["riders"], (r_temp, r_top_k, r_top_p) = riders[:4], riders[4:]
+        temperature = jnp.concatenate([temperature, r_temp])
+        top_k = jnp.concatenate([top_k, r_top_k])
+        top_p = jnp.concatenate([top_p, r_top_p])
     out, did = _split_counters(cfg, forward(
         params, cfg, input_ids, positions, k_pages, v_pages, page_table,
         kv_lens, **kw,

@@ -146,6 +146,25 @@ class Sequence:
 
 
 @dataclass
+class Riders:
+    """Running decode rows that take ONE step inside a prefill dispatch: a
+    slot of fixed width beside the chunk's rows (StepInput.riders), padded
+    with inert rows (position -1, kv_len 0) when fewer or none ride. Row i's
+    token is row ``len(batch.kv_lens) + i`` of the dispatch's result."""
+
+    seqs: list[Sequence]
+    input_ids: np.ndarray          # [R, 1] each row's last token
+    positions: np.ndarray          # [R, 1], -1 for the padding
+    page_table: np.ndarray         # [R, Scheduler.rider_pages]
+    kv_lens: np.ndarray            # [R] including the token this step writes
+    temperature: np.ndarray
+    top_k: np.ndarray
+    top_p: np.ndarray
+    # planned behind a dispatch that still runs: as ScheduledBatch.fed_from
+    fed_from: np.ndarray = None
+
+
+@dataclass
 class ScheduledBatch:
     kind: str                      # "prefill" | "decode"
     seqs: list[Sequence]
@@ -177,6 +196,16 @@ class ScheduledBatch:
     # [B] int32, the row of THAT dispatch whose last token is this row's
     # input (``input_ids`` holds -1 there), -1 where the host knows the token
     fed_from: np.ndarray = None
+    # a prefill dispatch of a family that takes decode rows along: the slot
+    # (None: the family, the engine's set-up or this batch's program variant
+    # has none)
+    riders: Optional[Riders] = None
+
+    @property
+    def rows(self) -> list[Sequence]:
+        """Every sequence the dispatch reads or writes: its own rows and the
+        riders'."""
+        return self.seqs + self.riders.seqs if self.riders else self.seqs
 
 
 def _bucket(n: int, buckets: tuple[int, ...]) -> int:
@@ -186,11 +215,32 @@ def _bucket(n: int, buckets: tuple[int, ...]) -> int:
     return buckets[-1]
 
 
+def host_staged(seq: Sequence) -> bool:
+    """Whether ``seq``'s dispatches are staged from the host's copy of its
+    tokens: penalties, a logit bias, log-probabilities, the ban on EOS under
+    ``min_tokens``. Such a row neither rides a prefill dispatch nor is
+    planned behind a dispatch that still runs."""
+    p = seq.params
+    return bool(
+        p.wants_penalties
+        or p.logprobs is not None
+        or p.logit_bias
+        or not (p.ignore_eos or len(seq.output_ids) >= p.min_tokens)
+    )
+
+
 class Scheduler:
     DECODE_BATCH_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
     CHUNK_BUCKETS = (16, 32, 64, 128, 256, 512, 1024)
     PAGE_BUCKETS = (2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)
     HISTORY_BUCKETS = CHUNK_BUCKETS + (2048, 4096, 8192, 16384, 32768)
+    # rows of the slot in which decode rows ride a prefill dispatch. ONE
+    # width, so a prefill program is a mixed program and a run builds no
+    # more step programs than without riders; where more rows decode than
+    # the slot holds, none rides (counted ``over_width``). On the chip an
+    # empty slot of 16 costs a 512-token chunk +0.7-1.0 ms of 25, one of 32
+    # +1.3-1.8 ms, and 16 rows ride for +3.0 ms (PERF.md section 6, PR 54)
+    RIDER_SLOTS = 16
 
     def __init__(
         self,
@@ -212,6 +262,7 @@ class Scheduler:
         interactive_reserve: int = 1,
         batch_queue_deadline_s: float = 0.0,
         batch_prefill_share: float = 0.5,
+        rider_refusal: Optional[str] = "family",
     ):
         self.kv = kv
         self.max_num_seqs = max_num_seqs
@@ -243,6 +294,33 @@ class Scheduler:
         ) if decode_page_bucket_floor > 0 else 0
         self.spec_k = max(0, spec_k)
         self.spec_ngram = max(1, spec_ngram)
+        # why this engine's prefill dispatches have no slot for decode rows
+        # (None: they have one). What the runner reports of the model module
+        # and of its own set-up (``ModelRunner.rider_refusal``; a bare
+        # scheduler has no runner that could take riders), then this
+        # scheduler's own: speculative rounds and chained bursts keep a decode
+        # row's tokens on the device
+        self.rider_refusal = rider_refusal or (
+            "speculative" if self.spec_k
+            else "decode_pipeline" if self.decode_pipeline > 1 else None
+        )
+        self.rider_slots = min(
+            self._batch_bucket(self.RIDER_SLOTS), self._batch_bucket(max_num_seqs)
+        )
+        # a live decode row has at most max_model_len - 1 tokens: one width
+        self.rider_pages = _bucket(
+            self._pages_needed(max_model_len), self.PAGE_BUCKETS
+        )
+        # prefill dispatches planned; those in which the running decode rows
+        # took one step, and the rows that did; those planned while decode
+        # rows ran that carried none, by reason (engagement: the second over
+        # the second and the fourth)
+        self.prefill_dispatches_total = 0
+        self.prefill_rider_dispatches_total = 0
+        self.prefill_rider_rows_total = 0
+        self.prefill_riderless_dispatches = dict.fromkeys(
+            ("cannot_ride", "over_width", "no_page"), 0
+        )
         # admission control (overload survival, docs/failure-handling.md):
         # a bounded waiting queue — the API layer sheds (429 + Retry-After)
         # once num_waiting() reaches max_waiting_seqs (0 = unbounded) — and
@@ -496,7 +574,12 @@ class Scheduler:
     def _ensure_decode_page(self, seq: Sequence, bursts: int = 1) -> bool:
         """Make sure the next decode dispatch has KV slots; grow the page list
         if needed (one dispatch of lookahead)."""
-        need = self._pages_needed(self._decode_target_len(seq, bursts)) - len(seq.pages)
+        return self._ensure_pages(seq, self._decode_target_len(seq, bursts))
+
+    def _ensure_pages(self, seq: Sequence, tokens: int) -> bool:
+        """Grow ``seq``'s page list to hold ``tokens`` tokens; False: the
+        pool has none to give."""
+        need = self._pages_needed(tokens) - len(seq.pages)
         if need <= 0:
             return True
         extra = self.kv.allocate(need)
@@ -538,13 +621,13 @@ class Scheduler:
     def pin(self, batch: ScheduledBatch) -> None:
         """``batch`` goes to the device: its rows keep their pages and state
         slots until ``retire``, whatever finishes them meanwhile."""
-        for s in batch.seqs:
+        for s in batch.rows:
             s.inflight += 1
 
     def retire(self, batch: ScheduledBatch) -> None:
         """``batch``'s dispatch has ended (or failed): rows that finished
         while it named them give back what they held."""
-        for s in batch.seqs:
+        for s in batch.rows:
             s.inflight -= 1
             if not s.inflight and s.release_pending:
                 s.release_pending = False
@@ -578,11 +661,17 @@ class Scheduler:
         if batch is None:
             self._last_kind, self._chain_streak = kept
         elif batch.kind == "decode":
-            batch.fed_from = np.full((len(batch.kv_lens),), -1, np.int32)
-            batch.fed_from[: len(batch.seqs)] = [
-                fed.get(id(s), -1) for s in batch.seqs
-            ]
+            batch.fed_from = self._fed_from(fed, batch.seqs, len(batch.kv_lens))
+        elif batch.riders is not None:
+            r = batch.riders
+            r.fed_from = self._fed_from(fed, r.seqs, len(r.kv_lens))
         return batch
+
+    @staticmethod
+    def _fed_from(fed: dict, seqs: list[Sequence], rows: int) -> np.ndarray:
+        out = np.full((rows,), -1, np.int32)
+        out[: len(seqs)] = [fed.get(id(s), -1) for s in seqs]
+        return out
 
     def _project(self, batch: ScheduledBatch):
         """Advance ``batch``'s rows to what its dispatch will leave (what
@@ -601,11 +690,17 @@ class Scheduler:
                     batch.kv_limits[:n].astype(np.int64) - batch.kv_lens[:n] + 1,
                     0, self.decode_steps,
                 )
-        for i, s in enumerate(batch.seqs):
+        # a riding row is a decode row that makes one token; its row in the
+        # result follows the chunk's (padded) rows
+        riding = batch.riders.seqs if batch.riders else []
+        for i, s in enumerate(batch.seqs + riding):
             if s.finished:
                 continue
             undo.append((s, s.num_computed, s.recompute_len, len(s.output_ids)))
-            if batch.kind == "decode":
+            if i >= len(batch.seqs):
+                s.output_ids.append(-1)
+                fed[id(s)] = len(batch.kv_lens) + i - len(batch.seqs)
+            elif batch.kind == "decode":
                 if made[i]:
                     s.output_ids.extend([-1] * int(made[i]))
                     fed[id(s)] = i
@@ -679,7 +774,7 @@ class Scheduler:
             "waiting": len(self.waiting),
         }
         if prefilling and not alternate:
-            return self._take_prefill(prefilling)
+            return self._take_prefill(prefilling, ending)
         self._last_kind = "decode"
         if self.running:
             # chain bursts when nothing admissible is waiting to join the
@@ -814,7 +909,7 @@ class Scheduler:
                 # — a page another live sequence owns.
                 prefilling = [s for s in self.running if s.in_prefill]
                 if prefilling:
-                    return self._take_prefill(prefilling)
+                    return self._take_prefill(prefilling, ending)
             return batch
         return None
 
@@ -840,14 +935,16 @@ class Scheduler:
             return None
         return self._take_prefill(prefilling)
 
-    def _take_prefill(self, prefilling: list[Sequence]) -> ScheduledBatch:
+    def _take_prefill(self, prefilling: list[Sequence],
+                      ending=frozenset()) -> ScheduledBatch:
         """Plan the next prefill dispatch: interactive rows first (their
         TTFT is the SLO under protection), then shortest remaining prompts
         (they finish and start decoding soonest). While interactive prefill
         work is waiting — resident rows that overflow this dispatch, or
         arrivals still queued for a seat — batch's share of the chunk slots
         is capped at ``batch_prefill_share`` so a wall of long batch
-        prompts cannot monopolize the prefill pipeline."""
+        prompts cannot monopolize the prefill pipeline. The running decode
+        rows (but those in ``ending``) ride along where they can."""
         self._last_kind = "prefill"
         self._chain_streak = 0  # prefill work ends the quiescence streak
         prefilling.sort(
@@ -877,7 +974,63 @@ class Scheduler:
             # always keep >= 1 row so the dispatch makes progress even when
             # everything resident is batch
             take = (inter + batch_rows[:cap]) or take[:1]
-        return self._plan_prefill(take)
+        batch = self._plan_prefill(take)
+        batch.riders = self._plan_riders(batch, ending)
+        return batch
+
+    def _plan_riders(self, batch: ScheduledBatch, ending) -> Optional[Riders]:
+        """The slot of the prefill dispatch ``batch``: every running row that
+        is not in prefill and not ending takes one step in it, with its last
+        token, its position, its own pages (one ensured for the token without
+        preempting anybody) and its sampling parameters. All or nothing: one
+        row too many for the slot, one that is staged from the host or one
+        that gets no page, and the slot goes out empty (counted by reason);
+        the burst that follows serves them all, as it did before there were
+        riders. Nothing here delays or shrinks the chunk. None: the step
+        program of this batch has no slot."""
+        self.prefill_dispatches_total += 1
+        if self.rider_refusal:
+            return None
+        decoding = [
+            s for s in self.running
+            if not s.in_prefill and not s.finished and id(s) not in ending
+        ]
+        # a chunk that is staged from the host runs another program variant,
+        # which has no slot
+        no_slot = (batch.want_logprobs or batch.want_penalties
+                   or any(map(host_staged, batch.seqs)))
+        why = None
+        if len(decoding) > self.rider_slots:
+            why = "over_width"
+        elif decoding and (no_slot or any(map(host_staged, decoding))):
+            why = "cannot_ride"
+        elif not all(self._ensure_pages(s, s.num_tokens + 1) for s in decoding):
+            why = "no_page"
+        if why:
+            self.prefill_riderless_dispatches[why] += 1
+        if no_slot:
+            return None  # the program variants staged from the host
+        riding = [] if why else decoding
+        R = self.rider_slots
+        out = Riders(
+            riding,
+            np.zeros((R, 1), np.int32), np.full((R, 1), -1, np.int32),
+            np.zeros((R, self.rider_pages), np.int32), np.zeros((R,), np.int32),
+            np.zeros((R,), np.float32), np.zeros((R,), np.int32),
+            np.ones((R,), np.float32),
+        )
+        for i, s in enumerate(riding):
+            out.input_ids[i, 0] = (s.output_ids or s.prompt_ids)[-1]
+            out.positions[i, 0] = s.num_tokens - 1
+            out.page_table[i, : len(s.pages)] = s.pages[: self.rider_pages]
+            out.kv_lens[i] = s.num_tokens
+            out.temperature[i] = s.params.temperature
+            out.top_k[i] = s.params.top_k
+            out.top_p[i] = s.params.top_p
+        if riding:
+            self.prefill_rider_dispatches_total += 1
+            self.prefill_rider_rows_total += len(riding)
+        return out
 
     def _plan_prefill(self, seqs: list[Sequence]) -> ScheduledBatch:
         chunks = [
@@ -1148,6 +1301,10 @@ class Scheduler:
                 if s.first_token_time is None:
                     s.first_token_time = time.monotonic()
                 consume(s, int(tokens[i, 0]), i, 0)
+            for i, s in enumerate(batch.riders.seqs if batch.riders else ()):
+                row = len(batch.kv_lens) + i
+                if not s.finished and tokens[row, 0] >= 0:
+                    consume(s, int(tokens[row, 0]), row, 0)
             return events
 
         for j in range(tokens.shape[1]):

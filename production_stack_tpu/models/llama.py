@@ -437,6 +437,7 @@ def forward(
     mesh=None,
     kv_burst: Optional[tuple] = None,
     kv_scales: Optional[tuple] = None,
+    riders: Optional[tuple] = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One forward step (prefill chunk or decode) with paged KV.
 
@@ -469,6 +470,17 @@ def forward(
                   the return grows to (logits, k_pages, v_pages, k_scales,
                   v_scales). kv_burst keeps its 3-tuple return (the pools
                   and scales stay read-only through the burst).
+      riders:     (ids [R, 1], positions [R, 1], page_table [R, Pr], kv_lens
+                  [R]): decode rows that take ONE step inside this prefill
+                  dispatch (write-after-attend, one device, no LoRA, fp
+                  pools). Their tokens join the chunk's on one token axis for
+                  the embedding, the norms, every projection, the MLP and the
+                  head, so the weights are read once; attention is per kind
+                  (the chunk as without riders, a rider through the decode
+                  path against its own pages); their K/V is committed after
+                  the layer scan. An inert row has position -1 and kv_len 0:
+                  it writes nothing. Logits come back [B + R, V], the riders'
+                  rows last.
 
     Returns (logits[B, V] for each sequence's last valid token — or [B, T, V]
              when ``all_logits`` — and k_pages, v_pages updated; with
@@ -478,6 +490,25 @@ def forward(
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
     pp = mesh.shape.get("pp", 1) if mesh is not None else 1
     B, T = input_ids.shape
+    single_dev = mesh is None or mesh.devices.size == 1
+    rope_positions = positions
+    if riders is not None:
+        if not (cfg.kv_write_mode == "post" and kv_burst is None
+                and kv_scales is None and lora is None and not all_logits
+                and single_dev and T > 1):
+            raise ValueError(
+                "riders ride a prefill chunk in write-after-attend mode on "
+                "one device, with fp pools and no LoRA"
+            )
+        r_ids, r_pos, r_table, r_lens = riders
+        R = r_ids.shape[0]
+        # ONE token axis: [1, B * T + R] through everything but attention
+        input_ids = jnp.concatenate(
+            [input_ids.reshape(1, B * T), r_ids.reshape(1, R)], axis=1
+        )
+        rope_positions = jnp.concatenate(
+            [positions.reshape(1, B * T), r_pos.reshape(1, R)], axis=1
+        )
     with jax.named_scope("embed"):
         x = params["embed"][input_ids].astype(cfg.dtype)  # [B, T, H]
     if sp > 1 and T > 1:
@@ -489,7 +520,8 @@ def forward(
             x, NamedSharding(mesh, PartitionSpec("dp", "sp", None))
         )
     cos, sin = rope_cos_sin(
-        jnp.maximum(positions, 0), cfg.head_dim, cfg.rope_theta, cfg.rope_scaling
+        jnp.maximum(rope_positions, 0), cfg.head_dim, cfg.rope_theta,
+        cfg.rope_scaling,
     )
     lora_scale = None if lora is None else lora["scale"][lora_ids].astype(cfg.dtype)
 
@@ -538,6 +570,7 @@ def forward(
         "kv_pos": kv_pos if post_write else None,
         "burst_counts": burst_counts if burst else None,
         "lora_ids": lora_ids, "lora_scale": lora_scale,
+        "riders": None if riders is None else (r_pos, r_table, r_lens),
     }
 
     # pallas kernels stream pages straight from the STACKED pools (layer
@@ -548,7 +581,6 @@ def forward(
     # (T >= 16, post-write) streams single-device — multi-device prefill
     # keeps the XLA/ring path (GSPMD cannot partition a pallas_call and the
     # sp axis owns long chunks).
-    single_dev = mesh is None or mesh.devices.size == 1
     # prefill kernel v2 (attn_impl="pallas_prefill", the TPU auto default /
     # "pallas_interpret" in tests): packed ragged grid + contiguous-KV DMA
     # ring — v1's page-granular (64-slot) matmuls fragmented the MXU and
@@ -616,9 +648,48 @@ def forward(
                 )
             return y
 
+        def decode_kernel(q1, pools, table, lens, cur_kw, **scales):
+            """[rows, NH, D]: each row's one query against its own pages,
+            streamed HBM->VMEM (no gather materialization); in post mode the
+            current token's K/V fold in from registers. On a multi-device
+            dp x tp mesh the kernel runs per shard via shard_map (GSPMD cannot
+            partition a pallas_call). ``pools`` are the stacked pools (the
+            layer's index rides along) or the layer's own slices."""
+            from production_stack_tpu.ops.pallas.paged_attention import (
+                ragged_paged_attention_decode,
+                ragged_paged_attention_decode_sharded,
+            )
+
+            pallas_kw = dict(
+                window=cfg.sliding_window,
+                interpret=cfg.attn_impl == "pallas_interpret",
+                pages_per_block=cfg.decode_pages_per_block or None,
+                prefetch_pages=cfg.decode_prefetch_pages or None,
+                **cur_kw, **scales,
+            )
+            if stream_pools:
+                pallas_kw["layer"] = li
+            # under pp the kernel runs INSIDE the pipeline's manual region;
+            # the sharded call nests there and maps the remaining axes
+            if mesh is not None and mesh.devices.size > 1:
+                return ragged_paged_attention_decode_sharded(
+                    mesh, q1, *pools, table, lens, **pallas_kw
+                )
+            return ragged_paged_attention_decode(
+                q1, *pools, table, lens, **pallas_kw
+            )
+
         with jax.named_scope("attention"):
             h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
             q, k, v = _qkv(h, lp, cfg, Bm, Tm, aux["cos"], aux["sin"], proj)
+            if riders is not None:
+                # attention is per kind: the chunk's rows go on as they would
+                # without riders, the riders' follow below
+                qr, kr, vr = q[0, B * T:], k[0, B * T:], v[0, B * T:]
+                q, k, v = (
+                    a[0, : B * T].reshape(B, T, *a.shape[2:]) for a in (q, k, v)
+                )
+                Bm, Tm = B, T
             if burst:
                 # append the current token into the burst window at slot
                 # ``counts`` (entries 0..counts-1 hold earlier burst tokens);
@@ -633,15 +704,6 @@ def forward(
                     aux["page_table"], aux["positions"],
                 )
             if Tm == 1 and cfg.attn_impl.startswith("pallas"):
-                # decode: stream pages HBM->VMEM, no gather materialization; in
-                # post mode the current token's K/V fold in from registers. On a
-                # multi-device dp x tp mesh the kernel runs per shard via
-                # shard_map (GSPMD cannot partition a pallas_call).
-                from production_stack_tpu.ops.pallas.paged_attention import (
-                    ragged_paged_attention_decode,
-                    ragged_paged_attention_decode_sharded,
-                )
-
                 # the in-register window stays fp under int8 pools — it is the
                 # quantizer's INPUT, committed by the post-scan quant scatter
                 cur_dt = cfg.dtype if quant else k_pages.dtype
@@ -657,37 +719,16 @@ def forward(
                     )
                 else:
                     cur_kw = dict(k_cur=None, v_cur=None)
-                pallas_kw = dict(
-                    window=cfg.sliding_window,
-                    interpret=cfg.attn_impl == "pallas_interpret",
-                    pages_per_block=cfg.decode_pages_per_block or None,
-                    prefetch_pages=cfg.decode_prefetch_pages or None,
-                    **cur_kw,
-                )
-                if stream_pools:
-                    pool_args = (k_pages, v_pages)
-                    pallas_kw["layer"] = li
-                    if quant:
-                        pallas_kw["k_scales"] = k_scales
-                        pallas_kw["v_scales"] = v_scales
-                else:
-                    pool_args = (kp, vp)
-                    if quant:
-                        pallas_kw["k_scales"] = ksl
-                        pallas_kw["v_scales"] = vsl
-                # under pp the kernel runs INSIDE the pipeline's manual region;
-                # the sharded call nests there and maps the remaining axes
-                if mesh is not None and mesh.devices.size > 1:
-                    attn = ragged_paged_attention_decode_sharded(
-                        mesh, q[:, 0], *pool_args,
-                        aux["page_table"], aux["kv_lens"],
-                        **pallas_kw,
-                    )[:, None]
-                else:
-                    attn = ragged_paged_attention_decode(
-                        q[:, 0], *pool_args, aux["page_table"], aux["kv_lens"],
-                        **pallas_kw,
-                    )[:, None]
+                scales = {}
+                if quant:
+                    scales = dict(
+                        k_scales=k_scales if stream_pools else ksl,
+                        v_scales=v_scales if stream_pools else vsl,
+                    )
+                attn = decode_kernel(
+                    q[:, 0], (k_pages, v_pages) if stream_pools else (kp, vp),
+                    aux["page_table"], aux["kv_lens"], cur_kw, **scales,
+                )[:, None]
             elif (
                 Tm > 1
                 and cfg.attn_impl.startswith("pallas")
@@ -777,6 +818,40 @@ def forward(
                         window=cfg.sliding_window,
                         kv_positions=aux["kv_pos"] if post_write else None,
                     )
+            rider_kv = None
+            if riders is not None:
+                r_pos, r_table, r_lens = aux["riders"]
+                # the pools are stale for the rider's token as for the chunk's:
+                # its K/V fold in from registers and are committed after the scan
+                rider_kv = (kr[:, None].astype(k_pages.dtype),
+                            vr[:, None].astype(v_pages.dtype))
+                if cfg.attn_impl.startswith("pallas"):
+                    # behind the chunk's kernel where that one writes the
+                    # pools (a read of what it returns: no copy of a pool)
+                    pools = (
+                        (kp_c, vp_c) if fused_prefill
+                        else (k_pages, v_pages) if stream_pools else (kp, vp)
+                    )
+                    attn_r = decode_kernel(
+                        qr, pools, r_table, r_lens,
+                        dict(k_cur=rider_kv[0][:, 0], v_cur=rider_kv[1][:, 0]),
+                    )
+                else:
+                    kc, vc = gather_kv_pages(kp, vp, r_table)
+                    attn_r = flash_attention(
+                        qr[:, None],
+                        jnp.concatenate([kc, rider_kv[0]], axis=1),
+                        jnp.concatenate([vc, rider_kv[1]], axis=1),
+                        q_positions=r_pos, kv_lens=r_lens,
+                        window=cfg.sliding_window,
+                        kv_positions=stale_kv_positions(
+                            r_table, r_pos, k_pages.shape[2]
+                        ),
+                    )
+                attn = jnp.concatenate(
+                    [attn.reshape(1, B * T, -1), attn_r.reshape(1, R, -1)], axis=1
+                )
+                Bm, Tm = attn.shape[:2]
             x = x + proj(attn.reshape(Bm, Tm, -1), "wo")
         with jax.named_scope("mlp"):
             x = _mlp_residual(x, lp, cfg, proj)
@@ -784,7 +859,7 @@ def forward(
             # the kernel already committed this layer's K/V to the pool
             if quant:
                 return (x, aux, kp_c, vp_c, ksc_c, vsc_c), None
-            return (x, aux, kp_c, vp_c), None
+            return (x, aux, kp_c, vp_c), rider_kv
         if burst:
             out_kv = (kwin, vwin)  # stacked by the scan -> [L, B, C, KH, D]
         elif post_write:
@@ -793,6 +868,8 @@ def forward(
             out_kv = (k.astype(store_dt), v.astype(store_dt))
         else:
             out_kv = (kp, vp)
+        if rider_kv is not None:
+            out_kv = out_kv + rider_kv
         return (x, aux), out_kv
 
     lora_layers = None if lora is None else lora["layers"]
@@ -835,7 +912,7 @@ def forward(
         )
     elif fused_prefill:
         # no post-scan scatter: every layer's kernel wrote its pool slice
-        (x, _, k_pages, v_pages), _ = lax.scan(
+        (x, _, k_pages, v_pages), rider_new = lax.scan(
             layer, (x, aux, k_pages, v_pages), scan_xs
         )
     elif post_write and quant:
@@ -852,13 +929,20 @@ def forward(
                 )
             )
     elif post_write:
-        (x, _), (k_new, v_new) = lax.scan(layer, (x, aux), scan_xs)
+        (x, _), (k_new, v_new, *rider_new) = lax.scan(layer, (x, aux), scan_xs)
         with jax.named_scope("kv_commit"):
             k_pages, v_pages = write_kv_pages_all_layers(
                 k_pages, v_pages, k_new, v_new, page_table, positions
             )
     else:
         (x, _), (k_pages, v_pages) = lax.scan(layer, (x, aux), scan_xs)
+    if riders is not None:
+        # the riders' one token each, every layer's in one scatter (a row's
+        # own page, never one of the chunk's; position -1 is dropped)
+        with jax.named_scope("kv_commit"):
+            k_pages, v_pages = write_kv_pages_all_layers(
+                k_pages, v_pages, *rider_new, r_table, r_pos
+            )
 
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
@@ -874,7 +958,12 @@ def forward(
         # Select each sequence's last valid token before the vocab projection so the
         # logits tensor is [B, V], not [B, T, V] (a 2 GB save at V=128k, T=1k).
         last_idx = jnp.maximum(jnp.sum(positions >= 0, axis=1) - 1, 0)  # [B]
+        x_riders = None
+        if riders is not None:
+            x, x_riders = x[0, : B * T].reshape(B, T, -1), x[0, B * T:]
         x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]  # [B, H]
+        if x_riders is not None:
+            x_last = jnp.concatenate([x_last, x_riders], axis=0)  # [B + R, H]
         logits = (x_last @ head).astype(jnp.float32)
     if burst:
         return logits, k_acc, v_acc

@@ -139,10 +139,12 @@ def compile_ssm_scan(*, B=64, T=1, Di=5120, N=16, layers=26, slots=64):
 
 
 def _step_program(cfg, *, tp, B, T, max_pages, num_pages, page_size,
-                  decode_steps, state_slots=64):
+                  decode_steps, state_slots=64, riders=None):
     """(jitted step, how it was jitted, abstract arguments on a v5e mesh of
     ``tp`` chips) as ModelRunner builds them: a prefill/decode ``step``
-    (``decode_steps=0``) or the ``decode_steps``-token deferred burst."""
+    (``decode_steps=0``) or the ``decode_steps``-token deferred burst.
+    ``riders``: (rows, page-table width) of the slot of decode rows a prefill
+    step takes along (StepInput.riders)."""
     from production_stack_tpu import models
     from production_stack_tpu.engine import runner
 
@@ -199,6 +201,13 @@ def _step_program(cfg, *, tp, B, T, max_pages, num_pages, page_size,
     if state:
         args = args + (None,) * (scales_at - len(args)) + state
         outs, donate = outs + (None,), (1, 2, scales_at)
+    if riders is not None:
+        R, r_pages = riders
+        slot = lambda *shape, dt=jnp.int32: on_mesh((R, *shape), dt)  # noqa: E731
+        args = args + (None,) * (18 - len(args)) + ((
+            slot(1), slot(1), slot(r_pages), slot(),
+            slot(dt=jnp.float32), slot(), slot(dt=jnp.float32),
+        ),)
     if getattr(cfg, "step_counters", 0):
         outs = outs + (rep,)  # the dispatch's counters, fetched with the tokens
     kw = {"donate_argnums": donate, "out_shardings": outs}
@@ -207,7 +216,7 @@ def _step_program(cfg, *, tp, B, T, max_pages, num_pages, page_size,
 
 def compile_step_program(
     cfg, *, tp=1, B=4, T=512, max_pages=64, num_pages=512, page_size=64,
-    decode_steps=0, report=None, state_slots=64,
+    decode_steps=0, report=None, state_slots=64, riders=None,
 ):
     """AOT-compile the serving step ModelRunner would dispatch for ``cfg`` on
     a v5e mesh of ``tp`` chips, the way ``runner._dispatch`` runs it: exported,
@@ -222,6 +231,7 @@ def compile_step_program(
     jitted, kw, args = _step_program(
         cfg, tp=tp, B=B, T=T, max_pages=max_pages, num_pages=num_pages,
         page_size=page_size, decode_steps=decode_steps, state_slots=state_slots,
+        riders=riders,
     )
     blob = jax_export.export(jitted, platforms=["tpu"])(*args).serialize()
     exported = jax_export.deserialize(blob)
@@ -292,12 +302,19 @@ CELL_BUCKETS = {
 STEP_PROGRAMS = {
     "mistral-7b-d16.chat/burst-b64xp64": dict(
         preset="mistral-7b", B=64, max_pages=64, num_pages=953, decode_steps=8),
+    # a llama cell's prefill program carries the riders' slot (16 decode
+    # rows by max_model_len's pages): the prefill kernel AND the decode one
     "mistral-7b-d16.chat/prefill-b4xt512": dict(
-        preset="mistral-7b", B=4, T=512, max_pages=64, num_pages=953),
+        preset="mistral-7b", B=4, T=512, max_pages=64, num_pages=953,
+        riders=(16, 64)),
+    "mistral-7b-d16.rag/prefill-b1xt512": dict(
+        preset="mistral-7b", B=1, T=512, max_pages=8, num_pages=953,
+        riders=(16, 64)),
     "qwen2.5-7b-d14.sessions/burst-b16xp64": dict(
         preset="qwen2.5-7b", B=16, max_pages=64, num_pages=2285, decode_steps=8),
     "qwen2.5-7b-d14.sessions/prefill-b4xt512": dict(
-        preset="qwen2.5-7b", B=4, T=512, max_pages=64, num_pages=2285),
+        preset="qwen2.5-7b", B=4, T=512, max_pages=64, num_pages=2285,
+        riders=(16, 64)),
     "mistral-7b/tp4/burst-b8xp64": dict(
         preset="mistral-7b", tp=4, B=8, max_pages=64, num_pages=128,
         decode_steps=8),

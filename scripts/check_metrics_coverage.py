@@ -65,6 +65,12 @@ GENERATED = [
     # engine/api_server.py: labelled by kind / by reason (engine._turn)
     "vllm:queued_ahead_dispatches_total",
     "vllm:queue_ahead_drains_total",
+    # engine/api_server.py: decode rows riding prefill dispatches
+    # (scheduler._plan_riders), the last labelled by reason
+    "vllm:prefill_dispatches_total",
+    "vllm:prefill_rider_dispatches_total",
+    "vllm:prefill_rider_rows_total",
+    "vllm:prefill_riderless_dispatches_total",
     # engine/api_server.py: first-dispatch wall by phase (runner._dispatch)
     *(f"vllm:first_dispatch_{phase}_seconds_total" for phase in (
         "trace", "lower", "compile", "run",
@@ -94,6 +100,10 @@ DASHBOARD_ALLOWLIST = {
     "vllm:engine_dispatch_call_seconds_total",
     "vllm:queued_ahead_dispatches_total",    # how often the loop kept one
     "vllm:queue_ahead_drains_total",         # dispatch queued: bench/debug
+    "vllm:prefill_dispatches_total",         # how often decode rows rode a
+    "vllm:prefill_rider_dispatches_total",   # prefill dispatch and why not:
+    "vllm:prefill_rider_rows_total",         # the same bench/debug surface
+    "vllm:prefill_riderless_dispatches_total",
     "vllm:decode_kv_tokens_read_total",      # the benchmark's counted roofline reads it
     "vllm:first_dispatches_total",           # first-dispatch stalls: a start-up
     "vllm:first_dispatch_seconds_total",     # and bench surface; the dashboard

@@ -120,6 +120,9 @@ def test_step_program_compiles_through_the_store_with_its_pools_donated(tier1, p
     r = tier1["step_programs"][pid]
     assert r["compiled"], r["error"]
     assert r["custom_calls_blob"] == r["custom_calls_plain"] >= 1
+    # a prefill step that takes decode rows along holds both attention kernels
+    if "riders" in v5e_aot.STEP_PROGRAMS[pid]:
+        assert r["custom_calls_blob"] == 2
     # (a family with recurrent state donates its state pool too; its
     # convolution tails, 3 rows of bf16 each, are padded to the tile)
     donated = r["pool_bytes"] + r["state_bytes"]

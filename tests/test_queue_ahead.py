@@ -432,6 +432,132 @@ def test_a_resident_row_the_host_stages_refuses_the_plan_and_a_pinned_row_keeps_
     assert not a.pages and kv.num_free() > free and not a.release_pending
 
 
+# -- riders in the queued loop, and the families that have none ----------------
+
+def test_a_burst_queued_behind_a_mixed_prefill_takes_the_riders_tokens_from_the_device(
+        engine, drained, monkeypatch):
+    """llama: the running decode rows ride the prefill dispatch, and the burst
+    enqueued BEHIND it is fed their tokens from its device-resident result
+    (rows past the chunk's own). What is served is the drained loop's. The
+    state families carry none and serve the same."""
+    # rows that still decode when the later prompts arrive, however fast the toy is
+    traffic = [("long", "a b c", 150, 0.0), ("longer", "d e f g", 170, 0.0),
+               ("chunked", "the prompt of three chunks, " * 3, 40, 0.02),
+               ("late", "arrives while the others decode", 30, 0.05)]
+    drained(True)
+    want = _serve(engine, traffic)
+    drained(False)
+    if engine.scheduler.rider_refusal:
+        # jamba, lfm2: no slot, no rider, the same tokens
+        assert engine.scheduler.rider_refusal == "family"
+        assert _serve(engine, traffic) == want
+        stats = engine.stats()
+        assert stats["prefill_dispatches_total"] > 0 == stats["prefill_rider_rows_total"]
+        assert not any(stats["prefill_riderless_dispatches_total"].values())
+        return _everything_back(engine)
+    seen, enqueue = [], engine._enqueue
+
+    def spy(batch, feeds, running, first):
+        seen.append((batch, feeds.batch if feeds is not None else None))
+        return enqueue(batch, feeds, running, first)
+
+    monkeypatch.setattr(engine, "_enqueue", spy)
+    rows0 = engine.stats()["prefill_rider_rows_total"]
+    assert _serve(engine, traffic) == want
+    mixed = [b for b, _ in seen if b.kind == "prefill" and b.riders and b.riders.seqs]
+    assert mixed and engine.stats()["prefill_rider_rows_total"] - rows0 == sum(
+        len(b.riders.seqs) for b in mixed)
+    mixed_ids = {id(b) for b in mixed}   # (a batch compares by its arrays)
+    fed_by_riders = [
+        (b, f) for b, f in seen
+        if b.kind == "decode" and id(f) in mixed_ids and b.fed_from is not None
+        and (b.fed_from >= len(f.kv_lens)).any()
+    ]
+    assert fed_by_riders
+    for burst, f in fed_by_riders:
+        for i, s in enumerate(burst.seqs):
+            if s in f.riders.seqs:   # its token: its row of the slot, on the device
+                assert burst.fed_from[i] == len(f.kv_lens) + f.riders.seqs.index(s)
+                assert burst.input_ids[i, 0] == -1
+    # a mixed dispatch behind a burst takes its riders' inputs from the burst
+    assert any(id(b) in mixed_ids and f is not None and f.kind == "decode"
+               and (b.riders.fed_from >= 0).any() for b, f in seen)
+    _everything_back(engine)
+
+
+def _digest(batch) -> dict:
+    """A planned batch, array for array."""
+    import hashlib
+
+    def h(a):
+        a = np.ascontiguousarray(a)
+        return f"{a.dtype}{list(a.shape)}:{hashlib.sha1(a.tobytes()).hexdigest()[:16]}"
+    arrays = ("input_ids", "positions", "page_table", "kv_lens", "temperature", "top_k",
+              "top_p", "lora_ids", "kv_limits", "state_slots", "fed_from")
+    return {"kind": batch.kind, "seqs": [s.seq_id for s in batch.seqs],
+            "chunks": list(batch.chunk_sizes), "bursts": batch.bursts,
+            **{k: h(getattr(batch, k)) for k in arrays if getattr(batch, k) is not None}}
+
+
+# who arrives before which turn of the loop: (turn, name, prompt tokens, max_tokens)
+ARRIVALS = [
+    (0, "a", 6, 14), (0, "b", 40, 9), (2, "c", 21, 30), (3, "d", 70, 6), (5, "e", 3, 1),
+    (9, "f", 33, 12), (9, "g", 12, 22), (14, "h", 50, 5), (20, "i", 8, 40),
+]
+# how the engine builds the scheduler of a family whose prefill carries no
+# riders: state slots (jamba, lfm2), one decode page-table width (lfm2), and
+# the llama family on a runner that refuses (a mesh)
+PLANS = {
+    "jamba": dict(state_slots=4),
+    "lfm2": dict(state_slots=4, decode_page_bucket_floor=16),
+    "llama-refused": dict(),
+}
+
+
+def plan_script(scheduler_cls, kv_cls, family: str, **kw) -> list:
+    """The batches the queued loop plans over ARRIVALS (its own order: plan
+    behind the running dispatch, pin, then apply and retire the running one;
+    tokens a fixed function of the turn), each as ``_digest`` has it."""
+    opts = dict(PLANS[family])
+    kv = kv_cls(40, 8, state_slots=opts.pop("state_slots", 0))
+    sched = scheduler_cls(kv, max_num_seqs=4, max_model_len=128, prefill_chunk=16,
+                          decode_steps=4, enable_prefix_caching=False, **opts, **kw)
+    out, running, turn = [], None, 0
+    while turn < 200 and (turn <= ARRIVALS[-1][0] or sched.has_work() or running):
+        for _, name, n, most in (a for a in ARRIVALS if a[0] == turn):
+            sched.add(_seq(name, n, most))
+        batch = (sched.schedule() if running is None
+                 else sched.schedule(ahead_of=running, allow=_allow))
+        if batch is not None:
+            out.append(_digest(batch))
+            sched.pin(batch)
+        if running is not None:
+            shape = (len(running.kv_lens), 4) if running.kind == "decode" else (
+                len(running.kv_lens),)
+            sched.apply_step(running, np.full(shape, 3 + turn % 5), eos_token_id=0)
+            sched.retire(running)
+        running, turn = batch, turn + 1
+    assert not sched.has_work() and kv.num_free() == 40
+    return out
+
+
+@pytest.mark.parametrize("family", sorted(PLANS))
+def test_a_family_that_does_not_ride_plans_the_parents_batches_array_for_array(family):
+    """``tests/data/parent_plans.json`` holds what the scheduler of the commit
+    BEFORE riders planned over this script (written by running ``plan_script``
+    on that commit's ``scheduler.py``, PR 54): jamba, lfm2 and a llama runner
+    that refuses plan the same batches still, array for array."""
+    import json
+    import pathlib
+
+    golden = json.loads(
+        (pathlib.Path(__file__).parent / "data" / "parent_plans.json").read_text())
+    refusal = "mesh" if family == "llama-refused" else "family"
+    got = plan_script(Scheduler, KVPageManager, family, rider_refusal=refusal)
+    assert len(got) > 30 and {b["kind"] for b in got} == {"prefill", "decode"}
+    assert got == golden[family]
+
+
 def _engine_beside(store_dir, name, tp=1):
     """An engine whose runner finds ``store_dir`` as the step-program store
     (and so builds what it lists as it starts), the loader finished."""

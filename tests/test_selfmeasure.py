@@ -463,6 +463,66 @@ def test_the_queue_ahead_counters_stand_in_stats_and_in_metrics(engine, name, la
         assert f'vllm:{name}{{model_name="mistral-debug",{label}="{key}"}} {n}' in text
 
 
+def _ride(engine):
+    """A long answer, and a prompt of its own that arrives once it decodes."""
+    async def run():
+        decoding = asyncio.Event()
+
+        async def one(prompt, n, wait):
+            if wait:
+                await decoding.wait()
+            async for _ in engine.generate(
+                f"t-{np.random.randint(1 << 30)}", prompt=prompt,
+                params=SamplingParams(max_tokens=n, temperature=0.0, ignore_eos=True),
+            ):
+                decoding.set()
+
+        tag = str(np.random.randint(1 << 30))   # nothing of it is cached
+        await asyncio.gather(
+            one("a row that decodes while another prompt is prefilled", 200, False),
+            one(tag + " the late prompt of two chunks and more" * 2, 8, True))
+    asyncio.run(run())
+
+
+@pytest.mark.parametrize("name", [
+    "prefill_dispatches_total", "prefill_rider_dispatches_total",
+    "prefill_rider_rows_total", "prefill_riderless_dispatches_total"])
+def test_the_rider_counters_stand_in_stats_and_in_metrics(engine, name):
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from production_stack_tpu.engine.api_server import EngineServer
+
+    assert engine.stats()["rider_refusal"] == ""   # a llama runner on one device
+    s0 = engine.stats()
+    _ride(engine)
+    s1 = engine.stats()
+    if name == "prefill_riderless_dispatches_total":
+        assert set(s1[name]) == {"cannot_ride", "over_width", "no_page"}
+        assert s1[name] == s0[name]   # every dispatch with decode demand carried its rows
+    else:
+        assert s1[name] > s0[name]
+    # the rows that rode made a token each, and their reads are decode work
+    rows = s1["prefill_rider_rows_total"] - s0["prefill_rider_rows_total"]
+    assert rows >= s1["prefill_rider_dispatches_total"] - s0["prefill_rider_dispatches_total"] > 0
+    steps = [e["data"] for e in tracing.get_flightrecorder().events(kind="step")]
+    assert sum(e.get("rider_rows", 0) for e in steps) >= rows
+    assert all(e["kv_tokens_read"] == WINDOW * e["rider_rows"]
+               for e in steps if e.get("rider_rows"))   # contexts past the window of 8
+
+    async def scrape():
+        cfg = EngineConfig(model="mistral-debug")
+        async with TestClient(TestServer(EngineServer(cfg, engine).build_app())) as client:
+            return await (await client.get("/metrics")).text()
+
+    text, value = asyncio.run(scrape()), engine.stats()[name]
+    assert f"# TYPE vllm:{name} counter" in text
+    if isinstance(value, dict):
+        for why, n in value.items():
+            assert f'vllm:{name}{{model_name="mistral-debug",reason="{why}"}} {n}' in text
+    else:
+        assert f'vllm:{name}{{model_name="mistral-debug"}} {value}' in text
+
+
 def test_profile_endpoints_ride_the_debug_gate(engine, tmp_path):
     from aiohttp.test_utils import TestClient, TestServer
 
