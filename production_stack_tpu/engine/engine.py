@@ -1455,6 +1455,7 @@ class LLMEngine:
             riders=r and (
                 r.input_ids if rider_ids is None else rider_ids, r.positions,
                 r.page_table, r.kv_lens, r.temperature, r.top_k, r.top_p,
+                r.state_slots,
             ),
         )
 
@@ -1816,6 +1817,8 @@ class LLMEngine:
                 r = len(batch.riders.seqs)
                 read = _kv_tokens_read(batch.riders.kv_lens[:r], 1, window)
                 self.decode_kv_tokens_read_total += read
+                if self.state_family:
+                    self.ssm_decode_tokens_total += r
                 work.update(rider_rows=r, kv_tokens_read=read)
             return work
         steps = max(1, self.scheduler.decode_steps) * batch.bursts

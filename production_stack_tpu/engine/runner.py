@@ -215,8 +215,9 @@ class StepInput:
     state_slots: Any = None
     # decode rows that take one step inside this prefill dispatch (a runner
     # whose ``rider_refusal`` is None): (ids [R, 1], positions [R, 1],
-    # page_table [R, Pr], kv_lens [R], temperature [R], top_k [R], top_p [R]),
-    # a slot of fixed width whose padded rows have position -1 and kv_len 0
+    # page_table [R, Pr], kv_lens [R], temperature [R], top_k [R], top_p [R],
+    # state_slots [R] or None as ``state_slots`` above), a slot of fixed width
+    # whose padded rows have position -1, kv_len 0 and the null state slot
     riders: Any = None
 
 
@@ -342,9 +343,10 @@ class ModelRunner:
         )
 
         # whether the family's prefill step takes running decode rows along
-        # for one token each (``forward(riders=)``, models/llama.py), and what
-        # stands in the way here where it does: the scheduler plans riders
-        # only where this is None (engine.py hands it over)
+        # for one token each (``forward(riders=)``: models/llama.py,
+        # models/nemotron_h.py), and what stands in the way here where it
+        # does: the scheduler plans riders only where this is None (engine.py
+        # hands it over)
         self.rider_refusal = (
             "family" if "riders" not in inspect.signature(
                 self.module.forward).parameters
@@ -586,12 +588,19 @@ class ModelRunner:
                 )
             staged["state_slots"] = vec(inp.state_slots, jnp.int32)
         if inp.riders is not None:
-            ids, pos, table, lens, temp, top_k, top_p = inp.riders
+            ids, pos, table, lens, temp, top_k, top_p, slots = inp.riders
             staged["riders"] = (
                 row(ids, jnp.int32), row(pos, jnp.int32), row(table, jnp.int32),
                 vec(lens, jnp.int32), vec(temp, jnp.float32),
                 vec(top_k, jnp.int32), vec(top_p, jnp.float32),
             )
+            if self.has_state:
+                if slots is None:
+                    raise ValueError(
+                        "this model family keeps recurrent state: the riders "
+                        "need their state slots (the scheduler fills them in)"
+                    )
+                staged["riders"] += (vec(slots, jnp.int32),)
         return staged
 
     def _with_state(self, args: tuple, s: dict, scales_at: int) -> tuple:
@@ -2067,7 +2076,10 @@ def _step_fn(forward, cfg, want_lp, want_pen, params, k_pages, v_pages,
     if state is not None:
         kw.update(state=state, state_slots=state_slots)
     if riders is not None:
-        kw["riders"], (r_temp, r_top_k, r_top_p) = riders[:4], riders[4:]
+        # what ``forward`` gets: ids, positions, page table, lengths and, for
+        # a family with recurrent state, the riders' slots as a fifth entry
+        r_temp, r_top_k, r_top_p = riders[4:7]
+        kw["riders"] = riders[:4] + riders[7:]
         temperature = jnp.concatenate([temperature, r_temp])
         top_k = jnp.concatenate([top_k, r_top_k])
         top_p = jnp.concatenate([top_p, r_top_p])

@@ -160,6 +160,9 @@ class Riders:
     temperature: np.ndarray
     top_k: np.ndarray
     top_p: np.ndarray
+    # [R] each row's slot in the recurrent-state pool, the null slot for the
+    # padding; None where the family keeps pages only
+    state_slots: Optional[np.ndarray] = None
     # planned behind a dispatch that still runs: as ScheduledBatch.fed_from
     fed_from: np.ndarray = None
 
@@ -1017,7 +1020,7 @@ class Scheduler:
             np.zeros((R, 1), np.int32), np.full((R, 1), -1, np.int32),
             np.zeros((R, self.rider_pages), np.int32), np.zeros((R,), np.int32),
             np.zeros((R,), np.float32), np.zeros((R,), np.int32),
-            np.ones((R,), np.float32),
+            np.ones((R,), np.float32), state_slots=self._state_slots(riding, R),
         )
         for i, s in enumerate(riding):
             out.input_ids[i, 0] = (s.output_ids or s.prompt_ids)[-1]

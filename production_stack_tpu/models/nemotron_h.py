@@ -478,15 +478,31 @@ def _gated_norm(y, z, w, groups: int, eps: float):
     return y.reshape(shape) * w.astype(jnp.float32)
 
 
-def _ssd_mixer(x, lp, cfg: NemotronHConfig, state, li, row):
-    """One Mamba-2 mixer over a chunk. Returns (mixer output float32, state)."""
-    B, T, _ = x.shape
+def _by_kind(a, kinds):
+    """``a`` [.., tokens, C] cut into each kind's own [B, T, C] rows (``kinds``:
+    the ``_rows`` of the chunk, then of the riders, in the order they lie
+    along the token axis); with one kind it is ``a`` itself."""
+    if len(kinds) == 1:
+        return [a]
+    out, at = [], 0
+    for row in kinds:
+        B, T = row["valid"].shape
+        out.append(a[0, at:at + B * T].reshape(B, T, a.shape[-1]))
+        at += B * T
+    return out
+
+
+def _conv_and_scan(xbc, dt, lp, cfg: NemotronHConfig, state, li, row):
+    """The part of a Mamba-2 mixer that knows rows: the causal convolution
+    over [the sequence's tail, its new inputs] and the recurrence, for rows of
+    ONE kind (a chunk: ``ssd_scan_prefill``; one token a row: ``ssd_step_decode``).
+    ``xbc`` [B, T, C] before the convolution, ``dt`` [B, T, NH] after its
+    softplus. Returns (y [B, T, Di] float32, state)."""
+    B, T, _ = xbc.shape
     Di, K = cfg.d_inner, cfg.conv_kernel
     NH, P, G, N = (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.n_groups,
                    cfg.ssm_state_size)
     f32 = jnp.float32
-    h = rms_norm(x, lp["mixer_norm"], cfg.norm_eps).astype(cfg.dtype)
-    z, xbc = jnp.split(h @ lp["in_proj"], [Di], axis=-1)
     # causal depthwise convolution over [the sequence's last K-1 inputs, chunk]
     tail = jnp.where(row["first"][:, None, None], 0, state["conv"][li, row["slots"]])
     seq = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)   # [B, K-1+T, C]
@@ -501,11 +517,6 @@ def _ssd_mixer(x, lp, cfg: NemotronHConfig, state, li, row):
         jnp.take_along_axis(seq, keep[:, :, None], axis=1).astype(state["conv"].dtype)
     )
     xs, b_mat, c_mat = jnp.split(xbc, [Di, Di + G * N], axis=-1)
-    # the step is an exponent summed along the whole sequence: float32 from
-    # the projection on
-    dt = jax.nn.softplus(
-        jnp.dot(h, lp["dt_proj"], preferred_element_type=f32) + lp["dt_bias"]
-    )
     dt = jnp.where(row["valid"][..., None], dt, 0.0)  # a zero step leaves the state
     y, ssm_pool = ssd_scan.ssd_scan(
         xs.reshape(B, T, NH, P), dt, -jnp.exp(lp["a_log_head"].astype(f32)),
@@ -513,8 +524,30 @@ def _ssd_mixer(x, lp, cfg: NemotronHConfig, state, li, row):
         state["ssm"], row["slots"], row["first"], row["lens"], li,
         impl=cfg.ssm_impl,
     )
-    y = _gated_norm(y.reshape(B, T, Di), z, lp["gate_norm"], G, cfg.norm_eps)
-    return _dot_f32(y, lp["out_proj"]), {"conv": conv_pool, "ssm": ssm_pool}
+    return y.reshape(B, T, Di), {"conv": conv_pool, "ssm": ssm_pool}
+
+
+def _ssd_mixer(x, lp, cfg: NemotronHConfig, state, li, kinds):
+    """One Mamba-2 mixer over ``x`` [B, T, H], or [1, tokens, H] where decode
+    rows ride the chunk: the projections and the gated norm see one token
+    axis, the convolution and the recurrence each kind of row by itself, the
+    chunk's first. Returns (mixer output float32, state)."""
+    Di, G = cfg.d_inner, cfg.n_groups
+    h = rms_norm(x, lp["mixer_norm"], cfg.norm_eps).astype(cfg.dtype)
+    z, xbc = jnp.split(h @ lp["in_proj"], [Di], axis=-1)
+    # the step is an exponent summed along the whole sequence: float32 from
+    # the projection on
+    dt = jax.nn.softplus(
+        jnp.dot(h, lp["dt_proj"], preferred_element_type=jnp.float32) + lp["dt_bias"]
+    )
+    ys = []
+    for row, xbc_k, dt_k in zip(kinds, _by_kind(xbc, kinds), _by_kind(dt, kinds)):
+        y, state = _conv_and_scan(xbc_k, dt_k, lp, cfg, state, li, row)
+        ys.append(y)
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(
+        [y.reshape(1, -1, Di) for y in ys], axis=1)
+    y = _gated_norm(y, z, lp["gate_norm"], G, cfg.norm_eps)
+    return _dot_f32(y, lp["out_proj"]), state
 
 
 def _moe_layer(x, mp, experts_flat, cfg: NemotronHConfig, li, valid, impl):
@@ -582,6 +615,7 @@ def forward(
     kv_burst: Optional[tuple] = None,
     state: Optional[dict] = None,
     state_slots: Optional[jnp.ndarray] = None,
+    riders: Optional[tuple] = None,
 ):
     """One forward step (prefill chunk or decode) with paged KV and slotted
     recurrent state.
@@ -589,7 +623,20 @@ def forward(
     Same contract as models/lfm2.py ``forward``: ``state`` is ``init_state``'s
     pools, ``state_slots`` [B] int32 (the null slot for padded rows); returns
     ``(logits, k_pages, v_pages, state, counters)``, or ``(logits, k_acc,
-    v_acc, state, counters)`` with ``kv_burst``."""
+    v_acc, state, counters)`` with ``kv_burst``.
+
+    ``riders``: (ids [R, 1], positions [R, 1], page_table [R, Pr], kv_lens
+    [R], state_slots [R]): decode rows that take ONE step inside this prefill
+    dispatch, as models/llama.py's do. Their tokens join the chunk's on one
+    token axis [1, B * T + R] for the embedding, every norm, every projection,
+    the router, the experts, the shared expert and the head, so the weights
+    are read once. Per kind, each through the code it runs without riders:
+    the convolution and the recurrence (the chunk's rows ``ssd_scan`` at T >
+    1, a rider's at T == 1 against its OWN slot) and attention (the chunk's
+    rows ``flash_attention``, a rider's one query ``burst_attention`` over its
+    own pages and a window of its one new token). An inert row has position
+    -1, kv_len 0 and the null slot: it writes no page and leaves the state it
+    reads. Logits come back [B + R, V], the riders' rows last."""
     if cfg.attn_impl not in ("auto", "xla"):
         raise ValueError(
             f"attn_impl={cfg.attn_impl!r}: this family's attention blocks run "
@@ -608,10 +655,22 @@ def forward(
     QH, KH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     La, P = k_pages.shape[:2]
     burst = kv_burst is not None
+    row = _rows(positions, state_slots)
+    kinds, valid = [row], row["valid"]
+    if riders is not None:
+        if burst or all_logits or T == 1:
+            raise ValueError("riders ride a prefill chunk (T > 1, no kv_burst, no all_logits)")
+        r_ids, r_pos, r_table, r_lens, r_slots = riders
+        R = r_ids.shape[0]
+        kinds.append(_rows(r_pos, r_slots))
+        # ONE token axis: [1, B * T + R] through everything but the
+        # convolution, the recurrence and attention
+        input_ids = jnp.concatenate(
+            [input_ids.reshape(1, B * T), r_ids.reshape(1, R)], axis=1)
+        valid = jnp.concatenate(
+            [valid.reshape(1, B * T), kinds[1]["valid"].reshape(1, R)], axis=1)
     with jax.named_scope("embed"):
         x = params["embed"][input_ids].astype(jnp.float32)
-    row = _rows(positions, state_slots)
-    valid = row["valid"]
     if burst:
         if T != 1:
             raise ValueError("kv_burst is the decode shape (T == 1)")
@@ -620,13 +679,20 @@ def forward(
             kv_lens, counts + 1, page_table.shape[1] * k_pages.shape[2], k_acc.shape[2]
         )
         rows = jnp.arange(B, dtype=jnp.int32)
-        k_new, v_new = k_acc, v_acc
+        new = {"k": k_acc, "v": v_acc}
     else:
         kv_pos = stale_kv_positions(page_table, positions, k_pages.shape[2])
         # this step's keys and values by attention block, rows as the pool
         # stores them: what the commit below writes to the pages
-        k_new = jnp.zeros((La, B, T, 1, KH * D), k_pages.dtype)
-        v_new = jnp.zeros((La, B, T, 1, KH * D), v_pages.dtype)
+        new = {"k": jnp.zeros((La, B, T, 1, KH * D), k_pages.dtype),
+               "v": jnp.zeros((La, B, T, 1, KH * D), v_pages.dtype)}
+    if riders is not None:
+        # a rider's pages are stale for its token as the chunk's are: its K/V
+        # ride a window of one entry and are committed beside the chunk's
+        r_kv_pos = burst_kv_positions(
+            r_lens, jnp.ones_like(r_lens), r_table.shape[1] * k_pages.shape[2], 1)
+        new.update(rk=jnp.zeros((La, R, 1, 1, KH * D), k_pages.dtype),
+                   rv=jnp.zeros((La, R, 1, 1, KH * D), v_pages.dtype))
     pools_flat = (
         k_pages.reshape((La * P,) + k_pages.shape[2:]),
         v_pages.reshape((La * P,) + v_pages.shape[2:]),
@@ -636,21 +702,30 @@ def forward(
     mp = params["moe_layers"]
     experts_flat = tuple(mp[n].reshape((-1,) + mp[n].shape[2:]) for n in ("w1", "w2"))
 
-    def attn_block(x, k_new, v_new, j):
+    def put(stack, a, j):
+        return lax.dynamic_update_index_in_dim(stack, a, j, 0)
+
+    def attn_block(x, new, j):
         lp = _at(params["attn_layers"], j)
         with jax.named_scope("attn_mixer"):
             h = rms_norm(x, lp["mixer_norm"], cfg.norm_eps).astype(cfg.dtype)
-            q = (h @ lp["wq"]).reshape(B, T, QH, D)
-            k = (h @ lp["wk"]).astype(k_pages.dtype).reshape(B, T, 1, KH * D)
-            v = (h @ lp["wv"]).astype(v_pages.dtype).reshape(B, T, 1, KH * D)
+            q = h @ lp["wq"]
+            k = (h @ lp["wk"]).astype(k_pages.dtype)
+            v = (h @ lp["wv"]).astype(v_pages.dtype)
+            if riders is not None:
+                # attention is per kind: the chunk's rows go on as they would
+                # without riders, the riders' follow below
+                (q, qr), (k, kr), (v, vr) = (_by_kind(a, kinds) for a in (q, k, v))
+            q = q.reshape(B, T, QH, D)
+            k, v = k.reshape(B, T, 1, KH * D), v.reshape(B, T, 1, KH * D)
             # pages of block ``j`` out of the pools seen as [La * P, ...] (a
             # bitcast): ``k_pages[j]`` would be a copy of both whole pools
             kc, vc = gather_kv_pages(*pools_flat, page_table + j * P)
             if burst:
                 # the burst's window, not the pool, carries this burst's K/V
-                k = lax.dynamic_index_in_dim(k_new, j, 0, keepdims=False).at[
+                k = lax.dynamic_index_in_dim(new["k"], j, 0, keepdims=False).at[
                     rows, counts].set(k[:, 0])
-                v = lax.dynamic_index_in_dim(v_new, j, 0, keepdims=False).at[
+                v = lax.dynamic_index_in_dim(new["v"], j, 0, keepdims=False).at[
                     rows, counts].set(v[:, 0])
                 attn = burst_attention(
                     q, kc[:, :, 0], vc[:, :, 0], k[:, :, 0], v[:, :, 0], kv_pos,
@@ -663,10 +738,22 @@ def forward(
                     heads(jnp.concatenate([vc, v], axis=1)),
                     q_positions=positions, kv_lens=kv_lens, kv_positions=kv_pos,
                 )
-            x = x + _dot_f32(attn.reshape(B, T, QH * D), lp["wo"])
-        k_new = lax.dynamic_update_index_in_dim(k_new, k, j, 0)
-        v_new = lax.dynamic_update_index_in_dim(v_new, v, j, 0)
-        return x, k_new, v_new
+            new = dict(new, k=put(new["k"], k, j), v=put(new["v"], v, j))
+            if riders is not None:
+                # a decode step's attention for one token a row: the rider's
+                # own pages and a window that holds its new K/V alone
+                kc, vc = gather_kv_pages(*pools_flat, r_table + j * P)
+                attn_r = burst_attention(
+                    qr.reshape(R, 1, QH, D), kc[:, :, 0], vc[:, :, 0], kr, vr,
+                    r_kv_pos, r_pos, KH,
+                )
+                attn = jnp.concatenate(
+                    [attn.reshape(1, B * T, QH * D), attn_r.reshape(1, R, QH * D)],
+                    axis=1)
+                new.update(rk=put(new["rk"], kr[:, :, None], j),
+                           rv=put(new["rv"], vr[:, :, None], j))
+            x = x + _dot_f32(attn.reshape(x.shape[:2] + (QH * D,)), lp["wo"])
+        return x, new
 
     def run(carry, shape, units):
         """One run of units of one shape (``has_m``, ``has_e``) as a scan."""
@@ -675,51 +762,66 @@ def forward(
         index = lambda k: jnp.asarray([u[k] or 0 for u in units], jnp.int32)  # noqa: E731
 
         def body(carry, xs):
-            x, st, k_new, v_new, counters = carry
+            x, st, new, counters = carry
             m, a, e, attend = xs
             if has_m:
                 with jax.named_scope("ssd_mixer"):
-                    out, st = _ssd_mixer(x, _at(params["ssm_layers"], m), cfg, st, m, row)
+                    out, st = _ssd_mixer(x, _at(params["ssm_layers"], m), cfg, st, m, kinds)
                     x = x + out
             if all(attends):
-                x, k_new, v_new = attn_block(x, k_new, v_new, a)
+                x, new = attn_block(x, new, a)
             elif any(attends):
-                x, k_new, v_new = lax.cond(
-                    attend, attn_block, lambda x, k, v, j: (x, k, v),
-                    x, k_new, v_new, a,
+                x, new = lax.cond(
+                    attend, attn_block, lambda x, new, j: (x, new), x, new, a,
                 )
             if has_e:
                 out, routed = _moe_layer(x, mp, experts_flat, cfg, e, valid, impl)
                 x = x + out
                 counters = counters.at[:routed.shape[0]].add(routed)
-            return (x, st, k_new, v_new, counters), None
+            return (x, st, new, counters), None
 
         return lax.scan(
             body, carry, (index(0), index(1), index(2), jnp.asarray(attends))
         )[0]
 
-    carry = (x, state, k_new, v_new, jnp.zeros((cfg.step_counters,), jnp.int32))
+    carry = (x, state, new, jnp.zeros((cfg.step_counters,), jnp.int32))
     for shape, units in _runs(_units(cfg.pattern)):
         carry = run(carry, shape, units)
-    x, state, k_new, v_new, counters = carry
+    x, state, new, counters = carry
     # what the SSD layers did, counted where the positions are: tokens stepped
-    # (decode); tokens walked in chunks (prefill), those chunks, and the rows
-    # they belonged to (a row's state crosses HBM in and out once a layer)
+    # (decode rows, and the LIVE riders of a prefill dispatch: both run
+    # ``ssd_step_decode``); tokens walked in chunks (prefill), those chunks,
+    # and the rows they belonged to (a row's state crosses HBM in and out
+    # once a layer)
     tokens = jnp.sum(row["lens"])
     chunks = jnp.sum(-(-row["lens"] // cfg.chunk_size))
-    ssd = (tokens, 0, 0, 0) if T == 1 else (0, tokens, chunks, jnp.sum(row["lens"] > 0))
+    stepped = 0 if riders is None else jnp.sum(kinds[1]["lens"])
+    ssd = (tokens, 0, 0, 0) if T == 1 else (
+        stepped, tokens, chunks, jnp.sum(row["lens"] > 0))
     counters = counters.at[-len(SSD_COUNTERS):].set(
         jnp.stack([jnp.asarray(c, jnp.int32) for c in ssd]))
+    k_new, v_new = new["k"], new["v"]
     if not burst:
         with jax.named_scope("kv_commit"):
             k_new, v_new = write_kv_pages_all_layers(
                 k_pages, v_pages, k_new, v_new, page_table, positions
             )
+            if riders is not None:
+                # the riders' one token each, all six blocks' in one scatter
+                # (a row's own page, never one of the chunk's; position -1
+                # is dropped)
+                k_new, v_new = write_kv_pages_all_layers(
+                    k_new, v_new, new["rk"], new["rv"], r_table, r_pos
+                )
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         if not all_logits:
             # the last valid token alone meets the vocabulary ([B, V], not [B, T, V])
             last = jnp.maximum(row["lens"] - 1, 0)
+            if riders is not None:
+                x, x_riders = x[0, :B * T].reshape(B, T, -1), x[0, B * T:]
             x = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+            if riders is not None:
+                x = jnp.concatenate([x, x_riders], axis=0)   # [B + R, H]
         logits = _dot_f32(x, params["lm_head"])
     return logits, k_new, v_new, state, counters

@@ -207,6 +207,7 @@ def _step_program(cfg, *, tp, B, T, max_pages, num_pages, page_size,
         args = args + (None,) * (18 - len(args)) + ((
             slot(1), slot(1), slot(r_pages), slot(),
             slot(dt=jnp.float32), slot(), slot(dt=jnp.float32),
+            *([slot()] if state else []),   # the riders' state slots
         ),)
     if getattr(cfg, "step_counters", 0):
         outs = outs + (rep,)  # the dispatch's counters, fetched with the tokens
@@ -330,6 +331,13 @@ STEP_PROGRAMS = {
         decode_steps=8),
     "lfm2-8b-a1b-d16.chat/prefill-b4xt512": dict(
         preset="lfm2-8b-a1b-d16", B=4, T=512, max_pages=64, num_pages=4096),
+    # Mamba-2 mixers with the riders' slot, all 52 blocks (one scan of 23
+    # units) at the cell's pools: ``ssd_scan_prefill`` for the chunk AND
+    # ``ssd_step_decode`` for the riders in one program, beside the grouped
+    # product's two calls
+    "nemotron3-nano-30b-ep8.chat/prefill-b4xt512": dict(
+        preset="nemotron3-nano-30b-ep8", B=4, T=512, max_pages=64,
+        num_pages=1280, state_slots=32, riders=(16, 64)),
 }
 
 # the selective scan at the shapes the jamba2-3b.chat cell dispatches
@@ -387,7 +395,7 @@ def _attempt(fn, **kw) -> dict:
 def run_matrix(slow: bool = False) -> dict:
     from production_stack_tpu import models
     from production_stack_tpu.engine import runner
-    from production_stack_tpu.models import jamba, lfm2
+    from production_stack_tpu.models import jamba, lfm2, nemotron_h
 
     out: dict = {"decode": {}, "prefill": {}, "prefill_refused": {},
                  "smem": {}, "step_programs": {}, "ssm_scan": {}}
@@ -440,6 +448,11 @@ def run_matrix(slow: bool = False) -> dict:
                 # its whole depth: the scans compile each body once
                 cfg = dataclasses.replace(
                     preset, attn_impl=attn.impl, moe_impl="pallas")
+            elif module is nemotron_h:
+                # its whole depth too; the attention blocks run the XLA path
+                # whatever the rule allows (NemotronHConfig.attn_impl)
+                cfg = dataclasses.replace(
+                    preset, moe_impl="pallas", ssm_impl="pallas")
             else:
                 cfg = dataclasses.replace(preset, num_layers=2, attn_impl=attn.impl)
             if module is jamba:

@@ -86,7 +86,7 @@ def _slot(decode: StepInput, R: int, width: int, fault=None):
     if fault == "neighbour":  # every row carries the next row's input token
         ids[:n] = np.roll(ids[:n], 1, axis=0)
     return (ids, pos, table, lens, np.zeros(R, np.float32), np.zeros(R, np.int32),
-            np.ones(R, np.float32))
+            np.ones(R, np.float32), None)
 
 
 def _both_ways(preset, attn, n, R, rows, fault=None, f32=False):
